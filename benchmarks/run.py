@@ -67,6 +67,9 @@ def main(argv=None) -> None:
 
         ensure_devices(int(np.prod(parse_mesh_spec(args.mesh))))
     backends = _resolve_backends(args.backend)
+    from repro.common.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     t0 = time.time()
     if _selected(which, "fig2"):

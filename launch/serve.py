@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compile_cache import use_compile_cache
 from repro.core import LemurConfig
 from repro.data import synthetic
 from repro.fleet import Router, clone_replicas
@@ -77,6 +78,7 @@ def main() -> None:
                    help="inject N topic-shifted docs mid-traffic (plus "
                         "N//2 deletes) to exercise the refresh")
     args = p.parse_args()
+    use_compile_cache()
 
     corpus = synthetic.make_corpus(m=args.m, d=args.d, avg_tokens=12,
                                    max_tokens=16, seed=args.seed)

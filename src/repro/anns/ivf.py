@@ -29,7 +29,7 @@ from repro.anns.quantization import (
     sq8_dequant,
     sq8_quant,
 )
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 class IVFIndex(NamedTuple):
@@ -58,6 +58,9 @@ class IVFIndex(NamedTuple):
     @property
     def residual(self) -> bool:
         return self.rq_values is not None
+
+
+PACK_BLOCK_BYTES = 1 << 30   # fp32 list gather quantized per SQ8 pack step
 
 
 def default_nlist(m: int) -> int:
@@ -130,12 +133,26 @@ def _pack_lists(vectors, assign: np.ndarray, nlist: int, *, sq8: bool,
         ids[c, pos[c]] = i
         pos[c] += 1
     ids = jnp.asarray(ids)
-    safe = jnp.maximum(ids, 0)
-    vecs = jnp.take(jnp.asarray(vectors), safe, axis=0)  # (nlist, cap, d)
-    vecs = vecs * (ids >= 0)[..., None]
-    scales = None
-    if sq8:
-        vecs, scales = sq8_quant(vecs)
+    vectors = jnp.asarray(vectors)
+
+    def pack(rows):        # (n, cap) ids -> (n, cap, d) lists (+ SQ8 codes)
+        v = jnp.take(vectors, jnp.maximum(rows, 0), axis=0)
+        v = v * (rows >= 0)[..., None]
+        return sq8_quant(v) if sq8 else (v, None)
+
+    # SQ8 lists are quantized a block of lists at a time (per-row scales, so
+    # the result is the same) and the blocks are joined on the host: the
+    # device never holds the fp32 (nlist, cap, d) gather, nor two copies of
+    # the int8 lists
+    step = nlist if not sq8 else max(1, (PACK_BLOCK_BYTES // 4)
+                                     // (cap * vectors.shape[1]))
+    if step >= nlist:
+        vecs, scales = pack(ids)
+    else:
+        parts = [jax.device_get(pack(ids[lo:lo + step]))
+                 for lo in range(0, nlist, step)]
+        vecs = jnp.asarray(np.concatenate([v for v, _ in parts]))
+        scales = jnp.asarray(np.concatenate([sc for _, sc in parts]))
     return ids, vecs, scales, jnp.asarray(counts, jnp.int32)
 
 
@@ -226,6 +243,15 @@ def extend_ivf(index: IVFIndex, new_vectors: jax.Array) -> IVFIndex:
                     rq_values=index.rq_values)
 
 
+def probe_lists(index: IVFIndex, q: jax.Array, nprobe: int) -> jax.Array:
+    """(B, d) queries -> (B, nprobe) ids of the best-scoring centroids.
+    Scored at full fp32 precision: on the TPU, XLA's default rounds a
+    batched product through bf16 but computes a one-row one in fp32, so a
+    query's probes would depend on the size of its batch."""
+    cs = jnp.matmul(q, index.centroids.T, precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.top_k(cs, nprobe)[1]
+
+
 @functools.partial(jax.jit, static_argnames=("nprobe", "k", "use_fused_gather"))
 def search_ivf(index: IVFIndex, q: jax.Array, nprobe: int, k: int,
                use_fused_gather: bool = False):
@@ -238,8 +264,7 @@ def search_ivf(index: IVFIndex, q: jax.Array, nprobe: int, k: int,
     the legacy materialize-then-score path benchmarkable.
     """
     B, d = q.shape
-    cs = q @ index.centroids.T                     # (B, nlist)
-    _, probe = jax.lax.top_k(cs, nprobe)           # (B, nprobe)
+    probe = probe_lists(index, q, nprobe)          # (B, nprobe)
     ids = jnp.take(index.ids, probe, axis=0)       # (B, nprobe, cap)
     if index.residual:
         # decode-at-source scan (in-kernel on TPU); the "legacy" path for
@@ -253,7 +278,7 @@ def search_ivf(index: IVFIndex, q: jax.Array, nprobe: int, k: int,
         # masked -inf inside the scan (same pad convention as below)
         s = ops.fused_ivf_scan(q, probe, index.ids, index.vecs, index.scales)
     else:
-        vecs = jnp.take(index.vecs, probe, axis=0)  # (B, nprobe, cap, d)
+        vecs = ref.take_lists(index.vecs, probe)   # (B, nprobe, cap, d)
         cap = vecs.shape[2]
         if index.scales is not None:
             # batched SQ8 scan: all B queries' gathered lists in ONE call
@@ -265,6 +290,7 @@ def search_ivf(index: IVFIndex, q: jax.Array, nprobe: int, k: int,
             s = s.reshape(B, nprobe, cap)
         else:
             s = jnp.einsum("bd,bpcd->bpc", q, vecs.astype(q.dtype),
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
         s = jnp.where(ids >= 0, s, -jnp.inf)
     flat_s = s.reshape(B, -1)

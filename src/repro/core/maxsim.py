@@ -14,11 +14,15 @@ import jax
 import jax.numpy as jnp
 
 NEG = -1e30
+# MaxSim is an exact score: fp32 operands contract at full fp32 precision
+# (XLA's TPU default would round them through bf16).  The OLS target
+# generator token_maxsim keeps the default — it feeds a least-squares fit.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def maxsim_pair(q, q_mask, c, c_mask):
     """MaxSim(X, C) for one pair.  q: (Tq, d); c: (Td, d)."""
-    s = q @ c.T  # (Tq, Td)
+    s = jnp.matmul(q, c.T, precision=HIGHEST)  # (Tq, Td)
     s = jnp.where(c_mask[None, :], s, NEG)
     best = jnp.max(s, axis=-1)
     best = jnp.where(q_mask, best, 0.0)
@@ -27,7 +31,8 @@ def maxsim_pair(q, q_mask, c, c_mask):
 
 def _score_block(q, q_mask, docs, docs_mask):
     """q: (B, Tq, d); docs: (Mb, Td, d) -> (B, Mb)."""
-    s = jnp.einsum("bqd,mtd->bmqt", q, docs, preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqd,mtd->bmqt", q, docs, precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
     s = jnp.where(docs_mask[None, :, None, :], s, NEG)
     best = jnp.max(s, axis=-1)  # (B, Mb, Tq)
     best = jnp.where(q_mask[:, None, :], best, 0.0)
@@ -50,12 +55,20 @@ def maxsim_scores(q, q_mask, docs, docs_mask, *, block: int = 1024):
     return jnp.moveaxis(out, 0, 1).reshape(q.shape[0], nb * block)[:, :m]
 
 
-def token_maxsim(x, docs, docs_mask, *, block: int = 1024):
+BLOCK_BYTES = 1 << 30   # the (n, block, T) fp32 score block of token_maxsim
+
+
+def token_maxsim(x, docs, docs_mask, *, block: int | None = None):
     """g(x)_l = max_{c in C_l} <c, x>  (§3.1).  x: (n, d) -> (n, m) fp32.
 
     This is both the OLS/MLP training target generator and the per-token
-    inner loop of reranking."""
+    inner loop of reranking.  The corpus axis is processed ``block`` docs
+    at a time; by default as many (up to 1024) as keep the ``(n, block,
+    T)`` score block within :data:`BLOCK_BYTES`."""
     m = docs.shape[0]
+    if block is None:
+        per_doc = x.shape[0] * docs.shape[1] * 4
+        block = max(1, min(1024, BLOCK_BYTES // max(per_doc, 1)))
 
     def blk(d, dm):
         s = jnp.einsum("nd,mtd->nmt", x, d, preferred_element_type=jnp.float32)
@@ -88,7 +101,8 @@ def rerank(q, q_mask, cand_ids, docs, docs_mask, k: int):
     safe = jnp.maximum(cand_ids, 0)
     cd = jnp.take(docs, safe, axis=0)           # (B, k', Td, d)
     cm = jnp.take(docs_mask, safe, axis=0)      # (B, k', Td)
-    s = jnp.einsum("bqd,bmtd->bmqt", q, cd, preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqd,bmtd->bmqt", q, cd, precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
     s = jnp.where(cm[:, :, None, :], s, NEG)
     best = jnp.max(s, axis=-1)
     best = jnp.where(q_mask[:, None, :], best, 0.0)
@@ -108,7 +122,7 @@ def rerank_gathered(q, q_mask, cand_ids, cand_docs, cand_mask, k: int):
     :func:`rerank`; per-token dots and the order-independent max make the
     scores bit-identical to the dense layout's."""
     valid = cand_ids >= 0
-    s = jnp.einsum("bqd,bmtd->bmqt", q, cand_docs,
+    s = jnp.einsum("bqd,bmtd->bmqt", q, cand_docs, precision=HIGHEST,
                    preferred_element_type=jnp.float32)
     s = jnp.where(cand_mask[:, :, None, :], s, NEG)
     best = jnp.max(s, axis=-1)

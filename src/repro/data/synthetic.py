@@ -59,11 +59,18 @@ def make_corpus(
 
     tok = rng.standard_normal((m, max_tokens, d), dtype=np.float32)
     which = rng.integers(0, topics_per_doc, size=(m, max_tokens))
-    c = centers[np.take_along_axis(topics, which, axis=1)]  # (m, T, d)
-    tok = _unit(tok + topic_strength * c)
     mask = np.arange(max_tokens)[None, :] < counts[:, None]
-    tok = tok * mask[..., None]
-    return MultiVectorCorpus(tok.astype(np.float32), mask, topics, centers)
+    # finished in place, a block of docs at a time: the same values as the
+    # whole-array expressions, without (m, T, d) temporaries
+    for lo in range(0, m, 4096):
+        t = tok[lo:lo + 4096]
+        c = centers[np.take_along_axis(topics[lo:lo + 4096],
+                                       which[lo:lo + 4096], axis=1)]
+        c *= topic_strength
+        t += c
+        t /= np.maximum(np.linalg.norm(t, axis=-1, keepdims=True), 1e-9)
+        t *= mask[lo:lo + 4096, :, None]
+    return MultiVectorCorpus(tok, mask, topics, centers)
 
 
 def queries_from_corpus_query(

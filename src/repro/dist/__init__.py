@@ -1,6 +1,5 @@
 """Multi-device layer: sharding rule tables + the LEMUR corpus-sharded
-serving/indexing steps (both built on ``repro.common.compat``, so they run
-on every supported jax).
+serving/indexing steps.
 
 * :mod:`repro.dist.sharding` — regex rule tables mapping parameter names to
   PartitionSpecs (``LM_RULES`` / ``LM_RULES_FFSLICE`` / ``RECSYS_RULES`` /
@@ -11,6 +10,7 @@ on every supported jax).
 """
 from repro.dist.serve import (
     ShardedRetrievalState,
+    auto_axes,
     corpus_axes,
     default_k_prime_local,
     make_index_step,
@@ -33,6 +33,7 @@ __all__ = [
     "RECSYS_RULES",
     "ShardedRetrievalState",
     "ShardingRules",
+    "auto_axes",
     "corpus_axes",
     "default_k_prime_local",
     "make_index_step",

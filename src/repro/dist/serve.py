@@ -21,14 +21,24 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map
 from repro.core import maxsim
 from repro.core.config import LemurConfig
 from repro.core.model import pool_queries
 from repro.kernels import ops
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The serve step lays out its
+    operands with sharding constraints and lets the partitioner place the
+    rest, which only ``Auto`` axes allow; ``jax.make_mesh`` gives
+    ``Explicit`` axes by default."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def corpus_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -154,6 +164,7 @@ def _local_retrieve(psi_q, W, W_scales, doc_tokens, doc_scales, doc_mask,
         # s*(q.c) — avoids materializing a dequantized (B,k',Td,d) fp copy
         # (the fused kernel path does the same dequant in-VMEM on TPU)
         sc = jnp.einsum("bqd,bmtd->bmqt", q_tokens, cd,
+                        precision=maxsim.HIGHEST,
                         preferred_element_type=jnp.float32)
         sc = sc * cs.astype(jnp.float32)[:, :, None, :]
         sc = jnp.where(cm[:, :, None, :], sc, -1e30)
@@ -217,6 +228,7 @@ def make_serve_step(mesh: Mesh, cfg: LemurConfig, *,
     SQ8-requantized per row), never on the serve path — so the knob only
     pins the compiled-step identity to match the single-device facade's
     (backend, resolved-params) cache contract."""
+    mesh = auto_axes(mesh)
     axes = corpus_axes(mesh)
     axis_sizes = tuple(mesh.shape[a] for a in axes)
     n_shards = int(np.prod(axis_sizes))
@@ -258,7 +270,7 @@ def make_serve_step(mesh: Mesh, cfg: LemurConfig, *,
                     corpus_spec, corpus_spec if sq8 else P(), corpus_spec,
                     corpus_spec if rows else P(),
                     corpus_spec if rows else P(), P(), P())
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=in_specs,
@@ -284,7 +296,7 @@ def make_index_step(mesh: Mesh, cfg: LemurConfig, *, doc_block: int = 128):
         return jax.scipy.linalg.cho_solve((chol_c, False), rhs).T
 
     def index_step(chol_c, feats, x_ols, doc_tokens, doc_mask, mean, std):
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(), P(), corpus_spec, corpus_spec, P(), P()),

@@ -17,21 +17,27 @@ O(k'·Td·d) source bytes streamed exactly once; nothing is materialized.
 Consecutive grid steps double-buffer their DMAs automatically (the Pallas
 grid pipeline), so the scan runs at HBM bandwidth.
 
-``ivf_probe_scan`` — grid ``(B, nprobe)``; step ``(b, p)`` DMAs cluster
-``probe[b, p]``'s ``(cap, d)`` list (fp32, or int8 codes dequantized
-in-kernel via the same hi/lo-bf16 split as ``mips_sq8``), scores it against
+``ivf_probe_scan`` — grid ``(B, nprobe, cap/bc)``; step ``(b, p, t)`` DMAs
+cap-tile ``t`` (``bc`` rows, :func:`cap_tile`) of cluster ``probe[b, p]``'s
+list (fp32, or int8 codes dequantized
+in-kernel via the same exact bf16 split as ``mips_sq8``), scores it against
 query row ``b`` in one MXU matmul, masks ``-1`` pad slots to ``-inf`` and
 writes a compact ``(B, nprobe, cap)`` score strip (the top-k' runs on the
 strip outside, like the legacy path — bit-identical ids on fp32).
 
-VMEM per step (cap=4096, d=128): fp32 cluster tile 2 MiB (int8: 512 KiB +
-16 KiB scales), query row 512 B, score strip 16 KiB — ×2 for the pipeline's
-double buffer, comfortably inside ~16 MiB v5e VMEM.
+VMEM per step: a list tile of at most 1 MiB, the query row and a ``(1,
+bc)`` score strip — ×2 for the pipeline's double buffer, inside v5e VMEM at
+any cap and d.  Row vectors travel with a unit axis (``(B, 1, d)``,
+``(nlist, 1, cap)``) so every block's last two dims are the array's own or
+(8, 128)-aligned, as the TPU lowering requires.
 
 ``rerank_gather_scores`` — grid ``(B, k')``; step ``(b, c)`` DMAs candidate
 ``cand[b, c]``'s ``(Td, d)`` token slab (fp or int8 + per-token scales),
 computes the masked ``(Tq × Td)`` MXU contraction, token-max and
-query-masked sum entirely in VMEM, and writes the single MaxSim score.
+query-masked sum entirely in VMEM, and writes the MaxSim score into lane
+``c`` of query ``b``'s ``(1, k')`` output strip, which stays in VMEM
+across the candidate steps.  A k' whose prefetched ids would overflow
+SMEM is split into chunks scored as rows of their own.
 ``-1`` candidates are clamped to doc 0 for the DMA and masked by the
 caller (``ops.fused_rerank``), matching ``core.maxsim.rerank``.
 
@@ -50,7 +56,9 @@ scores it against the query slab, masks token positions ``>= n_tokens`` to
 ``NEG``, and folds a per-query-token running max carried in VMEM scratch
 across the ``pmax`` minor steps (the TPU grid iterates the last dimension
 innermost, so the scratch persists per candidate); the final step applies
-the query mask and writes the single MaxSim score.  Because per-token dots
+the query mask and writes the MaxSim score into the output strip.  The
+page-id strips are flat per query and split, with their queries, into
+groups that fit SMEM.  Because per-token dots
 are unchanged and max is order-independent, scores are bit-identical to the
 dense-slab kernel's on the same docs.
 """
@@ -63,7 +71,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mips_sq8 import split_dot
+
 NEG = -1e30
+# fp32 operands contract at full fp32 precision in every kernel, as in the
+# XLA reference paths: the exact rerank must not round through bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -71,32 +84,33 @@ NEG = -1e30
 # --------------------------------------------------------------------------
 
 def _ivf_scan_fp_kernel(probe_ref, q_ref, ids_ref, vecs_ref, out_ref):
-    # q: (1, d); ids: (1, cap); vecs: (1, cap, d) — ONE cluster, DMA'd by the
-    # index_map from the prefetched probe id; out: (1, 1, cap) score strip
-    q = q_ref[...]
-    _, cap, d = vecs_ref.shape
+    # q: (1, d); ids: (1, bc); vecs: (bc, d) — one cap-tile of ONE cluster,
+    # DMA'd by the index_map from the prefetched probe id; out: (1, bc)
     s = jax.lax.dot_general(
-        q, vecs_ref[...].reshape(cap, d), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (1, cap)
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf).reshape(1, 1, cap)
+        q_ref[...], vecs_ref[...], (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
+    )  # (1, bc)
+    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
 
 
 def _ivf_scan_sq8_kernel(probe_ref, q_ref, ids_ref, codes_ref, scales_ref,
                          out_ref):
-    # int8 cluster codes dequantized IN-KERNEL: hi/lo bf16 split of the fp32
-    # query (two MXU passes) x bf16-widened codes, per-slot scales folded
-    # into the score strip — matches kernels.mips_sq8 to ~2^-16 relative
-    q = q_ref[...]                                   # (1, d) fp32
-    _, cap, d = codes_ref.shape
-    c = codes_ref[...].reshape(cap, d).astype(jnp.bfloat16)
-    q_hi = q.astype(jnp.bfloat16)
-    q_lo = (q - q_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dot = lambda a: jax.lax.dot_general(
-        a, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = (dot(q_hi) + dot(q_lo)) * scales_ref[...]    # (1, cap)
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf).reshape(1, 1, cap)
+    # int8 cluster codes dequantized IN-KERNEL: the exact bf16 split of the
+    # fp32 query x bf16-widened codes (kernels.mips_sq8.split_dot), per-slot
+    # scales folded into the score strip
+    s = split_dot(q_ref[...], codes_ref[...]) * scales_ref[...]   # (1, bc)
+    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
+
+
+def cap_tile(cap: int, row_bytes: int, budget: int = 1 << 20) -> int:
+    """Rows of one cluster list streamed per grid step: the whole list when
+    it fits ``budget`` bytes of VMEM, else the largest halving of ``cap``
+    that fits and stays a multiple of 128 (the lane width — the tile is
+    also the last dim of the id/score blocks)."""
+    bc = cap
+    while bc * row_bytes > budget and bc % 256 == 0:
+        bc //= 2
+    return bc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -106,56 +120,75 @@ def ivf_probe_scan(q, probe, ids, vecs, scales=None, *, interpret: bool = False)
     q: (B, d) fp32; probe: (B, nprobe) int32 cluster ids; ids: (nlist, cap)
     int32 (-1 padded); vecs: (nlist, cap, d) fp32 — or int8 codes with
     scales: (nlist, cap) — returns (B, nprobe, cap) fp32 scores with pad
-    slots at ``-inf``.  Each grid step DMAs only cluster ``probe[b, p]``.
+    slots at ``-inf``.  Each grid step DMAs one cap-tile of cluster
+    ``probe[b, p]``.  Row vectors travel with a unit axis (``(B, 1, d)``,
+    ``(nlist, 1, cap)``) so every block's last two dims are either the
+    array's own or (8, 128)-aligned, as the TPU lowering requires.
     """
     B, d = q.shape
     nprobe = probe.shape[1]
     nlist, cap = ids.shape
-    grid = (B, nprobe)
+    bc = cap_tile(cap, d * vecs.dtype.itemsize)
+    row = lambda b, p, t, pr: (pr[b, p], 0, t)
     in_specs = [
-        pl.BlockSpec((1, d), lambda b, p, pr: (b, 0)),
-        pl.BlockSpec((1, cap), lambda b, p, pr: (pr[b, p], 0)),
-        pl.BlockSpec((1, cap, d), lambda b, p, pr: (pr[b, p], 0, 0)),
+        pl.BlockSpec((None, 1, d), lambda b, p, t, pr: (b, 0, 0)),
+        pl.BlockSpec((None, 1, bc), row),
+        pl.BlockSpec((None, bc, d), lambda b, p, t, pr: (pr[b, p], t, 0)),
     ]
-    args = [q, ids, vecs]
+    args = [q.reshape(B, 1, d), ids.reshape(nlist, 1, cap), vecs]
     kernel = _ivf_scan_fp_kernel
     if scales is not None:
-        in_specs.append(pl.BlockSpec((1, cap), lambda b, p, pr: (pr[b, p], 0)))
-        args.append(scales)
+        in_specs.append(pl.BlockSpec((None, 1, bc), row))
+        args.append(scales.reshape(nlist, 1, cap))
         kernel = _ivf_scan_sq8_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(B, nprobe, cap // bc),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, cap), lambda b, p, pr: (b, p, 0)),
+        out_specs=pl.BlockSpec((None, None, 1, bc),
+                               lambda b, p, t, pr: (b, p, 0, t)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nprobe, cap), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, nprobe, 1, cap), jnp.float32),
         interpret=interpret,
     )(probe.astype(jnp.int32), *args)
+    return out.reshape(B, nprobe, cap)
 
 
 # --------------------------------------------------------------------------
 # fused candidate-gather MaxSim rerank
 # --------------------------------------------------------------------------
 
+def _put_lane(out_ref, c, val):
+    """Write the (1, 1) ``val`` into lane ``c`` of the (1, k') output strip
+    (a select — the TPU has no scalar store into a vector block).  The
+    strip stays resident in VMEM across the candidate steps of one query
+    and is written back once."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out_ref[...] = jnp.where(lane == c, val, out_ref[...])
+
+
+def _maxsim_flush(best, qm_ref):
+    """(Tq, 1) per-query-token maxima -> (1, 1) query-masked sum."""
+    best = jnp.where(qm_ref[...] > 0, best, 0.0)
+    return jnp.sum(best, axis=0, keepdims=True)
+
+
 def _rerank_fp_kernel(cand_ref, q_ref, qm_ref, docs_ref, dm_ref, out_ref):
-    # q: (1, Tq, d); docs: (1, Td, d) — ONE candidate's token slab, DMA'd by
-    # the index_map from the prefetched (clamped) candidate id; the masks
-    # arrive pre-gathered per (b, c) (they are Td bytes against the slab's
-    # Td·d·4 — see rerank_gather_scores); out: (1, 1)
-    _, Tq, d = q_ref.shape
-    _, Td, _ = docs_ref.shape
+    # q: (Tq, d); qm: (Tq, 1); docs: (Td, d) — ONE candidate's token slab,
+    # DMA'd by the index_map from the prefetched (clamped) candidate id; the
+    # mask dm: (1, Td) arrives pre-gathered per (b, c) (Td entries against
+    # the slab's Td·d — see rerank_gather_scores); out: (1, k') strip of b
+    c = pl.program_id(1)
     s = jax.lax.dot_general(
-        q_ref[...].reshape(Tq, d), docs_ref[...].reshape(Td, d),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        q_ref[...], docs_ref[...], (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )  # (Tq, Td)
-    s = jnp.where(dm_ref[...].reshape(1, Td) > 0, s, NEG)
-    best = jnp.max(s, axis=-1)                       # (Tq,)
-    best = jnp.where(qm_ref[...].reshape(Tq) > 0, best, 0.0)
-    out_ref[...] = jnp.sum(best).reshape(1, 1)
+    s = jnp.where(dm_ref[...] > 0, s, NEG)
+    best = jnp.max(s, axis=-1, keepdims=True)        # (Tq, 1)
+    _put_lane(out_ref, c, _maxsim_flush(best, qm_ref))
 
 
 def _rerank_sq8_kernel(cand_ref, q_ref, qm_ref, codes_ref, dm_ref, ds_ref,
@@ -163,20 +196,55 @@ def _rerank_sq8_kernel(cand_ref, q_ref, qm_ref, codes_ref, dm_ref, ds_ref,
     # per-token scales fold into the SCORE rows — score(q, s·c) = s·(q·c) —
     # so the dequantized fp slab never materializes (same identity the
     # sharded serve step used in jnp, now in VMEM)
-    _, Tq, d = q_ref.shape
-    _, Td, _ = codes_ref.shape
-    q = q_ref[...].reshape(Tq, d)
-    c = codes_ref[...].reshape(Td, d).astype(jnp.bfloat16)
-    q_hi = q.astype(jnp.bfloat16)
-    q_lo = (q - q_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dot = lambda a: jax.lax.dot_general(
-        a, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = (dot(q_hi) + dot(q_lo)) * ds_ref[...].reshape(1, Td)
-    s = jnp.where(dm_ref[...].reshape(1, Td) > 0, s, NEG)
-    best = jnp.max(s, axis=-1)
-    best = jnp.where(qm_ref[...].reshape(Tq) > 0, best, 0.0)
-    out_ref[...] = jnp.sum(best).reshape(1, 1)
+    c_id = pl.program_id(1)
+    s = split_dot(q_ref[...], codes_ref[...]) * ds_ref[...]
+    s = jnp.where(dm_ref[...] > 0, s, NEG)
+    best = jnp.max(s, axis=-1, keepdims=True)
+    _put_lane(out_ref, c_id, _maxsim_flush(best, qm_ref))
+
+
+def _query_rows(q_mask):
+    """(B, Tq) query mask -> (B, Tq, 1) int32: one column per query, so its
+    block is the array's own last two dims."""
+    return q_mask.astype(jnp.int32)[..., None]
+
+
+SMEM_PREFETCH_BYTES = 256 << 10   # of the v5e core's 1 MiB SMEM
+
+
+def _cand_chunk(kp: int, words: int) -> int:
+    """Candidates per kernel row: k' itself when one query's prefetched
+    strips (``words`` int32 per candidate) fit :data:`SMEM_PREFETCH_BYTES`,
+    else the largest divisor of k' that does."""
+    fit = max(1, SMEM_PREFETCH_BYTES // (4 * words))
+    return max(c for c in range(1, min(kp, fit) + 1) if kp % c == 0)
+
+
+def _fold_rows(nc: int, *xs):
+    """Repeat every query row ``nc`` times, one copy per candidate chunk:
+    (B, ...) -> (B·nc, ...), matching ``cand.reshape(B·nc, k'/nc)``."""
+    return [jnp.repeat(x, nc, axis=0) for x in xs]
+
+
+def _over_row_chunks(call, prefetch, per_row):
+    """Run ``call(*prefetch_flat, *per_row)`` over groups of rows small
+    enough that the scalar-prefetched strips (``prefetch``: (R, n) int32
+    each, flattened per group) fit :data:`SMEM_PREFETCH_BYTES`.  Groups run
+    in a ``lax.map``, so one kernel is compiled however many there are;
+    rows are independent and the results stack on the row axis."""
+    R = prefetch[0].shape[0]
+    per = sum(a.shape[1] for a in prefetch) * 4
+    bb = max([c for c in range(1, R + 1)
+              if R % c == 0 and c * per <= SMEM_PREFETCH_BYTES] or [1])
+    flat = lambda a: a.reshape(-1)
+    if bb == R:
+        return call(*map(flat, prefetch), *per_row)
+    group = lambda a: a.reshape((R // bb, bb) + a.shape[1:])
+    n_pf = len(prefetch)
+    out = jax.lax.map(
+        lambda xs: call(*map(flat, xs[:n_pf]), *xs[n_pf:]),
+        tuple(map(group, (*prefetch, *per_row))))
+    return out.reshape((R,) + out.shape[2:])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -188,41 +256,52 @@ def rerank_gather_scores(q, q_mask, cand_ids, doc_tokens, doc_mask,
     q: (B, Tq, d); cand_ids: (B, k') int32 (-1 padded — pads are clamped to
     doc 0 here and must be masked by the caller); doc_tokens: (m, Td, d) fp
     — or int8 codes with doc_scales: (m, Td) — returns (B, k') fp32 raw
-    pair scores.
+    pair scores.  The candidate ids are scalar-prefetched to SMEM; a k'
+    too long for it is split into chunks, each scored as a row of its own.
     """
     B, Tq, d = q.shape
     kp = cand_ids.shape[1]
     m, Td, _ = doc_tokens.shape
-    safe = jnp.maximum(cand_ids, 0).astype(jnp.int32)
-    qm = q_mask.astype(jnp.int8)
+    kc = _cand_chunk(kp, 1)
+    nc = kp // kc
+    safe = jnp.maximum(cand_ids, 0).astype(jnp.int32).reshape(B * nc, kc)
     # masks (and SQ8 scales) are gathered per candidate in XLA — B·k'·Td
     # slots, tiny next to the (Td, d) token slabs the kernel streams, and it
     # avoids converting/copying the corpus-sized (m, Td) mask every call
-    dm = jnp.take(doc_mask, safe, axis=0).astype(jnp.int8)   # (B, k', Td)
-    in_specs = [
-        pl.BlockSpec((1, Tq, d), lambda b, c, cr: (b, 0, 0)),
-        pl.BlockSpec((1, Tq), lambda b, c, cr: (b, 0)),
-        pl.BlockSpec((1, Td, d), lambda b, c, cr: (cr[b, c], 0, 0)),
-        pl.BlockSpec((1, 1, Td), lambda b, c, cr: (b, c, 0)),
-    ]
-    args = [q, qm, doc_tokens, dm]
+    per_cand = lambda a: jnp.take(a, safe, axis=0).reshape(B * nc, kc, 1, Td)
+    q, q_mask = _fold_rows(nc, q, q_mask)
+    per_row = [q, _query_rows(q_mask), per_cand(doc_mask.astype(jnp.int32))]
     kernel = _rerank_fp_kernel
     if doc_scales is not None:
-        in_specs.append(pl.BlockSpec((1, 1, Td), lambda b, c, cr: (b, c, 0)))
-        args.append(jnp.take(doc_scales, safe, axis=0))
+        per_row.append(per_cand(doc_scales))
         kernel = _rerank_sq8_kernel
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, kp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda b, c, cr: (b, c)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kp), jnp.float32),
-        interpret=interpret,
-    )(safe, *args)
+
+    def call(cr, *rows):
+        bb = rows[0].shape[0]
+        strip = pl.BlockSpec((None, None, 1, Td),
+                             lambda b, c, cr: (b, c, 0, 0))
+        in_specs = [
+            pl.BlockSpec((None, Tq, d), lambda b, c, cr: (b, 0, 0)),
+            pl.BlockSpec((None, Tq, 1), lambda b, c, cr: (b, 0, 0)),
+            pl.BlockSpec((None, Td, d),
+                         lambda b, c, cr: (cr[b * kc + c], 0, 0)),
+            *[strip] * (len(rows) - 2),
+        ]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bb, kc),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, 1, kc), lambda b, c, cr: (b, 0, 0)),
+        )
+        q, qm, *cand = rows
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
+            interpret=interpret,
+        )(cr, q, qm, doc_tokens, *cand)
+
+    return _over_row_chunks(call, (safe,), per_row).reshape(B, kp)
 
 
 # --------------------------------------------------------------------------
@@ -231,30 +310,45 @@ def rerank_gather_scores(q, q_mask, cand_ids, doc_tokens, doc_mask,
 
 def _rerank_paged_fp_kernel(pt_ref, nt_ref, q_ref, qm_ref, page_ref, out_ref,
                             acc_ref, *, pmax):
-    # q: (1, Tq, d); page: (1, page, d) — ONE token page, DMA'd by the
-    # index_map from the prefetched page id pt[b, c, j]; acc: (Tq, 1) VMEM
-    # running per-query-token max, carried across the pmax minor grid steps
+    # q: (Tq, d); page: (page, d) — ONE token page, DMA'd by the index_map
+    # from the prefetched flat page-id strip; acc: (Tq, 1) VMEM running
+    # per-query-token max, carried across the pmax minor grid steps
     b, c, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.full(acc_ref.shape, NEG, jnp.float32)
 
-    _, Tq, d = q_ref.shape
-    _, page, _ = page_ref.shape
+    Tq = q_ref.shape[0]
+    page = page_ref.shape[0]
     s = jax.lax.dot_general(
-        q_ref[...].reshape(Tq, d), page_ref[...].reshape(page, d),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        q_ref[...], page_ref[...], (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )  # (Tq, page)
     pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (Tq, page), 1)
-    s = jnp.where(pos < nt_ref[b, c], s, NEG)
+    s = jnp.where(pos < nt_ref[b * pl.num_programs(1) + c], s, NEG)
     acc_ref[...] = jnp.maximum(acc_ref[...],
                                jnp.max(s, axis=-1, keepdims=True))
 
     @pl.when(j == pmax - 1)
     def _flush():
-        best = jnp.where(qm_ref[...].reshape(Tq, 1) > 0, acc_ref[...], 0.0)
-        out_ref[...] = jnp.sum(best).reshape(1, 1)
+        _put_lane(out_ref, c, _maxsim_flush(acc_ref[...], qm_ref))
+
+
+def _paged_prefetch(cand_ids, page_table, n_tokens, pmax):
+    """Fold k' into SMEM-sized candidate chunks (see :func:`_cand_chunk`)
+    and gather, per chunk row, the candidates' page-id strip (R, kc·pmax),
+    flat so SMEM does not pad a short pmax axis out to a full word row, and
+    token counts (R, kc) for scalar prefetch: pads/dead slots clamp to page
+    0 with 0 tokens.  Returns (kc, nc, pt, nt)."""
+    B, kp = cand_ids.shape
+    kc = _cand_chunk(kp, pmax + 1)
+    nc = kp // kc
+    cand_ids = cand_ids.reshape(B * nc, kc)
+    safe = jnp.maximum(cand_ids, 0).astype(jnp.int32)
+    pt = jnp.maximum(jnp.take(page_table, safe, axis=0), 0).astype(jnp.int32)
+    nt = jnp.take(n_tokens, safe, axis=0).astype(jnp.int32)
+    return kc, nc, pt.reshape(B * nc, -1), jnp.where(cand_ids >= 0, nt, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -274,29 +368,33 @@ def rerank_paged_scores(q, q_mask, cand_ids, tok_pages, page_table, n_tokens,
     kp = cand_ids.shape[1]
     _, page, _ = tok_pages.shape
     pmax = page_table.shape[1]
-    safe = jnp.maximum(cand_ids, 0).astype(jnp.int32)
-    pt = jnp.maximum(jnp.take(page_table, safe, axis=0), 0).astype(jnp.int32)
-    nt = jnp.take(n_tokens, safe, axis=0).astype(jnp.int32)
-    nt = jnp.where(cand_ids >= 0, nt, 0)         # (B, k')
-    qm = q_mask.astype(jnp.int8)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, kp, pmax),
-        in_specs=[
-            pl.BlockSpec((1, Tq, d), lambda b, c, j, pt, nt: (b, 0, 0)),
-            pl.BlockSpec((1, Tq), lambda b, c, j, pt, nt: (b, 0)),
-            pl.BlockSpec((1, page, d),
-                         lambda b, c, j, pt, nt: (pt[b, c, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, c, j, pt, nt: (b, c)),
-        scratch_shapes=[pltpu.VMEM((Tq, 1), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_rerank_paged_fp_kernel, pmax=pmax),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kp), jnp.float32),
-        interpret=interpret,
-    )(pt, nt, q, qm, tok_pages)
+    kc, nc, pt, nt = _paged_prefetch(cand_ids, page_table, n_tokens, pmax)
+    pg = lambda b, c, j, pt, nt: (pt[(b * kc + c) * pmax + j], 0, 0)
+
+    def call(pt, nt, q, qm):
+        bb = q.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bb, kc, pmax),
+            in_specs=[
+                pl.BlockSpec((None, Tq, d), lambda b, c, j, pt, nt: (b, 0, 0)),
+                pl.BlockSpec((None, Tq, 1), lambda b, c, j, pt, nt: (b, 0, 0)),
+                pl.BlockSpec((None, page, d), pg),
+            ],
+            out_specs=pl.BlockSpec((None, 1, kc),
+                                   lambda b, c, j, pt, nt: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((Tq, 1), jnp.float32)],
+        )
+        return pl.pallas_call(
+            functools.partial(_rerank_paged_fp_kernel, pmax=pmax),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
+            interpret=interpret,
+        )(pt, nt, q, qm, tok_pages)
+
+    q, q_mask = _fold_rows(nc, q, q_mask)
+    out = _over_row_chunks(call, (pt, nt), (q, _query_rows(q_mask)))
+    return out.reshape(B, kp)
 
 
 # --------------------------------------------------------------------------
@@ -306,13 +404,15 @@ def rerank_paged_scores(q, q_mask, cand_ids, tok_pages, page_table, n_tokens,
 # The compressed corpus stores each token as a centroid id (int32) plus a
 # packed 2/4-bit per-dim residual code (``repro.anns.quantization``).  The
 # kernels below decode INSIDE the grid — the fp32 token slab never exists in
-# HBM — generalizing the SQ8 hi/lo-bf16 trick from "scale a cheap int8 dot"
+# HBM — generalizing the SQ8 bf16-split trick from "scale a cheap int8 dot"
 # to "reconstruct, then dot".  Mosaic has no dynamic-gather primitive, so
 # the decode avoids gathers entirely:
 #
 # * packed codes unpack with int32 shifts/ANDs (vector ALU);
-# * per-dim reconstruction values resolve by a select-sum over the L static
-#   levels (``sum_l values[:, l] * (idx == l)``);
+# * per-dim reconstruction values resolve by a select-sum over the L
+#   levels (``sum_l values[:, l] * (idx == l)``), looped so one (n, d)
+#   accumulator lives in VMEM; the table travels transposed, (L, d), so a
+#   level is one row;
 # * centroid rows resolve by a one-hot MXU matmul
 #   (``onehot(cent, ncent) @ centroids``).
 #
@@ -326,23 +426,63 @@ def _unpack_codes_i32(codes, *, bits):
     """Packed (n, db) uint8 -> (n, db * 8//bits) int32 bucket indices.
 
     Same little-endian-within-byte layout as ``quantization.pack_codes``:
-    dim ``i*per + j`` sits at bit ``bits*j`` of byte ``i``."""
+    dim ``i*per + j`` sits at bit ``bits*j`` of byte ``i``.  Each byte is
+    first spread over its ``per`` dims by a 0/1 expansion matmul (byte
+    values are integers below 256, exact in every MXU precision) — the TPU
+    has no lane interleave — then each dim shifts out its own bit field."""
     per = 8 // bits
-    mask = (1 << bits) - 1
-    b = codes.astype(jnp.int32)
-    parts = [(b >> (bits * j)) & mask for j in range(per)]
-    idx = jnp.stack(parts, axis=-1)                    # (n, db, per)
-    return idx.reshape(idx.shape[0], idx.shape[1] * per)
+    n, db = codes.shape
+    w = 128 if db % 128 == 0 else db
+    x = codes.astype(jnp.int32).astype(jnp.float32)
+    spread = (jax.lax.broadcasted_iota(jnp.int32, (w, w * per), 1) // per
+              == jax.lax.broadcasted_iota(jnp.int32, (w, w * per), 0)
+              ).astype(jnp.float32)
+    parts = [jax.lax.dot_general(x[:, u:u + w], spread,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+             for u in range(0, db, w)]
+    full = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    shift = (jax.lax.broadcasted_iota(jnp.int32, full.shape, 1) % per) * bits
+    return (full.astype(jnp.int32) >> shift) & ((1 << bits) - 1)
 
 
-def _residual_values(idx, values):
-    """Bucket indices (n, d) + per-dim tables (d, L) -> (n, d) fp32 via a
-    select-sum over the L static levels (exactly one nonzero term/element)."""
-    L = values.shape[1]
-    res = jnp.zeros(idx.shape, jnp.float32)
-    for l in range(L):
-        res = res + jnp.where(idx == l, values[:, l][None, :], 0.0)
-    return res
+def _residual_values(idx, vt_ref):
+    """Bucket indices (n, d) + the transposed per-dim table (L, d), as a
+    kernel ref -> (n, d) fp32 via a select-sum over the L levels (exactly
+    one nonzero term per element, so the loop order cannot change a bit)."""
+    def level(l, res):
+        return res + jnp.where(idx == l, vt_ref[pl.ds(l, 1), :], 0.0)
+
+    return jax.lax.fori_loop(0, vt_ref.shape[0], level,
+                             jnp.zeros(idx.shape, jnp.float32))
+
+
+class _Rows:
+    """Array stand-in for a (L, d) ref: ``[pl.ds(l, 1), :]`` slicing."""
+
+    def __init__(self, a):
+        self.a, self.shape = a, a.shape
+
+    def __getitem__(self, key):
+        return jax.lax.dynamic_slice_in_dim(self.a, key[0].start, 1, 0)
+
+
+def _decode_rows(cent_row, codes, centroids, vt, *, bits):
+    """Kernel-side residual decode: cent_row (1, n) int32 centroid ids (a
+    row: the one-hot is built transposed, (ncent, n), and contracted on its
+    leading axis, so no id column is ever needed); codes (n, db) uint8; vt
+    (L, d) the transposed level table -> (n, d) fp32."""
+    n = codes.shape[0]
+    ncent = centroids.shape[0]
+    idx = _unpack_codes_i32(codes, bits=bits)          # (n, d)
+    res = _residual_values(idx, vt)                    # (n, d)
+    onehot_t = (cent_row == jax.lax.broadcasted_iota(jnp.int32, (ncent, n), 0)
+                ).astype(jnp.float32)
+    cvec = jax.lax.dot_general(
+        onehot_t, centroids, (((0,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
+    )                                                  # (n, d)
+    return cvec + res
 
 
 def residual_decode_onehot(cent, codes, centroids, values, *, bits):
@@ -351,35 +491,23 @@ def residual_decode_onehot(cent, codes, centroids, values, *, bits):
     cent: (n,) int32 centroid ids; codes: (n, db) uint8 packed residuals;
     centroids: (ncent, d) fp32; values: (d, L) fp32 -> (n, d) fp32,
     bit-identical to ``quantization.residual_decode`` on the same inputs."""
-    n = cent.shape[0]
-    ncent = centroids.shape[0]
-    idx = _unpack_codes_i32(codes, bits=bits)          # (n, d)
-    res = _residual_values(idx, values)                # (n, d)
-    onehot = (cent[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (n, ncent), 1)
-              ).astype(jnp.float32)
-    cvec = jax.lax.dot_general(
-        onehot, centroids, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                  # (n, d)
-    return cvec + res
+    return _decode_rows(cent[None, :], codes, centroids, _Rows(values.T),
+                        bits=bits)
 
 
 def _ivf_scan_res_kernel(probe_ref, q_ref, ids_ref, codes_ref, cent_ref,
                          val_ref, out_ref, *, bits):
-    # codes: (1, cap, db) packed residuals of ONE cluster; cent: (1, d) the
-    # SAME cluster's centroid row (IVF storage codes each vector against its
-    # own cluster, so the id is implicit in the list and both tiles are
-    # DMA'd by the one prefetched probe id) — no one-hot lookup needed here
-    q = q_ref[...]                                     # (1, d) fp32
-    _, cap, db = codes_ref.shape
-    idx = _unpack_codes_i32(codes_ref[...].reshape(cap, db), bits=bits)
-    v = _residual_values(idx, val_ref[...]) + cent_ref[...]   # (cap, d)
+    # codes: (bc, db) packed residuals of one cap-tile of ONE cluster; cent:
+    # (1, d) the SAME cluster's centroid row (IVF storage codes each vector
+    # against its own cluster, so the id is implicit in the list and both
+    # tiles are DMA'd by the one prefetched probe id) — no one-hot lookup
+    idx = _unpack_codes_i32(codes_ref[...], bits=bits)
+    v = _residual_values(idx, val_ref) + cent_ref[...]        # (bc, d)
     s = jax.lax.dot_general(
-        q, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    )  # (1, cap)
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf).reshape(
-        1, 1, out_ref.shape[-1])
+        q_ref[...], v, (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
+    )  # (1, bc)
+    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -399,57 +527,65 @@ def ivf_probe_res_scan(q, probe, ids, codes, centroids, values, *,
     db = codes.shape[2]
     L = values.shape[1]
     bits = int(L).bit_length() - 1
+    # the decoded (bc, d) fp32 tile and its int32 bucket indices live in
+    # VMEM next to the packed codes
+    bc = cap_tile(cap, 8 * d)
+    row = lambda b, p, t, pr: (pr[b, p], 0, t)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, nprobe),
+        grid=(B, nprobe, cap // bc),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, p, pr: (b, 0)),
-            pl.BlockSpec((1, cap), lambda b, p, pr: (pr[b, p], 0)),
-            pl.BlockSpec((1, cap, db), lambda b, p, pr: (pr[b, p], 0, 0)),
-            pl.BlockSpec((1, d), lambda b, p, pr: (pr[b, p], 0)),
-            pl.BlockSpec((d, L), lambda b, p, pr: (0, 0)),
+            pl.BlockSpec((None, 1, d), lambda b, p, t, pr: (b, 0, 0)),
+            pl.BlockSpec((None, 1, bc), row),
+            pl.BlockSpec((None, bc, db), lambda b, p, t, pr: (pr[b, p], t, 0)),
+            pl.BlockSpec((None, 1, d), lambda b, p, t, pr: (pr[b, p], 0, 0)),
+            pl.BlockSpec((L, d), lambda b, p, t, pr: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, cap), lambda b, p, pr: (b, p, 0)),
+        out_specs=pl.BlockSpec((None, None, 1, bc),
+                               lambda b, p, t, pr: (b, p, 0, t)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ivf_scan_res_kernel, bits=bits),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nprobe, cap), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, nprobe, 1, cap), jnp.float32),
         interpret=interpret,
-    )(probe.astype(jnp.int32), q, ids, codes, centroids, values)
+    )(probe.astype(jnp.int32), q.reshape(B, 1, d), ids.reshape(nlist, 1, cap),
+      codes, centroids.reshape(nlist, 1, d), values.T)
+    return out.reshape(B, nprobe, cap)
 
 
 def _rerank_paged_res_kernel(pt_ref, nt_ref, q_ref, qm_ref, cent_ref,
                              code_ref, cb_ref, val_ref, out_ref, acc_ref, *,
-                             pmax, bits):
-    # the paged fp rerank with the page DMA swapped for cent ids (1, page)
-    # int32 + packed codes (1, page, db) uint8 and an in-VMEM decode; the
-    # codec tables (cb: (ncent, d), val: (d, L)) ride along as full blocks
+                             kc, pmax, bits):
+    # the paged fp rerank with the page DMA swapped for cent ids + packed
+    # codes (page, db) uint8 and an in-VMEM decode.  The id block holds the
+    # aligned group of cent-page rows around the wanted page (a single
+    # (1, page) row is not a legal TPU block); the row is picked here.  The
+    # codec tables (cb: (ncent, d), val: (L, d)) ride along as full blocks
     b, c, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    group = cent_ref.shape[0]
+    row = pt_ref[(b * kc + c) * pmax + j] % group
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.full(acc_ref.shape, NEG, jnp.float32)
 
-    _, Tq, d = q_ref.shape
-    _, page = cent_ref.shape
-    toks = residual_decode_onehot(
-        cent_ref[...].reshape(page), code_ref[...].reshape(page, -1),
-        cb_ref[...], val_ref[...], bits=bits,
-    )                                                  # (page, d)
+    Tq = q_ref.shape[0]
+    page = code_ref.shape[0]
+    toks = _decode_rows(cent_ref[pl.ds(row, 1), :], code_ref[...],
+                        cb_ref[...], val_ref, bits=bits)   # (page, d)
     s = jax.lax.dot_general(
-        q_ref[...].reshape(Tq, d), toks, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        q_ref[...], toks, (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )  # (Tq, page)
     pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (Tq, page), 1)
-    s = jnp.where(pos < nt_ref[b, c], s, NEG)
+    s = jnp.where(pos < nt_ref[b * pl.num_programs(1) + c], s, NEG)
     acc_ref[...] = jnp.maximum(acc_ref[...],
                                jnp.max(s, axis=-1, keepdims=True))
 
     @pl.when(j == pmax - 1)
     def _flush():
-        best = jnp.where(qm_ref[...].reshape(Tq, 1) > 0, acc_ref[...], 0.0)
-        out_ref[...] = jnp.sum(best).reshape(1, 1)
+        _put_lane(out_ref, c, _maxsim_flush(acc_ref[...], qm_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -468,36 +604,43 @@ def rerank_paged_res_scores(q, q_mask, cand_ids, cent_pages, code_pages,
     """
     B, Tq, d = q.shape
     kp = cand_ids.shape[1]
-    _, page = cent_pages.shape
+    P, page = cent_pages.shape
     db = code_pages.shape[2]
     ncent = centroids.shape[0]
     L = values.shape[1]
     bits = int(L).bit_length() - 1
     pmax = page_table.shape[1]
-    safe = jnp.maximum(cand_ids, 0).astype(jnp.int32)
-    pt = jnp.maximum(jnp.take(page_table, safe, axis=0), 0).astype(jnp.int32)
-    nt = jnp.take(n_tokens, safe, axis=0).astype(jnp.int32)
-    nt = jnp.where(cand_ids >= 0, nt, 0)         # (B, k')
-    qm = q_mask.astype(jnp.int8)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, kp, pmax),
-        in_specs=[
-            pl.BlockSpec((1, Tq, d), lambda b, c, j, pt, nt: (b, 0, 0)),
-            pl.BlockSpec((1, Tq), lambda b, c, j, pt, nt: (b, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, c, j, pt, nt: (pt[b, c, j], 0)),
-            pl.BlockSpec((1, page, db),
-                         lambda b, c, j, pt, nt: (pt[b, c, j], 0, 0)),
-            pl.BlockSpec((ncent, d), lambda b, c, j, pt, nt: (0, 0)),
-            pl.BlockSpec((d, L), lambda b, c, j, pt, nt: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, c, j, pt, nt: (b, c)),
-        scratch_shapes=[pltpu.VMEM((Tq, 1), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_rerank_paged_res_kernel, pmax=pmax, bits=bits),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kp), jnp.float32),
-        interpret=interpret,
-    )(pt, nt, q, qm, cent_pages, code_pages, centroids, values)
+    kc, nc, pt, nt = _paged_prefetch(cand_ids, page_table, n_tokens, pmax)
+    pg = lambda b, c, j, pt, nt: (pt[(b * kc + c) * pmax + j], 0, 0)
+    group = min(8, P)
+    grp = lambda b, c, j, pt, nt: (pt[(b * kc + c) * pmax + j] // group, 0)
+    fixed = lambda b, c, j, pt, nt: (0, 0)
+
+    def call(pt, nt, q, qm):
+        bb = q.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bb, kc, pmax),
+            in_specs=[
+                pl.BlockSpec((None, Tq, d), lambda b, c, j, pt, nt: (b, 0, 0)),
+                pl.BlockSpec((None, Tq, 1), lambda b, c, j, pt, nt: (b, 0, 0)),
+                pl.BlockSpec((group, page), grp),
+                pl.BlockSpec((None, page, db), pg),
+                pl.BlockSpec((ncent, d), fixed),
+                pl.BlockSpec((L, d), fixed),
+            ],
+            out_specs=pl.BlockSpec((None, 1, kc),
+                                   lambda b, c, j, pt, nt: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((Tq, 1), jnp.float32)],
+        )
+        return pl.pallas_call(
+            functools.partial(_rerank_paged_res_kernel, kc=kc, pmax=pmax,
+                              bits=bits),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
+            interpret=interpret,
+        )(pt, nt, q, qm, cent_pages, code_pages, centroids, values.T)
+
+    q, q_mask = _fold_rows(nc, q, q_mask)
+    out = _over_row_chunks(call, (pt, nt), (q, _query_rows(q_mask)))
+    return out.reshape(B, kp)

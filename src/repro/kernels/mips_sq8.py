@@ -9,9 +9,12 @@ is 4x lower than fp32, which matters because the latent scan is memory-bound
 
 int8 codes are widened to bf16 for the MXU dot (int8×int8→int32 MXU paths
 are not exposed via Pallas dot_general on all generations; bf16 exactly
-represents ints up to 256).  The fp32 query is split into hi+lo bf16 parts
-(two MXU passes) so the fp32-accumulated result matches the fp32 oracle to
-~2^-16 relative — 2 bf16 matmuls still beat one fp32 matmul on the MXU.
+represents ints up to 256).  The fp32 query is split into three bf16 parts
+that sum to it exactly (:func:`split_dot`, three MXU passes): every product
+with an int8 code is then exact in fp32, and the result matches the fp32
+oracle up to accumulation order.  A two-part split leaves ~2^-16 of each
+query element behind, which over d'=2048 terms already exceeds 2^-16 of
+the score; the scan is memory-bound, so the third pass costs nothing.
 """
 from __future__ import annotations
 
@@ -22,15 +25,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _mips_sq8_kernel(q_ref, codes_ref, scales_ref, out_ref):
-    q = q_ref[...]                       # (Bq, d) fp32
-    c = codes_ref[...].astype(jnp.bfloat16)  # (Bm, d) int8 -> bf16 (exact)
-    q_hi = q.astype(jnp.bfloat16)
-    q_lo = (q - q_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+def split_dot(q, codes):
+    """q (n, d) fp32 x int8 ``codes`` (m, d) -> (n, m) fp32 on the MXU.
+
+    The codes widen to bf16 exactly; q splits into hi + mid + lo bf16 parts
+    whose sum is q, so each bf16 pass multiplies exactly and only the fp32
+    accumulation rounds."""
+    c = codes.astype(jnp.float32).astype(jnp.bfloat16)
     dot = lambda a: jax.lax.dot_general(
         a, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    s = dot(q_hi) + dot(q_lo)            # (Bq, Bm) fp32, hi/lo split
+    hi = q.astype(jnp.bfloat16)
+    r = q - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return dot(hi) + dot(mid) + dot(lo)
+
+
+def _mips_sq8_kernel(q_ref, codes_ref, scales_ref, out_ref):
+    s = split_dot(q_ref[...], codes_ref[...])   # (Bq, Bm) fp32
     out_ref[...] = s * scales_ref[...][None, :]
 
 

@@ -24,6 +24,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _one_launch_kernel(use_kernel: bool | None) -> bool:
+    """Kernel dispatch for the one-launch first stages, whose carried top-k'
+    merge calls ``jax.lax.top_k`` inside the kernel.  Mosaic has no lowering
+    for ``top_k`` (jax 0.9), so on the TPU these paths raise rather than
+    quietly answering from the XLA reference."""
+    if use_kernel is None:
+        use_kernel = _on_tpu()
+    if use_kernel and _on_tpu():
+        raise NotImplementedError(
+            "one-launch first stage (use_one_launch=True) is not available "
+            "on TPU: Mosaic cannot lower the in-kernel jax.lax.top_k merge; "
+            "serve with use_one_launch=False")
+    return use_kernel
+
+
 def token_maxsim(x, doc_tokens, doc_mask, *, use_kernel: bool | None = None,
                  block_n: int = 256, block_m: int = 64):
     """(n, d) x (m, T, d) -> (n, m) fp32 per-token MaxSim contributions."""
@@ -253,9 +268,7 @@ def fused_query(q_tokens, q_mask, psi_params, centroids, ids, vecs,
     psi_q = ref.psi_pool_ref(q_tokens, q_mask, kernel, bias, g, b)
     cs = psi_q @ centroids.T
     _, probe = jax.lax.top_k(cs, nprobe)
-    if use_kernel is None:
-        use_kernel = _on_tpu()
-    if not use_kernel:
+    if not _one_launch_kernel(use_kernel):
         return ref.query_fused_ref(q_tokens, q_mask, kernel, bias, g, b,
                                    probe, ids, vecs, scales, kp=kp)
     return _qf.query_fused(q_tokens, q_mask, kernel, bias, g, b, probe, ids,
@@ -279,9 +292,7 @@ def fused_query_res(q_tokens, q_mask, psi_params, centroids, ids, codes,
     psi_q = ref.psi_pool_ref(q_tokens, q_mask, kernel, bias, g, b)
     cs = psi_q @ centroids.T
     _, probe = jax.lax.top_k(cs, nprobe)
-    if use_kernel is None:
-        use_kernel = _on_tpu()
-    if not use_kernel:
+    if not _one_launch_kernel(use_kernel):
         return ref.query_fused_res_ref(q_tokens, q_mask, kernel, bias, g, b,
                                        probe, ids, codes, centroids,
                                        rq_values, kp=kp)
@@ -301,9 +312,7 @@ def mips_topk_fused(q, W, W_scales, kp: int, valid=None, *,
     may be a traced array — the sharded path's pad mask depends on
     ``jax.lax.axis_index``.  Returns (scores, ids), (B, kp).
     """
-    if use_kernel is None:
-        use_kernel = _on_tpu()
-    if not use_kernel:
+    if not _one_launch_kernel(use_kernel):
         return ref.mips_topk_ref(q, W, W_scales, valid, kp=kp)
     return _qf.mips_topk(q, W, W_scales, valid, kp=kp, block_m=block_m,
                          interpret=not _on_tpu())

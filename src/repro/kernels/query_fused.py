@@ -7,21 +7,20 @@ the ``(B, Tq, d')`` ψ features and the ``(B, nprobe, cap)`` score strip each
 made an HBM write+read purely to cross a launch boundary.  These kernels
 keep the whole pre-rerank pipeline inside ONE grid:
 
-``query_fused`` — grid ``(B, nprobe)``, probe ids scalar-prefetched to SMEM
-(``pltpu.PrefetchScalarGridSpec``, same scheme as ``gather_scan``):
+``query_fused`` — grid ``(B, nprobe, cap/bc)``, probe ids scalar-prefetched
+to SMEM (``pltpu.PrefetchScalarGridSpec``, same scheme as ``gather_scan``):
 
-* step ``(b, 0)`` computes ψ for query ``b``'s tokens in-kernel (the
+* step ``(b, 0, 0)`` computes ψ for query ``b``'s tokens in-kernel (the
   ``fused_psi`` matmul+GELU+LayerNorm body), masks and pools them
   (eq. 5) into a ``(1, d')`` VMEM scratch — the pooled query never touches
-  HBM, and is carried across the ``nprobe`` minor grid steps (the TPU grid
-  iterates the last dimension innermost, so scratch persists per ``b``);
-* every step ``(b, p)`` DMAs exactly cluster ``probe[b, p]``'s ``(cap, d')``
-  tile HBM→VMEM (BlockSpec index_map reads the prefetched id; consecutive
-  steps double-buffer automatically — cluster ``p+1`` streams in while
-  ``p``'s MXU contraction runs), scores it against the pooled query (fp32,
-  or int8 codes dequantized in-kernel via the hi/lo-bf16 split), masks
+  HBM, and is carried across the minor grid steps (the TPU grid iterates
+  the last dimension innermost, so scratch persists per ``b``);
+* every step ``(b, p, t)`` DMAs cap-tile ``t`` of cluster ``probe[b, p]``
+  HBM→VMEM (BlockSpec index_map reads the prefetched id; consecutive steps
+  double-buffer automatically), scores it against the pooled query (fp32,
+  or int8 codes dequantized in-kernel via the exact bf16 split), masks
   ``-1`` pad slots to ``-inf``;
-* the per-step ``(1, cap)`` score strip is merged into a carried ``(1, k')``
+* the per-step ``(1, bc)`` score strip is merged into a carried ``(1, k')``
   best-scores/best-ids strip (local ``jax.lax.top_k`` over
   ``concat([carried, strip])`` — carried first, so earlier flat positions
   win score ties exactly like the legacy flat top-k), and only the final
@@ -31,11 +30,10 @@ Per query the HBM traffic is the probed source bytes streamed once plus
 ``k'`` result slots — the ``(B, Tq, d')`` feature tensor and the
 ``(B, nprobe, cap)`` strip never exist.
 
-VMEM per step (Tq=32, d=128, d'=2048, cap=512, k'=1024, fp32): W' tile
-1 MiB + token slab 16 KiB + pooled query 8 KiB + cluster tile 4 MiB (×2 for
-the pipeline's double buffer) + heap strip 8 KiB ≈ 9.1 MiB — inside ~16 MiB
-v5e VMEM.  cap=4096 at d'=2048 would need 32 MiB/tile in fp32: the SQ8 path
-(8 MiB/tile) is the only one-launch option there.
+VMEM per step (Tq=32, d=128, d'=2048, k'=1024, fp32): W' tile 1 MiB +
+token slab 16 KiB + pooled query 8 KiB + a cap-tile of at most 1 MiB
+(``gather_scan.cap_tile``; ×2 for the pipeline's double buffer) + the
+carried strips 8 KiB — inside v5e VMEM at any cap.
 
 ``mips_topk`` — the dense-scan twin for the sharded serving step: grid
 ``(B, m/bm)`` over corpus tiles of the local latent shard, per-step MXU
@@ -44,8 +42,9 @@ top-k' merge.  Replaces ``psi_q @ W.T`` → mask → ``top_k`` (a full
 ``(B, m_loc)`` HBM score matrix) with one launch returning ``(B, k')``.
 
 The in-kernel ``jax.lax.top_k`` merge is validated in interpret mode (the
-tests' parity grid); on real TPUs it relies on Mosaic's sort lowering —
-gate with ``use_one_launch=False`` if a toolchain rejects it.
+tests' parity grid).  Mosaic has no lowering for ``top_k``, so these
+kernels do not compile for the TPU; ``kernels.ops`` raises there instead
+of answering from the reference (``use_one_launch=False`` serves).
 """
 from __future__ import annotations
 
@@ -55,6 +54,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gather_scan import HIGHEST, cap_tile
+from repro.kernels.mips_sq8 import split_dot
 
 NEG = -1e30
 
@@ -75,118 +77,121 @@ def _merge_topk(best_s, best_i, s, ids):
 
 
 def _pool_psi(qt_ref, qm_ref, w_ref, b_ref, g_ref, beta_ref, eps):
-    """The ``fused_psi`` kernel body + mask + pool: (1, Tq, d) -> (1, d')."""
-    _, Tq, d = qt_ref.shape
-    x = qt_ref[...].reshape(Tq, d)
+    """The ``fused_psi`` kernel body + mask + pool: (Tq, d) -> (1, d')."""
     h = jax.lax.dot_general(
-        x, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        qt_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
-    h = h + b_ref[...][None, :]
+    h = h + b_ref[...]
     h = jax.nn.gelu(h, approximate=True)
     mu = jnp.mean(h, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(h - mu), axis=-1, keepdims=True)
     y = (h - mu) * jax.lax.rsqrt(var + eps)
-    y = y * g_ref[...][None, :] + beta_ref[...][None, :]
-    y = y * (qm_ref[...].reshape(Tq, 1) > 0)
+    y = y * g_ref[...] + beta_ref[...]
+    y = y * (qm_ref[...] > 0)
     return jnp.sum(y, axis=0, keepdims=True)
 
 
-def _query_fused_fp_kernel(probe_ref, qt_ref, qm_ref, w_ref, b_ref, g_ref,
-                           beta_ref, ids_ref, vecs_ref, out_s_ref, out_i_ref,
-                           q_acc, best_s, best_i, *, eps, nprobe):
-    p = pl.program_id(1)
+def _scan_fp(q, vecs_ref, scales_ref):
+    return jax.lax.dot_general(
+        q, vecs_ref[...], (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
+    )  # (1, bc)
 
-    @pl.when(p == 0)
+
+def _scan_sq8(q, codes_ref, scales_ref):
+    # int8 cluster codes dequantized IN-KERNEL (the exact bf16 split of
+    # kernels.mips_sq8.split_dot), per-slot scales folded into the strip —
+    # same identity as gather_scan._ivf_scan_sq8_kernel
+    return split_dot(q, codes_ref[...]) * scales_ref[...]
+
+
+def _query_fused_kernel(probe_ref, qt_ref, qm_ref, w_ref, b_ref, g_ref,
+                        beta_ref, ids_ref, *refs, eps, scan):
+    # refs: the list tile(s) of this step, then the two (1, k') outputs and
+    # the three scratch strips.  Step (b, p, t): cap-tile t of cluster
+    # probe[b, p]; the pooled ψ query is computed at the first step of b
+    *tiles, out_s_ref, out_i_ref, q_acc, best_s, best_i = refs
+    p, t = pl.program_id(1), pl.program_id(2)
+    first = (p == 0) & (t == 0)
+    last = (p == pl.num_programs(1) - 1) & (t == pl.num_programs(2) - 1)
+
+    @pl.when(first)
     def _init():
         q_acc[...] = _pool_psi(qt_ref, qm_ref, w_ref, b_ref, g_ref, beta_ref,
                                eps)
         best_s[...] = jnp.full(best_s.shape, -jnp.inf, jnp.float32)
         best_i[...] = jnp.full(best_i.shape, -1, jnp.int32)
 
-    _, cap, dp = vecs_ref.shape
-    s = jax.lax.dot_general(
-        q_acc[...], vecs_ref[...].reshape(cap, dp), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (1, cap)
+    s = scan(q_acc[...], *tiles)
     s = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
     _merge_topk(best_s, best_i, s, ids_ref[...])
 
-    @pl.when(p == nprobe - 1)
+    @pl.when(last)
     def _flush():
         out_s_ref[...] = best_s[...]
         out_i_ref[...] = best_i[...]
 
 
-def _query_fused_sq8_kernel(probe_ref, qt_ref, qm_ref, w_ref, b_ref, g_ref,
-                            beta_ref, ids_ref, codes_ref, scales_ref,
-                            out_s_ref, out_i_ref, q_acc, best_s, best_i, *,
-                            eps, nprobe):
-    # int8 cluster codes dequantized IN-KERNEL: hi/lo bf16 split of the fp32
-    # pooled query (two MXU passes), per-slot scales folded into the strip —
-    # same identity as gather_scan._ivf_scan_sq8_kernel (~2^-16 relative)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        q_acc[...] = _pool_psi(qt_ref, qm_ref, w_ref, b_ref, g_ref, beta_ref,
-                               eps)
-        best_s[...] = jnp.full(best_s.shape, -jnp.inf, jnp.float32)
-        best_i[...] = jnp.full(best_i.shape, -1, jnp.int32)
-
-    q = q_acc[...]
-    _, cap, dp = codes_ref.shape
-    c = codes_ref[...].reshape(cap, dp).astype(jnp.bfloat16)
-    q_hi = q.astype(jnp.bfloat16)
-    q_lo = (q - q_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dot = lambda a: jax.lax.dot_general(
-        a, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = (dot(q_hi) + dot(q_lo)) * scales_ref[...]
-    s = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
-    _merge_topk(best_s, best_i, s, ids_ref[...])
-
-    @pl.when(p == nprobe - 1)
-    def _flush():
-        out_s_ref[...] = best_s[...]
-        out_i_ref[...] = best_i[...]
-
-
-def _query_fused_res_kernel(probe_ref, qt_ref, qm_ref, w_ref, b_ref, g_ref,
-                            beta_ref, ids_ref, codes_ref, cent_ref, val_ref,
-                            out_s_ref, out_i_ref, q_acc, best_s, best_i, *,
-                            eps, nprobe, bits):
-    # residual-tier cluster lists decoded IN-KERNEL: packed 2/4-bit codes
-    # unpack via shifts/ANDs, per-dim values via a select-sum over the L
-    # static levels, and the cluster's OWN centroid row (IVF residual
-    # storage) arrives as a (1, d') tile DMA'd by the same prefetched probe
-    # id — the fp32 cluster list never exists in HBM (gather_scan.
-    # _ivf_scan_res_kernel, fused behind the pooled-ψ carry)
+def _scan_res(bits):
+    # residual-tier cluster lists decoded IN-KERNEL (gather_scan.
+    # _ivf_scan_res_kernel): packed codes unpack via an expansion matmul +
+    # shifts, per-dim values via a select-sum over the L levels, and the
+    # cluster's OWN centroid row arrives as a (1, d') tile DMA'd by the same
+    # prefetched probe id — the fp32 cluster list never exists in HBM
     from repro.kernels.gather_scan import _residual_values, _unpack_codes_i32
 
-    p = pl.program_id(1)
+    def scan(q, codes_ref, cent_ref, val_ref):
+        idx = _unpack_codes_i32(codes_ref[...], bits=bits)
+        v = _residual_values(idx, val_ref) + cent_ref[...]   # (bc, d')
+        return jax.lax.dot_general(
+            q, v, (((1,), (1,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(p == 0)
-    def _init():
-        q_acc[...] = _pool_psi(qt_ref, qm_ref, w_ref, b_ref, g_ref, beta_ref,
-                               eps)
-        best_s[...] = jnp.full(best_s.shape, -jnp.inf, jnp.float32)
-        best_i[...] = jnp.full(best_i.shape, -1, jnp.int32)
+    return scan
 
-    _, cap, db = codes_ref.shape
-    idx = _unpack_codes_i32(codes_ref[...].reshape(cap, db), bits=bits)
-    v = _residual_values(idx, val_ref[...]) + cent_ref[...]   # (cap, d')
-    s = jax.lax.dot_general(
-        q_acc[...], v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (1, cap)
-    s = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
-    _merge_topk(best_s, best_i, s, ids_ref[...])
 
-    @pl.when(p == nprobe - 1)
-    def _flush():
-        out_s_ref[...] = best_s[...]
-        out_i_ref[...] = best_i[...]
+def _query_fused_call(q_tokens, q_mask, kernel, bias, ln_scale, ln_bias,
+                      probe, ids, tiles, tile_specs, scan, bc, kp, interpret,
+                      eps):
+    """Shared launch of the one-launch query kernel: grid (B, nprobe,
+    cap/bc); ``tiles``/``tile_specs`` are the per-step list operands."""
+    B, Tq, d = q_tokens.shape
+    nprobe = probe.shape[1]
+    nlist, cap = ids.shape
+    dp = kernel.shape[1]
+    qb = lambda b, p, t, pr: (b, 0, 0)
+    fixed = lambda b, p, t, pr: (0, 0)
+    out_spec = pl.BlockSpec((None, 1, kp), qb)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, nprobe, cap // bc),
+        in_specs=[
+            pl.BlockSpec((None, Tq, d), qb),
+            pl.BlockSpec((None, Tq, 1), qb),
+            pl.BlockSpec((d, dp), fixed),
+            pl.BlockSpec((1, dp), fixed),
+            pl.BlockSpec((1, dp), fixed),
+            pl.BlockSpec((1, dp), fixed),
+            pl.BlockSpec((None, 1, bc), lambda b, p, t, pr: (pr[b, p], 0, t)),
+            *tile_specs,
+        ],
+        out_specs=[out_spec, out_spec],
+        scratch_shapes=[pltpu.VMEM((1, dp), jnp.float32),
+                        pltpu.VMEM((1, kp), jnp.float32),
+                        pltpu.VMEM((1, kp), jnp.int32)],
+    )
+    s, i = pl.pallas_call(
+        functools.partial(_query_fused_kernel, eps=eps, scan=scan),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, 1, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, kp), jnp.int32)],
+        interpret=interpret,
+    )(probe.astype(jnp.int32), q_tokens, q_mask.astype(jnp.int32)[..., None],
+      kernel, bias.reshape(1, dp), ln_scale.reshape(1, dp),
+      ln_bias.reshape(1, dp), ids.reshape(nlist, 1, cap), *tiles)
+    return s.reshape(B, kp), i.reshape(B, kp)
 
 
 @functools.partial(jax.jit, static_argnames=("kp", "interpret"))
@@ -203,45 +208,20 @@ def query_fused(q_tokens, q_mask, kernel, bias, ln_scale, ln_bias, probe,
     padded with ``(-inf, -1)`` when fewer than ``kp`` real candidates were
     probed.  Only these two (B, kp) strips ever reach HBM.
     """
-    B, Tq, d = q_tokens.shape
-    nprobe = probe.shape[1]
-    nlist, cap = ids.shape
-    dp = kernel.shape[1]
-    qm = q_mask.astype(jnp.int8)
-    in_specs = [
-        pl.BlockSpec((1, Tq, d), lambda b, p, pr: (b, 0, 0)),
-        pl.BlockSpec((1, Tq), lambda b, p, pr: (b, 0)),
-        pl.BlockSpec((d, dp), lambda b, p, pr: (0, 0)),
-        pl.BlockSpec((dp,), lambda b, p, pr: (0,)),
-        pl.BlockSpec((dp,), lambda b, p, pr: (0,)),
-        pl.BlockSpec((dp,), lambda b, p, pr: (0,)),
-        pl.BlockSpec((1, cap), lambda b, p, pr: (pr[b, p], 0)),
-        pl.BlockSpec((1, cap, dp), lambda b, p, pr: (pr[b, p], 0, 0)),
-    ]
-    args = [q_tokens, qm, kernel, bias, ln_scale, ln_bias, ids, vecs]
-    kfn = functools.partial(_query_fused_fp_kernel, eps=eps, nprobe=nprobe)
+    nlist, cap, dp = vecs.shape
+    bc = cap_tile(cap, dp * vecs.dtype.itemsize)
+    tile = pl.BlockSpec((None, bc, dp), lambda b, p, t, pr: (pr[b, p], t, 0))
+    tiles, specs, scan = [vecs], [tile], _scan_fp
     if scales is not None:
-        in_specs.append(pl.BlockSpec((1, cap), lambda b, p, pr: (pr[b, p], 0)))
-        args.append(scales)
-        kfn = functools.partial(_query_fused_sq8_kernel, eps=eps,
-                                nprobe=nprobe)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, nprobe),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, kp), lambda b, p, pr: (b, 0)),
-                   pl.BlockSpec((1, kp), lambda b, p, pr: (b, 0))],
-        scratch_shapes=[pltpu.VMEM((1, dp), jnp.float32),
-                        pltpu.VMEM((1, kp), jnp.float32),
-                        pltpu.VMEM((1, kp), jnp.int32)],
-    )
-    return pl.pallas_call(
-        kfn,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, kp), jnp.int32)],
-        interpret=interpret,
-    )(probe.astype(jnp.int32), *args)
+        tiles.append(scales.reshape(nlist, 1, cap))
+        specs.append(pl.BlockSpec((None, 1, bc),
+                                  lambda b, p, t, pr: (pr[b, p], 0, t)))
+        scan = _scan_sq8
+    else:
+        scan = lambda q, vecs_ref: _scan_fp(q, vecs_ref, None)
+    return _query_fused_call(q_tokens, q_mask, kernel, bias, ln_scale,
+                             ln_bias, probe, ids, tiles, specs, scan, bc, kp,
+                             interpret, eps)
 
 
 @functools.partial(jax.jit, static_argnames=("kp", "interpret"))
@@ -256,52 +236,32 @@ def query_fused_res(q_tokens, q_mask, kernel, bias, ln_scale, ln_bias, probe,
     the probe-select prelude scores); rq_values (d', L) fp32.  Returns
     (scores (B, kp) fp32, ids (B, kp) int32) padded with ``(-inf, -1)``.
     """
-    B, Tq, d = q_tokens.shape
-    nprobe = probe.shape[1]
-    nlist, cap = ids.shape
-    db = codes.shape[2]
-    dp = kernel.shape[1]
+    nlist, cap, db = codes.shape
+    dp = centroids.shape[1]
     L = rq_values.shape[1]
     bits = int(L).bit_length() - 1
-    qm = q_mask.astype(jnp.int8)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, nprobe),
-        in_specs=[
-            pl.BlockSpec((1, Tq, d), lambda b, p, pr: (b, 0, 0)),
-            pl.BlockSpec((1, Tq), lambda b, p, pr: (b, 0)),
-            pl.BlockSpec((d, dp), lambda b, p, pr: (0, 0)),
-            pl.BlockSpec((dp,), lambda b, p, pr: (0,)),
-            pl.BlockSpec((dp,), lambda b, p, pr: (0,)),
-            pl.BlockSpec((dp,), lambda b, p, pr: (0,)),
-            pl.BlockSpec((1, cap), lambda b, p, pr: (pr[b, p], 0)),
-            pl.BlockSpec((1, cap, db), lambda b, p, pr: (pr[b, p], 0, 0)),
-            pl.BlockSpec((1, dp), lambda b, p, pr: (pr[b, p], 0)),
-            pl.BlockSpec((dp, L), lambda b, p, pr: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, kp), lambda b, p, pr: (b, 0)),
-                   pl.BlockSpec((1, kp), lambda b, p, pr: (b, 0))],
-        scratch_shapes=[pltpu.VMEM((1, dp), jnp.float32),
-                        pltpu.VMEM((1, kp), jnp.float32),
-                        pltpu.VMEM((1, kp), jnp.int32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_query_fused_res_kernel, eps=eps, nprobe=nprobe,
-                          bits=bits),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, kp), jnp.int32)],
-        interpret=interpret,
-    )(probe.astype(jnp.int32), q_tokens, qm, kernel, bias, ln_scale, ln_bias,
-      ids, codes, centroids, rq_values)
+    bc = cap_tile(cap, 8 * dp)
+    specs = [
+        pl.BlockSpec((None, bc, db), lambda b, p, t, pr: (pr[b, p], t, 0)),
+        pl.BlockSpec((None, 1, dp), lambda b, p, t, pr: (pr[b, p], 0, 0)),
+        pl.BlockSpec((L, dp), lambda b, p, t, pr: (0, 0)),
+    ]
+    tiles = [codes, centroids.reshape(nlist, 1, dp), rq_values.T]
+    return _query_fused_call(q_tokens, q_mask, kernel, bias, ln_scale,
+                             ln_bias, probe, ids, tiles, specs,
+                             _scan_res(bits), bc, kp, interpret, eps)
 
 
 # --------------------------------------------------------------------------
 # dense-scan twin: fused latent MIPS + in-kernel top-k' (the sharded path)
 # --------------------------------------------------------------------------
 
-def _mips_topk_fp_kernel(q_ref, w_ref, valid_ref, out_s_ref, out_i_ref,
-                         best_s, best_i, *, nt, bm):
+def _mips_topk_kernel(q_ref, w_ref, *refs, nt, bm, sq8):
+    if sq8:
+        ws_ref, valid_ref, out_s_ref, out_i_ref, best_s, best_i = refs
+    else:
+        ws_ref = None
+        valid_ref, out_s_ref, out_i_ref, best_s, best_i = refs
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -309,37 +269,7 @@ def _mips_topk_fp_kernel(q_ref, w_ref, valid_ref, out_s_ref, out_i_ref,
         best_s[...] = jnp.full(best_s.shape, -jnp.inf, jnp.float32)
         best_i[...] = jnp.full(best_i.shape, -1, jnp.int32)
 
-    s = jax.lax.dot_general(
-        q_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (1, bm)
-    ids = t * bm + jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1)
-    s = jnp.where(valid_ref[...] > 0, s, NEG)
-    _merge_topk(best_s, best_i, s, ids)
-
-    @pl.when(t == nt - 1)
-    def _flush():
-        out_s_ref[...] = best_s[...]
-        out_i_ref[...] = best_i[...]
-
-
-def _mips_topk_sq8_kernel(q_ref, codes_ref, ws_ref, valid_ref, out_s_ref,
-                          out_i_ref, best_s, best_i, *, nt, bm):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        best_s[...] = jnp.full(best_s.shape, -jnp.inf, jnp.float32)
-        best_i[...] = jnp.full(best_i.shape, -1, jnp.int32)
-
-    q = q_ref[...]
-    c = codes_ref[...].astype(jnp.bfloat16)
-    q_hi = q.astype(jnp.bfloat16)
-    q_lo = (q - q_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dot = lambda a: jax.lax.dot_general(
-        a, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = (dot(q_hi) + dot(q_lo)) * ws_ref[...]
+    s = (_scan_sq8 if sq8 else _scan_fp)(q_ref[...], w_ref, ws_ref)
     ids = t * bm + jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1)
     s = jnp.where(valid_ref[...] > 0, s, NEG)
     _merge_topk(best_s, best_i, s, ids)
@@ -369,31 +299,27 @@ def mips_topk(q, W, W_scales=None, valid=None, *, kp: int,
     mp = -(-m // bm) * bm
     if valid is None:
         valid = jnp.ones((m,), bool)
-    valid = jnp.pad(valid, (0, mp - m)).reshape(1, mp).astype(jnp.int8)
+    valid = jnp.pad(valid, (0, mp - m)).reshape(1, mp).astype(jnp.int32)
     Wp = jnp.pad(W, ((0, mp - m), (0, 0)))
     nt = mp // bm
-    in_specs = [
-        pl.BlockSpec((1, dp), lambda b, t: (b, 0)),
-        pl.BlockSpec((bm, dp), lambda b, t: (t, 0)),
-    ]
-    args = [q, Wp]
+    strip = pl.BlockSpec((1, bm), lambda b, t: (0, t))
+    in_specs = [pl.BlockSpec((None, 1, dp), lambda b, t: (b, 0, 0)),
+                pl.BlockSpec((bm, dp), lambda b, t: (t, 0))]
+    args = [q.reshape(B, 1, dp), Wp]
     if W_scales is not None:
-        in_specs.append(pl.BlockSpec((1, bm), lambda b, t: (0, t)))
+        in_specs.append(strip)
         args.append(jnp.pad(W_scales, (0, mp - m)).reshape(1, mp))
-        kfn = functools.partial(_mips_topk_sq8_kernel, nt=nt, bm=bm)
-    else:
-        kfn = functools.partial(_mips_topk_fp_kernel, nt=nt, bm=bm)
-    in_specs.append(pl.BlockSpec((1, bm), lambda b, t: (0, t)))
-    args.append(valid)
-    return pl.pallas_call(
-        kfn,
+    out_spec = pl.BlockSpec((None, 1, kp), lambda b, t: (b, 0, 0))
+    s, i = pl.pallas_call(
+        functools.partial(_mips_topk_kernel, nt=nt, bm=bm,
+                          sq8=W_scales is not None),
         grid=(B, nt),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, kp), lambda b, t: (b, 0)),
-                   pl.BlockSpec((1, kp), lambda b, t: (b, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, kp), jnp.int32)],
+        in_specs=in_specs + [strip],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, kp), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((1, kp), jnp.float32),
                         pltpu.VMEM((1, kp), jnp.int32)],
         interpret=interpret,
-    )(*args)
+    )(*args, valid)
+    return s.reshape(B, kp), i.reshape(B, kp)

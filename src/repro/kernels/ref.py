@@ -8,18 +8,24 @@ import jax
 import jax.numpy as jnp
 
 NEG = -1e30
+# every oracle contracts fp32 operands at full fp32 precision: on the TPU,
+# XLA's default would round them through bf16 and the oracle would no longer
+# be the exact score the kernels are held to
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def token_maxsim_ref(x, doc_tokens, doc_mask):
     """g(x)_l = max_{c in C_l} <c, x>.   x: (n, d); docs: (m, T, d) -> (n, m)."""
-    s = jnp.einsum("nd,mtd->nmt", x, doc_tokens, preferred_element_type=jnp.float32)
+    s = jnp.einsum("nd,mtd->nmt", x, doc_tokens, precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
     s = jnp.where(doc_mask[None], s, NEG)
     return jnp.max(s, axis=-1)
 
 
 def maxsim_scores_ref(q, q_mask, doc_tokens, doc_mask):
     """MaxSim(X, C_j).  q: (B, Tq, d) -> (B, m)."""
-    s = jnp.einsum("bqd,mtd->bmqt", q, doc_tokens, preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqd,mtd->bmqt", q, doc_tokens, precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
     s = jnp.where(doc_mask[None, :, None, :], s, NEG)
     best = jnp.max(s, axis=-1)
     best = jnp.where(q_mask[:, None, :], best, 0.0)
@@ -40,7 +46,8 @@ def fused_psi_ref(x, kernel, bias, ln_scale, ln_bias, eps: float = 1e-5):
 def mips_sq8_ref(q, codes, scales):
     """fp32 queries x int8 corpus with per-row scales.
     q: (B, d); codes: (m, d) int8; scales: (m,) -> (B, m) fp32."""
-    return (q @ codes.astype(jnp.float32).T) * scales[None, :]
+    return jnp.matmul(q, codes.astype(jnp.float32).T,
+                      precision=HIGHEST) * scales[None, :]
 
 
 def mips_sq8_batched_ref(q, codes, scales):
@@ -49,8 +56,19 @@ def mips_sq8_batched_ref(q, codes, scales):
     no per-row vmap, no B one-row kernel launches).
     q: (B, d); codes: (B, n, d) int8; scales: (B, n) -> (B, n) fp32."""
     s = jnp.einsum("bd,bnd->bn", q, codes.astype(jnp.float32),
+                   precision=HIGHEST,
                    preferred_element_type=jnp.float32)
     return s * scales.astype(jnp.float32)
+
+
+def take_lists(vecs, probe):
+    """``vecs[probe]`` for (nlist, cap, d) cluster lists and (B, P) probe
+    ids -> (B, P, cap, d), copied one list at a time.  XLA's TPU gather of
+    int8 lists first relayouts the whole list array (3.75 GiB of temporaries
+    at nlist=1024, cap=d=2048); a loop of dynamic slices needs none."""
+    one = lambda i: jax.lax.dynamic_index_in_dim(vecs, i, 0, keepdims=False)
+    out = jax.lax.map(one, probe.reshape(-1))
+    return out.reshape(probe.shape + vecs.shape[1:])
 
 
 def ivf_scan_ref(q, probe, ids, vecs, scales=None):
@@ -60,17 +78,19 @@ def ivf_scan_ref(q, probe, ids, vecs, scales=None):
     fp32 or int8 (with scales (nlist, cap)) -> (B, nprobe, cap) fp32,
     pad slots at ``-inf``."""
     gids = jnp.take(ids, probe, axis=0)                 # (B, P, cap)
-    gv = jnp.take(vecs, probe, axis=0)                  # (B, P, cap, d)
+    gv = take_lists(vecs, probe)                        # (B, P, cap, d)
     if scales is not None:
         # same flattened contraction as mips_sq8_batched_ref (the legacy
         # SQ8 fallback), so fused-ref == legacy bit for bit on CPU
         B, P, cap, d = gv.shape
         s = jnp.einsum("bd,bnd->bn", q,
                        gv.reshape(B, P * cap, d).astype(jnp.float32),
+                       precision=HIGHEST,
                        preferred_element_type=jnp.float32).reshape(B, P, cap)
         s = s * jnp.take(scales, probe, axis=0).astype(jnp.float32)
     else:
         s = jnp.einsum("bd,bpcd->bpc", q, gv.astype(q.dtype),
+                       precision=HIGHEST,
                        preferred_element_type=jnp.float32)
     return jnp.where(gids >= 0, s, -jnp.inf)
 
@@ -96,6 +116,7 @@ def ivf_scan_res_ref(q, probe, ids, codes, centroids, values):
     cent = jnp.broadcast_to(probe[..., None], gids.shape)
     v = residual_decode(codec, cent, gc)                # (B, P, cap, d)
     s = jnp.einsum("bd,bpcd->bpc", q.astype(jnp.float32), v,
+                   precision=HIGHEST,
                    preferred_element_type=jnp.float32)
     return jnp.where(gids >= 0, s, -jnp.inf)
 
@@ -111,6 +132,7 @@ def rerank_scores_ref(q, q_mask, cand_ids, doc_tokens, doc_mask,
     cd = jnp.take(doc_tokens, safe, axis=0)             # (B, k', Td, d)
     cm = jnp.take(doc_mask, safe, axis=0)               # (B, k', Td)
     s = jnp.einsum("bqd,bmtd->bmqt", q, cd.astype(q.dtype),
+                   precision=HIGHEST,
                    preferred_element_type=jnp.float32)
     if doc_scales is not None:
         cs = jnp.take(doc_scales, safe, axis=0)
@@ -137,6 +159,7 @@ def rerank_scores_paged_ref(q, q_mask, cand_ids, tok_pages, page_table,
     toks = toks.reshape(B, kp, pmax * page, d)
     cm = jnp.arange(pmax * page, dtype=jnp.int32) < nt[..., None]
     s = jnp.einsum("bqd,bmtd->bmqt", q, toks.astype(q.dtype),
+                   precision=HIGHEST,
                    preferred_element_type=jnp.float32)
     s = jnp.where(cm[:, :, None, :], s, NEG)
     best = jnp.max(s, axis=-1)                          # (B, k', Tq)

@@ -92,9 +92,8 @@ def run_cell(arch: str, shape: str, mesh, *, hlo_dir: pathlib.Path | None = None
         out_shardings=cell.out_shardings,
         donate_argnums=cell.donate_argnums,
     )
-    from repro.common.compat import set_mesh
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*cell.args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -12,7 +12,8 @@ from __future__ import annotations
 
 import jax
 
-from repro.common.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -58,23 +59,27 @@ def parse_mesh_spec(spec: str) -> tuple[int, ...]:
 
 
 def ensure_devices(n: int) -> None:
-    """Make sure ``n`` devices exist for a ``--mesh`` request, forcing XLA
-    host devices when the process has not touched a jax backend yet (the
-    flag is read at backend initialization, so this works as long as it
-    runs before the first device query).  Raises with the manual fix when
-    the backend is already pinned to fewer devices."""
+    """Make sure ``n`` devices exist for a ``--mesh`` request.  On the CPU
+    platform (``JAX_PLATFORMS=cpu``) XLA host devices are forced, which
+    works as long as no jax backend has been initialized yet (the flag is
+    read then).  On any other platform the ``n`` devices must be real."""
     import os
 
-    if n > 1:
+    cpu = (jax.config.jax_platforms or "").split(",")[0] == "cpu"
+    if n > 1 and cpu:
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count={n}".strip())
-    if len(jax.devices()) < n:
+    have = len(jax.devices())
+    if have < n:
+        hint = (f"launch with XLA_FLAGS=--xla_force_host_platform_"
+                f"device_count={n}" if cpu else
+                f"run on a {n}-device host, or set JAX_PLATFORMS=cpu to "
+                f"rehearse on {n} forced host devices")
         raise RuntimeError(
-            f"--mesh needs {n} devices but only {len(jax.devices())} are "
-            f"visible; launch with XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={n} (or run on a {n}-device accelerator)")
+            f"--mesh needs {n} devices but only {have} "
+            f"{jax.devices()[0].platform} device(s) are visible; {hint}")
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

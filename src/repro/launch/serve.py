@@ -104,8 +104,15 @@ def serve_sharded(retriever, mesh_spec, batches, args):
     rows = []
     # flip the one-launch scan both ways: the smoke covers the fused
     # per-shard first stage AND the legacy 3-launch path (distinct compile
-    # keys; ids must agree — the parity suite asserts bit-identity)
-    for one_launch in (False, True):
+    # keys; ids must agree — the parity suite asserts bit-identity).  The
+    # TPU has no one-launch kernel (its in-kernel top_k does not lower)
+    import jax
+
+    one_launch_modes = (False, True)
+    if jax.default_backend() == "tpu":
+        one_launch_modes = (False,)
+        print("[serve] one-launch first stage skipped: not available on TPU")
+    for one_launch in one_launch_modes:
         params = SearchParams(k=args.k, use_one_launch=one_launch)
         qps, rec = _serve_loop(lambda q, qm: sr.search(q, qm, params),
                                batches, args)
@@ -148,6 +155,7 @@ def serve_online(retriever, args):
           f"occupancy={report['mean_occupancy']:.2f} "
           f"jit_traces={online_traces}/{bound}")
     assert online_traces <= bound, "bucket-ladder compile bound blown"
+    assert report["n_lost"] == 0, "online server lost requests"
     return report
 
 
@@ -255,10 +263,12 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from repro.anns import registry
+    from repro.common.compile_cache import use_compile_cache
     from repro.core import LemurConfig, maxsim
     from repro.data import synthetic
     from repro.retriever import IVFBackendConfig, LemurRetriever
 
+    use_compile_cache()
     names = registry.list_backends() if args.backend == "all" else [args.backend]
     for n in names:
         registry.get_backend(n)  # fail fast on typos, before the build

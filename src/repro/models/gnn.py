@@ -24,7 +24,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
 from repro.common.config import ConfigBase
 from repro.common.prng import PRNGSeq
 from repro.nn import layers
@@ -120,8 +119,8 @@ def _forward_body(params, node_feat, edge_feat, senders, receivers, cfg: GNNConf
     n_total = n_loc
     node_idx = 0
     for ax in node_axes:
-        n_total *= compat.axis_size(ax)
-        node_idx = node_idx * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        n_total *= jax.lax.axis_size(ax)
+        node_idx = node_idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
 
     def gather_full(h_l):
         h = h_l
@@ -177,13 +176,12 @@ def forward(params, node_feat, edge_feat, senders, receivers, cfg: GNNConfig,
     array sharded over the batch axes when a mesh is given)."""
     if mesh is None:
         return _forward_body(params, node_feat, edge_feat, senders, receivers, cfg)
-    from repro.common.compat import shard_map
 
     axes = tuple(mesh.axis_names)
     node_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     espec, nspec = P(axes), P(node_axes)
     body = functools.partial(_forward_body, cfg=cfg, edge_axes=axes, node_axes=node_axes)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), nspec, espec, espec, espec),
         out_specs=nspec,
@@ -196,7 +194,6 @@ def loss_fn(params, batch, cfg: GNNConfig, mesh=None):
         out = _forward_body(params, batch["node_feat"], batch["edge_feat"],
                             batch["senders"], batch["receivers"], cfg)
         return _loss_from_out(out, batch, cfg)
-    from repro.common.compat import shard_map
 
     axes = tuple(mesh.axis_names)
     node_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -216,7 +213,7 @@ def loss_fn(params, batch, cfg: GNNConfig, mesh=None):
         + tuple(nspec for _ in node_keys)
         + tuple(P() for _ in repl_keys)
     )
-    loss = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+    loss = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
                      check_vma=False)(
         params, batch["node_feat"], batch["edge_feat"], batch["senders"],
         batch["receivers"], *[batch[k] for k in node_keys + repl_keys]

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
 from repro.common.config import ConfigBase
 from repro.common.prng import PRNGSeq
 from repro.nn import layers
@@ -83,14 +82,13 @@ def sharded_embedding_lookup(table, ids, mesh, *, batch_axes=("pod", "data")):
     Batches that don't divide the batch axes (e.g. the single-query retrieval
     cell) fall back to replicated ids."""
     import numpy as np
-    from repro.common.compat import shard_map
 
     axes = tuple(a for a in batch_axes if a in mesh.axis_names)
     n_batch = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
     if ids.shape[0] % max(n_batch, 1) != 0:
         axes = ()
     body = functools.partial(_lookup_body, n_rows_global=table.shape[0])
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("model", None), P(axes)),
@@ -322,7 +320,7 @@ def _retrieval_body(u, cand, *, k: int, axes: tuple[str, ...]):
     top, ids = jax.lax.top_k(s, kk)
     idx = 0
     for ax in axes:
-        idx = idx * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     gids = ids + idx * m_loc
     for ax in axes:
         top = jax.lax.all_gather(top, ax, axis=1, tiled=True)
@@ -334,13 +332,12 @@ def _retrieval_body(u, cand, *, k: int, axes: tuple[str, ...]):
 def make_retrieval_step(cfg: RecsysConfig, mesh, k: int = 100):
     """Score one query batch against the full candidate matrix (sharded over
     the whole mesh) and return global top-k — the `retrieval_cand` cell."""
-    from repro.common.compat import shard_map
 
     axes = tuple(mesh.axis_names)
 
     def step(params, batch, candidates):
         u = two_tower_user(params, batch["ids"], cfg, mesh)
-        return shard_map(
+        return jax.shard_map(
             functools.partial(_retrieval_body, k=k, axes=axes),
             mesh=mesh,
             in_specs=(P(), P(axes)),

@@ -188,7 +188,6 @@ def flash_attention_cp(q, k, v, q_positions, mesh, *, causal=True, chunk=None,
     EXPERIMENTS.md §Perf iteration 1).  Causal load imbalance across shards
     is accepted (ring/striped attention is the documented next step).
     """
-    from repro.common.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     ba = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -203,7 +202,7 @@ def flash_attention_cp(q, k, v, q_positions, mesh, *, causal=True, chunk=None,
                                kv_block=kv_block, scale=scale)
 
     seq4 = P(ba, "model", None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(seq4, seq4, seq4, P(ba, "model"), P()),
         out_specs=seq4,

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
 from repro.nn import layers
 
 
@@ -195,7 +194,7 @@ def _moe_tokengather_body(x, router_w, wi_0, wi_1, wi, wo, *, layout, n_experts,
     y = jax.lax.psum(y, ("model", "data"))
     idx = 0
     for ax in batch_axes:
-        idx = idx * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     y = jax.lax.dynamic_slice_in_dim(y, idx * n_local_tokens, n_local_tokens, axis=0)
     return y, jax.lax.pmean(aux, "model")
 
@@ -211,7 +210,6 @@ def moe_apply(params, x, *, layout: str, n_experts: int, top_k: int, mesh,
     instead of the ZeRO weight-gather body.
     """
     import numpy as np
-    from repro.common.compat import shard_map
 
     B, T, d = x.shape
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -257,7 +255,7 @@ def moe_apply(params, x, *, layout: str, n_experts: int, top_k: int, mesh,
             activation=activation,
             model_size=model_size,
         )
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,

@@ -286,8 +286,11 @@ class LemurRetriever:
             key = jax.random.PRNGKey(0)
         t0 = time.time()
         keys = jax.random.split(key, 4)
-        doc_tokens = jnp.asarray(corpus.doc_tokens)
-        doc_mask = jnp.asarray(corpus.doc_mask)
+        # the dense padded corpus stays on the HOST: every stage below moves
+        # only the block it works on to the device, so a corpus the size of
+        # the device memory can be built into a paged store that fits it
+        doc_tokens = np.asarray(corpus.doc_tokens)
+        doc_mask = np.asarray(corpus.doc_mask)
         m = doc_tokens.shape[0]
 
         # 1. training tokens (§4.2)
@@ -297,9 +300,13 @@ class LemurRetriever:
 
         # 2. ψ pre-training against m' sampled documents (§4.3)
         m_pre = min(cfg.m_pretrain, m)
-        pre_idx = jax.random.choice(keys[0], m, (m_pre,), replace=False)
+        pre_idx = np.asarray(jax.random.choice(keys[0], m, (m_pre,),
+                                               replace=False))
         g_pre = maxsim.token_maxsim(x_train, doc_tokens[pre_idx], doc_mask[pre_idx])
         phi, stats, losses = train_phi(keys[1], x_train, g_pre, cfg)
+        # the (n, m') pre-training targets are done with: 3.3 GB at the
+        # paper's n=100k, m'=8192, which the paged store needs next
+        del g_pre
         if verbose:
             print(f"[build] psi pretrain done ({time.time()-t0:.1f}s, "
                   f"loss {losses[-1]:.4f})")
@@ -348,6 +355,8 @@ class LemurRetriever:
             if verbose:
                 print(f"[build] residual codec trained "
                       f"({time.time()-t0:.1f}s)")
+        # the store re-lays W out on the host; drop the device copy first
+        W = np.asarray(W)
         index = LemurIndex.from_dense(cfg, phi["psi"], stats, W, st_tokens,
                                       st_mask, backend, ann, codec=codec)
         return cls(index, solver_state=solver)

@@ -66,6 +66,9 @@ from repro.retriever.facade import LemurRetriever
 from repro.retriever.params import SearchParams
 
 
+STATE_CHUNK_ROWS = 8192   # slot-pool rows materialized per device step
+
+
 class ShardedLemurRetriever:
     """Multi-device serving facade over a built :class:`LemurRetriever`
     (see module docstring).  Construct via ``LemurRetriever.shard(mesh)``."""
@@ -73,7 +76,7 @@ class ShardedLemurRetriever:
     def __init__(self, base: LemurRetriever, mesh, *, sq8: bool | None = None,
                  k_prime_local: int | None = None):
         self._base = base
-        self._mesh = mesh
+        self._mesh = dist.auto_axes(mesh)
         self._sq8 = bool(base.cfg.ivf.sq8) if sq8 is None else bool(sq8)
         self._k_prime_local = k_prime_local
         self._compiled: dict[tuple, Any] = {}
@@ -155,14 +158,7 @@ class ShardedLemurRetriever:
         m = idx.m
         rps = max(1, pages.next_pow2(-(-m // n) if m else 1))
         total = n * rps
-        docs, mask = pages.gather_docs(st, jnp.arange(m, dtype=jnp.int32))
-        W = jnp.asarray(st.W[:m], jnp.float32)
         alive = np.asarray(st.alive[:m])
-        pad = total - m
-        if pad:
-            W = jnp.pad(W, ((0, pad), (0, 0)))
-            docs = jnp.pad(docs, ((0, pad), (0, 0), (0, 0)))
-            mask = jnp.pad(mask, ((0, pad), (0, 0)))
         row_ids = np.full(total, -1, np.int32)
         row_ids[:m][alive] = np.arange(m, dtype=np.int32)[alive]
         row_valid = row_ids >= 0
@@ -173,17 +169,30 @@ class ShardedLemurRetriever:
             sorted(free[(free >= s * rps) & (free < (s + 1) * rps)].tolist(),
                    reverse=True)
             for s in range(n)]
-        extra = {"row_ids": jnp.asarray(row_ids),
-                 "row_valid": jnp.asarray(row_valid)}
-        if self._sq8:
-            W, w_scales = sq8_quant(W)
-            docs, doc_scales = sq8_quant(docs)
-            state = dist.ShardedRetrievalState(
-                psi=idx.psi, W=W, doc_tokens=docs, doc_mask=mask,
-                W_scales=w_scales, doc_scales=doc_scales, **extra)
-        else:
-            state = dist.ShardedRetrievalState(
-                psi=idx.psi, W=W, doc_tokens=docs, doc_mask=mask, **extra)
+        # rows are materialized (and quantized) a chunk at a time and
+        # assembled on the host, then placed straight onto their shards:
+        # no device ever holds the whole dense (total, Td, d) slot pool.
+        # Rows past m (pool padding) gather as id -1: zero tokens, no mask
+        host: dict[str, list] = {}
+        for lo in range(0, total, STATE_CHUNK_ROWS):
+            ids = jnp.arange(lo, min(lo + STATE_CHUNK_ROWS, total),
+                             dtype=jnp.int32)
+            ids = jnp.where(ids < m, ids, -1)
+            docs, mask = pages.gather_docs(st, ids)
+            W = jnp.where((ids >= 0)[:, None],
+                          jnp.take(st.W, jnp.maximum(ids, 0), axis=0),
+                          0.0).astype(jnp.float32)
+            chunk = {"doc_mask": mask}
+            if self._sq8:
+                chunk["W"], chunk["W_scales"] = sq8_quant(W)
+                chunk["doc_tokens"], chunk["doc_scales"] = sq8_quant(docs)
+            else:
+                chunk["W"], chunk["doc_tokens"] = W, docs
+            for name, a in jax.device_get(chunk).items():
+                host.setdefault(name, []).append(a)
+        leaves = {name: np.concatenate(parts) for name, parts in host.items()}
+        state = dist.ShardedRetrievalState(
+            psi=idx.psi, row_ids=row_ids, row_valid=row_valid, **leaves)
         self._state = jax.device_put(
             state, dist.state_shardings(self._mesh, state))
 

@@ -133,11 +133,8 @@ def test_elastic_restore_with_shardings(tmp_path):
     the same codepath a resized job uses."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    if hasattr(jax.sharding, "AxisType"):  # newer jax
-        mesh = jax.make_mesh((1, 1), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     tree = _tree()
     save(tmp_path, 3, tree)
     sh = jax.tree_util.tree_map(lambda _: NamedSharding(mesh, P()), tree)

@@ -17,7 +17,6 @@ import textwrap
 # two-stage pipeline degenerates to exact MaxSim and parity must be EXACT
 _BUILD = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.common import compat
 from repro.core import LemurConfig
 from repro.data import synthetic
 from repro.retriever import LemurRetriever, SearchParams, ShardedLemurRetriever
@@ -32,8 +31,8 @@ def build(m=90, k=5):
     qm = jnp.ones(q.shape[:2], bool)
     return r, q, qm
 
-MESH1 = compat.make_mesh((1,), ("model",))
-MESH8 = compat.make_mesh((2, 4), ("data", "model"))
+MESH1 = jax.make_mesh((1,), ("model",))
+MESH8 = jax.make_mesh((2, 4), ("data", "model"))
 """
 
 
@@ -266,7 +265,6 @@ def test_sharded_index_step_matches_local_ols(run_forced8):
     solve over an 8-way sharded corpus."""
     out = run_forced8("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.common import compat
     from repro.core import LemurConfig, indexer
     from repro.core.model import init_psi
     from repro.data import synthetic
@@ -280,7 +278,7 @@ def test_sharded_index_step_matches_local_ols(run_forced8):
     W_ref = indexer.fit_output_layer_ols(psi, x, docs, mask, cfg)
 
     chol, feats = indexer.gram_factor(psi, x, cfg.ridge)
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"))
     step = make_index_step(mesh, cfg, doc_block=12)
     W = jax.jit(step)(chol[0], feats, x, docs, mask, jnp.zeros(()), jnp.ones(()))
     err = float(jnp.max(jnp.abs(W - W_ref)))

@@ -1,23 +1,18 @@
 """Multi-device distribution semantics, run in a subprocess with 8 forced
 host devices via the shared ``run_forced8`` conftest fixture (the main test
-process keeps the default single device under ANY pytest ordering).
-
-All mesh/shard_map plumbing goes through ``repro.common.compat`` so the
-suite runs on every supported jax (the installed 0.4.37 has no
-``jax.set_mesh`` / ``jax.sharding.AxisType`` / top-level ``shard_map``)."""
+process keeps the default single device under ANY pytest ordering)."""
 
 
 def test_moe_ep_multi_device_matches_dense(run_forced8):
     out = run_forced8("""
     import jax, jax.numpy as jnp
-    from repro.common import compat
     from repro.nn import moe
-    mesh = compat.make_mesh((2,2,2), ("pod","data","model"),
-                            axis_types=(compat.AxisType.Auto,)*3)
+    mesh = jax.make_mesh((2,2,2), ("pod","data","model"),
+                            axis_types=(jax.sharding.AxisType.Auto,)*3)
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 32))
     p = moe.init_moe(jax.random.PRNGKey(1), 8, 32, 64, gated=True, n_shared=1)
     want, aux_w = moe.moe_apply_dense(p, x, n_experts=8, top_k=2)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for layout in ("ep", "ffslice"):
             got, aux = jax.jit(lambda p, x: moe.moe_apply(
                 p, x, layout=layout, n_experts=8, top_k=2, mesh=mesh,
@@ -32,13 +27,12 @@ def test_moe_ep_multi_device_matches_dense(run_forced8):
 def test_sharded_embedding_lookup_multi_device(run_forced8):
     out = run_forced8("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.common import compat
     from repro.models.recsys import sharded_embedding_lookup
-    mesh = compat.make_mesh((2,4), ("data","model"),
-                            axis_types=(compat.AxisType.Auto,)*2)
+    mesh = jax.make_mesh((2,4), ("data","model"),
+                            axis_types=(jax.sharding.AxisType.Auto,)*2)
     table = jax.random.normal(jax.random.PRNGKey(0), (40, 8))
     ids = jax.random.randint(jax.random.PRNGKey(1), (6, 3), 0, 40)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda t, i: sharded_embedding_lookup(t, i, mesh))(table, ids)
     want = jnp.take(table, ids, axis=0)
     assert float(jnp.max(jnp.abs(got - want))) < 1e-6
@@ -50,7 +44,6 @@ def test_sharded_embedding_lookup_multi_device(run_forced8):
 def test_gnn_sharded_forward_matches_unsharded(run_forced8):
     out = run_forced8("""
     import jax, jax.numpy as jnp
-    from repro.common import compat
     from repro.data import synthetic
     from repro.models import gnn
     g = synthetic.make_mesh_graph(64, d_feat=8, d_edge=4, d_out=2, seed=0)
@@ -66,9 +59,9 @@ def test_gnn_sharded_forward_matches_unsharded(run_forced8):
     # use exact edge count divisible instead
     s = s[:E - E % 8]; r = r[:E - E % 8]; ef = ef[:E - E % 8]
     want = gnn.forward(p, nf, ef, s, r, cfg)
-    mesh = compat.make_mesh((2,4), ("data","model"),
-                            axis_types=(compat.AxisType.Auto,)*2)
-    with compat.set_mesh(mesh):
+    mesh = jax.make_mesh((2,4), ("data","model"),
+                            axis_types=(jax.sharding.AxisType.Auto,)*2)
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda *a: gnn.forward(*a, cfg, mesh))(p, nf, ef, s, r)
     err = float(jnp.max(jnp.abs(got - want)))
     assert err < 1e-3, err
@@ -80,7 +73,6 @@ def test_gnn_sharded_forward_matches_unsharded(run_forced8):
 def test_lemur_distributed_serve_matches_local(run_forced8):
     out = run_forced8("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.common import compat
     from repro.core import LemurConfig, maxsim
     from repro.dist import ShardedRetrievalState, make_serve_step
     from repro.core.model import init_psi, pool_queries
@@ -100,11 +92,11 @@ def test_lemur_distributed_serve_matches_local(run_forced8):
     cand = jax.lax.top_k(pq @ W.T, 160)[1]
     want_s, want_i = maxsim.rerank(q, qm, cand, docs, mask, 5)
 
-    mesh = compat.make_mesh((2,2,2), ("pod","data","model"),
-                            axis_types=(compat.AxisType.Auto,)*3)
+    mesh = jax.make_mesh((2,2,2), ("pod","data","model"),
+                            axis_types=(jax.sharding.AxisType.Auto,)*3)
     state = ShardedRetrievalState(psi=psi, W=W, doc_tokens=docs, doc_mask=mask)
     serve = make_serve_step(mesh, cfg, k_prime_local=20)  # 20/shard = all local docs
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got_s, got_i = jax.jit(serve)(state, q, qm)
     assert (np.sort(np.asarray(got_i)) == np.sort(np.asarray(want_i))).all()
     np.testing.assert_allclose(np.sort(np.asarray(got_s)), np.sort(np.asarray(want_s)), rtol=1e-4)
@@ -116,7 +108,6 @@ def test_lemur_distributed_serve_matches_local(run_forced8):
 def test_lemur_distributed_index_matches_local(run_forced8):
     out = run_forced8("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.common import compat
     from repro.core import LemurConfig, indexer
     from repro.dist import make_index_step
     from repro.core.model import init_psi, psi_apply
@@ -130,10 +121,10 @@ def test_lemur_distributed_index_matches_local(run_forced8):
     W_ref = indexer.fit_output_layer_ols(psi, x, docs, mask, cfg)
 
     chol, feats = indexer.gram_factor(psi, x, cfg.ridge)
-    mesh = compat.make_mesh((2,2,2), ("pod","data","model"),
-                            axis_types=(compat.AxisType.Auto,)*3)
+    mesh = jax.make_mesh((2,2,2), ("pod","data","model"),
+                            axis_types=(jax.sharding.AxisType.Auto,)*3)
     step = make_index_step(mesh, cfg, doc_block=8)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         W = jax.jit(step)(chol[0], feats, x, docs, mask,
                           jnp.zeros(()), jnp.ones(()))
     err = float(jnp.max(jnp.abs(W - W_ref)))
@@ -146,19 +137,17 @@ def test_lemur_distributed_index_matches_local(run_forced8):
 def test_grad_compression_cross_pod(run_forced8):
     out = run_forced8("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.common import compat
-    from repro.common.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import ef_int8_allreduce
-    mesh = compat.make_mesh((4,2), ("pod","data"),
-                            axis_types=(compat.AxisType.Auto,)*2)
+    mesh = jax.make_mesh((4,2), ("pod","data"),
+                            axis_types=(jax.sharding.AxisType.Auto,)*2)
     g = jax.random.normal(jax.random.PRNGKey(0), (4, 64))  # 4 pod-shards
     err0 = jnp.zeros((4, 64))
     def body(g, e):
         r, ne = ef_int8_allreduce({"g": g[0]}, {"g": e[0]}, "pod")
         return r["g"][None], ne["g"][None]
-    with compat.set_mesh(mesh):
-        red, new_err = jax.jit(lambda g, e: shard_map(
+    with jax.set_mesh(mesh):
+        red, new_err = jax.jit(lambda g, e: jax.shard_map(
             body, mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")),
             check_vma=False)(g, e))(g, err0)
     # each pod-shard sees ~the mean of the 4 int8-quantized rows

@@ -21,23 +21,23 @@ def test_scan_trip_count_correction():
 
 
 def test_collectives_inside_scan_multiplied():
-    from repro.common.compat import AxisType, make_mesh, set_mesh, shard_map
+    from jax.sharding import AxisType
     from jax.sharding import PartitionSpec as P
 
-    mesh = make_mesh((1, 1), ("data", "model"),
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
                      axis_types=(AxisType.Auto,) * 2)
 
     def g(x):
         def body(c, _):
             def inner(v):
                 return jax.lax.psum(v @ v, "model")
-            return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
+            return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
                              check_vma=False)(c), None
         y, _ = jax.lax.scan(body, x, None, length=5)
         return y
 
     spec = jax.ShapeDtypeStruct((32, 32), jnp.float32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = jax.jit(g).lower(spec).compile()
     r = analyze(comp.as_text())
     assert r["collective_count"].get("all-reduce", 0) == 5

@@ -76,10 +76,10 @@ def test_ef_int8_allreduce_error_feedback():
     """Over many steps the error-feedback compression is unbiased: the sum of
     dequantized transmissions converges to the sum of true gradients."""
     from repro.optim.compress import ef_int8_allreduce
-    from repro.common.compat import AxisType, make_mesh, shard_map
+    from jax.sharding import AxisType
     from jax.sharding import PartitionSpec as P
 
-    mesh = make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
     g_true = [jnp.asarray(rng.standard_normal(32), jnp.float32) for _ in range(30)]
     err = {"g": jnp.zeros(32)}
@@ -88,7 +88,7 @@ def test_ef_int8_allreduce_error_feedback():
         def body(g, e):
             return ef_int8_allreduce({"g": g}, e, "pod")
 
-        (red, err) = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+        (red, err) = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
                                check_vma=False)(g, err)
         sent_total = sent_total + red["g"]
     true_total = sum(np.asarray(g) for g in g_true)

@@ -103,9 +103,8 @@ def test_server_matches_sharded_direct_search(base):
     resident corpus): bucketed answers == direct sharded search.  The
     8-device twin runs in test_dist_serve.py::test_online_server_sharded_
     parity."""
-    from repro.common import compat
 
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",))
     params = SearchParams(use_ann=False)
     for sq8 in (False, True):
         sr = base.shard(mesh, sq8=sq8)        # served instance

@@ -88,7 +88,7 @@ def test_cells_resolve_specs_for_lm_and_recsys():
     only)."""
     from jax.sharding import NamedSharding
 
-    from repro.common.compat import make_mesh
+    from jax import make_mesh
     from repro.launch import cells
     from repro.models import lm, recsys
 

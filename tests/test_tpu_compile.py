@@ -1,0 +1,176 @@
+"""Compile the serving kernels for a TPU v5e at the paper's widths.
+
+Interpret mode (every other kernel test) cannot see what the TPU lowering
+refuses: block shapes off the (8, 128) tiling, VMEM or SMEM over budget,
+primitives Mosaic has no rule for.  These cases compile each main-path
+kernel ahead of time for a described (not attached) v5e chip — nothing
+runs — at d=128, d'=2048, k'=1024, Tq=32, 80 tokens/doc (5 pages of 16),
+IVF lists of cap 2048, a server batch of 8 and an offline batch of 64;
+the reranks also at a k' as long as the corpus, and the sharded serve step
+over a 2x2 mesh.
+
+The topology is described inside a module fixture, never at import, and
+the persistent compilation cache is off while these compiles run (a
+compile for a described chip cannot be read back without one).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import gather_scan, ops, query_fused
+
+D, DP, KP, TQ, PAGE, PMAX, NPROBE, CAP = 128, 2048, 1024, 32, 16, 5, 32, 2048
+P_PAGES, SLOTS = 1 << 20, 1 << 17     # ~131k docs x 67.5 tokens in pages
+LEVELS, NCENT = 16, 256               # 4-bit residual codec
+F32, I32, I8, U8, BOOL = jnp.float32, jnp.int32, jnp.int8, jnp.uint8, jnp.bool_
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
+
+
+def _cases(B, kp=KP):
+    """(fn, args-as-(shape, dtype), static kwargs) per kernel.  IVF list
+    counts are cut so each operand set fits one chip's 16 GB."""
+    q, probe = ((B, DP), F32), ((B, NPROBE), I32)
+    qt, qm, cand = ((B, TQ, D), F32), ((B, TQ), BOOL), ((B, kp), I32)
+    table, ntok = ((SLOTS, PMAX), I32), ((SLOTS,), I32)
+    m_loc = SLOTS // 4                 # one shard of a 2x2 mesh
+    return {
+        "ivf_probe_scan-fp32": (gather_scan.ivf_probe_scan, [
+            q, probe, ((256, CAP), I32), ((256, CAP, DP), F32)], {}),
+        "ivf_probe_scan-sq8": (gather_scan.ivf_probe_scan, [
+            q, probe, ((1024, CAP), I32), ((1024, CAP, DP), I8),
+            ((1024, CAP), F32)], {}),
+        "ivf_probe_scan-res4": (gather_scan.ivf_probe_res_scan, [
+            q, probe, ((1024, CAP), I32), ((1024, CAP, DP // 2), U8),
+            ((1024, DP), F32), ((DP, LEVELS), F32)], {}),
+        "rerank_paged_scores": (gather_scan.rerank_paged_scores, [
+            qt, qm, cand, ((P_PAGES, PAGE, D), F32), table, ntok], {}),
+        "rerank_paged_res_scores": (gather_scan.rerank_paged_res_scores, [
+            qt, qm, cand, ((P_PAGES, PAGE), I32),
+            ((P_PAGES, PAGE, D // 2), U8), table, ntok, ((NCENT, D), F32),
+            ((D, LEVELS), F32)], {}),
+        "rerank_gather_scores-fp32": (gather_scan.rerank_gather_scores, [
+            qt, qm, cand, ((m_loc, PMAX * PAGE, D), F32),
+            ((m_loc, PMAX * PAGE), BOOL)], {}),
+        "rerank_gather_scores-sq8": (gather_scan.rerank_gather_scores, [
+            qt, qm, cand, ((m_loc, PMAX * PAGE, D), I8),
+            ((m_loc, PMAX * PAGE), BOOL), ((m_loc, PMAX * PAGE), F32)], {}),
+    }
+
+
+CASES = sorted(_cases(1))
+
+
+@pytest.mark.parametrize("batch", [8, 64])
+@pytest.mark.parametrize("name", CASES)
+def test_kernel_compiles_for_v5e(one_chip, name, batch):
+    fn, specs, kw = _cases(batch)[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in specs]
+    compiled = fn.lower(*args, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,kp", [
+    ("rerank_paged_scores", SLOTS),              # k' = the whole corpus
+    ("rerank_gather_scores-fp32", SLOTS // 4),   # one 2x2 shard's rows
+    ("rerank_gather_scores-sq8", SLOTS // 4),
+])
+def test_long_kprime_rerank_compiles_for_v5e(one_chip, name, kp):
+    """A k' too long for one SMEM prefetch strip is split into candidate
+    chunks scored in a loop; the split program must still lower."""
+    fn, specs, kw = _cases(8, kp)[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in specs]
+    compiled = fn.lower(*args, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_serve_step_compiles_for_v5e_2x2(v5e, monkeypatch):
+    """The corpus-sharded serve step over a 2x2 mesh at the paper's widths:
+    per-shard latent scan, the gather rerank kernel, all-gather merge."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro import dist
+    from repro.configs.lemur_paper import CONFIG
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # the TPU dispatch
+    mesh = Mesh(np.array(v5e.devices[:4]).reshape(2, 2), ("data", "model"))
+    S = lambda s, dt, spec: jax.ShapeDtypeStruct(
+        s, dt, sharding=NamedSharding(mesh, spec))
+    rows = P(("data", "model"))
+    vec = S((DP,), F32, P())
+    psi = {"dense": {"kernel": S((D, DP), F32, P()), "bias": vec},
+           "ln": {"scale": vec, "bias": vec}}
+    td = PMAX * PAGE
+    state = dist.ShardedRetrievalState(
+        psi=psi, W=S((SLOTS, DP), F32, rows),
+        doc_tokens=S((SLOTS, td, D), F32, rows),
+        doc_mask=S((SLOTS, td), BOOL, rows),
+        row_ids=S((SLOTS,), I32, rows), row_valid=S((SLOTS,), BOOL, rows))
+    step = dist.make_serve_step(mesh, CONFIG)
+    compiled = jax.jit(step).lower(state, S((8, TQ, D), F32, P()),
+                                   S((8, TQ), BOOL, P())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+
+
+@pytest.mark.parametrize("name", ["mips_topk", "query_fused"])
+def test_one_launch_top_k_is_refused_for_v5e(one_chip, name):
+    """The one-launch kernels merge a carried top-k' with ``lax.top_k``
+    in-kernel, which Mosaic cannot lower; ``ops`` therefore raises on the
+    TPU.  When a jax release lowers it, this case fails and the guard in
+    ``kernels.ops._one_launch_kernel`` can go."""
+    S = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    if name == "mips_topk":
+        lowered = lambda: query_fused.mips_topk.lower(
+            S((8, DP), F32), S((SLOTS // 4, DP), I8), S((SLOTS // 4,), F32),
+            S((SLOTS // 4,), BOOL), kp=KP)
+    else:
+        lowered = lambda: query_fused.query_fused.lower(
+            S((8, TQ, D), F32), S((8, TQ), BOOL), S((D, DP), F32),
+            S((DP,), F32), S((DP,), F32), S((DP,), F32),
+            S((8, NPROBE), I32), S((1024, CAP), I32),
+            S((1024, CAP, DP), I8), S((1024, CAP), F32), kp=KP)
+    with pytest.raises(NotImplementedError, match="top_k"):
+        lowered().compile()
+
+
+@pytest.mark.parametrize("path", ["mips_topk_fused", "fused_query"])
+def test_one_launch_raises_on_tpu(monkeypatch, path):
+    """On the TPU the one-launch paths raise a clear error instead of
+    answering from the XLA reference."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    q = jnp.zeros((1, 4), F32)
+    with pytest.raises(NotImplementedError, match="use_one_launch=False"):
+        if path == "mips_topk_fused":
+            ops.mips_topk_fused(q, jnp.zeros((8, 4), F32), None, 2)
+        else:
+            psi = {"dense": {"kernel": jnp.zeros((4, 4)),
+                             "bias": jnp.zeros(4)},
+                   "ln": {"scale": jnp.ones(4), "bias": jnp.zeros(4)}}
+            ops.fused_query(jnp.zeros((1, 2, 4)), jnp.ones((1, 2), bool),
+                            psi, jnp.zeros((2, 4)), jnp.zeros((2, 8), I32),
+                            jnp.zeros((2, 8, 4)), nprobe=1, kp=2)
