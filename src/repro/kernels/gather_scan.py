@@ -61,6 +61,11 @@ page-id strips are flat per query and split, with their queries, into
 groups that fit SMEM.  Because per-token dots
 are unchanged and max is order-independent, scores are bit-identical to the
 dense-slab kernel's on the same docs.
+
+Every ``pallas_call`` is named after the function that issues it
+(``name="ivf_probe_scan"``, ``"rerank_paged_scores"``, …), so a compiled
+program and a device trace know each kernel by that name, also where it
+runs inside a ``lax.map`` over row groups.
 """
 from __future__ import annotations
 
@@ -153,6 +158,7 @@ def ivf_probe_scan(q, probe, ids, vecs, scales=None, *, interpret: bool = False)
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nprobe, 1, cap), jnp.float32),
         interpret=interpret,
+        name="ivf_probe_scan",
     )(probe.astype(jnp.int32), *args)
     return out.reshape(B, nprobe, cap)
 
@@ -299,6 +305,7 @@ def rerank_gather_scores(q, q_mask, cand_ids, doc_tokens, doc_mask,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
             interpret=interpret,
+            name="rerank_gather_scores",
         )(cr, q, qm, doc_tokens, *cand)
 
     return _over_row_chunks(call, (safe,), per_row).reshape(B, kp)
@@ -390,6 +397,7 @@ def rerank_paged_scores(q, q_mask, cand_ids, tok_pages, page_table, n_tokens,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
             interpret=interpret,
+            name="rerank_paged_scores",
         )(pt, nt, q, qm, tok_pages)
 
     q, q_mask = _fold_rows(nc, q, q_mask)
@@ -549,6 +557,7 @@ def ivf_probe_res_scan(q, probe, ids, codes, centroids, values, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nprobe, 1, cap), jnp.float32),
         interpret=interpret,
+        name="ivf_probe_res_scan",
     )(probe.astype(jnp.int32), q.reshape(B, 1, d), ids.reshape(nlist, 1, cap),
       codes, centroids.reshape(nlist, 1, d), values.T)
     return out.reshape(B, nprobe, cap)
@@ -639,6 +648,7 @@ def rerank_paged_res_scores(q, q_mask, cand_ids, cent_pages, code_pages,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
             interpret=interpret,
+            name="rerank_paged_res_scores",
         )(pt, nt, q, qm, cent_pages, code_pages, centroids, values.T)
 
     q, q_mask = _fold_rows(nc, q, q_mask)

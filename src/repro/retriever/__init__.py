@@ -17,7 +17,11 @@ from repro.anns.params import (
     TokenPruningBackendConfig,
     TokenPruningSearchParams,
 )
-from repro.retriever.facade import CorruptIndexError, LemurRetriever
+from repro.retriever.facade import (
+    CorruptIndexError,
+    LemurRetriever,
+    xla_compile_count,
+)
 from repro.retriever.params import SearchParams
 from repro.retriever.sharded import ShardedLemurRetriever
 
@@ -34,4 +38,5 @@ __all__ = [
     "MuveraBackendConfig",
     "DessertBackendConfig",
     "TokenPruningBackendConfig",
+    "xla_compile_count",
 ]

@@ -36,6 +36,16 @@ Design points:
   index wrapped directly), the corpus-sampling fallback takes an explicit
   ``seed`` instead of the v0 hidden ``default_rng(0)``.
 
+* **Observability.**  :meth:`trace_count` counts Python traces of the
+  query fns; :func:`xla_compile_count` counts what XLA did for them and
+  every other jitted function of the process (backend compiles and
+  persistent-cache loads), so a compile no retrace announced is seen too.
+  ``search`` is one ``lemur.search`` host span in a profiler trace
+  (``jax.profiler.TraceAnnotation``; nothing is recorded without an active
+  trace), and the device program names its two stages with
+  ``jax.named_scope``: every op of the first stage under ``first_stage/``,
+  every op of the rerank and the final top-k under ``rerank/``.
+
 * **Persistence.**  ``save()``/``load()`` use ``checkpoint/manager.py``'s
   atomic manifest+shards format: cfg, ψ, W, doc tokens, the backend name
   and its opaque packed state (plus the OLS tokens, so ``add()`` stays
@@ -47,8 +57,10 @@ The v0 free functions (``core.index.build_index`` / ``attach_backend`` /
 """
 from __future__ import annotations
 
+import bisect
 import json
 import pathlib
+import threading
 import time
 from typing import Any
 
@@ -80,6 +92,40 @@ class CorruptIndexError(ValueError):
     failure — no quarantine."""
 
     preserves_replica_state = True
+
+
+# --------------------------------------------------------------------------
+# process-wide XLA compile accounting
+# --------------------------------------------------------------------------
+
+# jax brackets every backend compile request in this duration event, whether
+# XLA compiles or the persistent cache hands back the executable (the cache
+# hit records an event of its own inside it, which would count it twice)
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_compiles_lock = threading.Lock()
+_compile_times: list[float] = []   # perf_counter at each request's end
+
+
+def _on_backend_compile(event: str, duration_secs: float, **_) -> None:
+    if event == _BACKEND_COMPILE:
+        with _compiles_lock:
+            _compile_times.append(time.perf_counter())
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_backend_compile)
+
+
+def xla_compile_count(since: float | None = None) -> int:
+    """XLA executables this process has made ready: backend compiles and
+    persistent-cache loads alike.  Counts every jitted function, not only
+    the query fns (:meth:`LemurRetriever.trace_count` counts Python
+    traces, which a compile need not follow).  ``since`` (a
+    ``time.perf_counter()`` reading) counts only those finished after it."""
+    with _compiles_lock:
+        if since is None:
+            return len(_compile_times)
+        return len(_compile_times) - bisect.bisect_right(_compile_times,
+                                                         since)
 
 
 # --------------------------------------------------------------------------
@@ -140,25 +186,32 @@ def search_pipeline(index: LemurIndex, q_tokens, q_mask, params: SearchParams):
     materializing the ``(B, k', Tm, d)`` gather in HBM); ``False`` keeps
     the legacy materialize-from-pages + ``maxsim.rerank_gathered`` path
     benchmarkable — both return bit-identical ids on fp32."""
-    cand = first_stage(index, q_tokens, q_mask, params)
+    # the two stages are named scopes: each device op's op_name (and the
+    # profiler's view of it) says which stage it belongs to.  A scope must
+    # hold the whole kernel call: the while a lax.map lowers to takes the
+    # scope of the map's caller, not of its body
+    with jax.named_scope("first_stage"):
+        cand = first_stage(index, q_tokens, q_mask, params)
     store = index.store
-    if store.residual and params.use_residual and params.use_fused_gather:
-        # compressed tier, fused path: candidate pages are DMA'd as centroid
-        # ids + packed residual codes and dequantized INSIDE the rerank
-        # kernel — fp32 token pages never exist
-        return ops.fused_rerank_paged_res(
-            q_tokens, q_mask, cand, store.cent_pages, store.code_pages,
-            store.page_table, store.n_tokens, store.codec.centroids,
-            store.codec.values, params.k)
-    if params.use_fused_gather and not store.residual:
-        return ops.fused_rerank_paged(q_tokens, q_mask, cand,
-                                      store.tok_pages, store.page_table,
-                                      store.n_tokens, params.k)
-    # legacy HBM gather; on the compressed tier gather_docs residual-decodes
-    # on the fly, so this is also the use_residual=False decoded-view path
-    toks, tmask = pages.gather_docs(store, cand)
-    return maxsim.rerank_gathered(q_tokens, q_mask, cand, toks, tmask,
-                                  params.k)
+    with jax.named_scope("rerank"):
+        if store.residual and params.use_residual and params.use_fused_gather:
+            # compressed tier, fused path: candidate pages are DMA'd as
+            # centroid ids + packed residual codes and dequantized INSIDE
+            # the rerank kernel — fp32 token pages never exist
+            return ops.fused_rerank_paged_res(
+                q_tokens, q_mask, cand, store.cent_pages, store.code_pages,
+                store.page_table, store.n_tokens, store.codec.centroids,
+                store.codec.values, params.k)
+        if params.use_fused_gather and not store.residual:
+            return ops.fused_rerank_paged(q_tokens, q_mask, cand,
+                                          store.tok_pages, store.page_table,
+                                          store.n_tokens, params.k)
+        # legacy HBM gather; on the compressed tier gather_docs
+        # residual-decodes on the fly, so this is also the
+        # use_residual=False decoded-view path
+        toks, tmask = pages.gather_docs(store, cand)
+        return maxsim.rerank_gathered(q_tokens, q_mask, cand, toks, tmask,
+                                      params.k)
 
 
 def launch_plan(resolved: SearchParams) -> dict[str, int]:
@@ -617,11 +670,14 @@ class LemurRetriever:
 
         Runs the compiled pool -> candidates -> exact-rerank pipeline for
         the resolved params (one XLA graph; compiled once per params and
-        batch shape)."""
-        q_tokens = jnp.asarray(q_tokens)
-        if q_mask is None:
-            q_mask = jnp.ones(q_tokens.shape[:2], bool)
-        return self._compiled_fn(self.resolve(params))(q_tokens, q_mask)
+        batch shape).  Returns once the program is dispatched, before the
+        device finishes it; the ``lemur.search`` host span covers the
+        query's host-to-device copy, ``resolve`` and the dispatch."""
+        with jax.profiler.TraceAnnotation("lemur.search"):
+            q_tokens = jnp.asarray(q_tokens)
+            if q_mask is None:
+                q_mask = jnp.ones(q_tokens.shape[:2], bool)
+            return self._compiled_fn(self.resolve(params))(q_tokens, q_mask)
 
     def candidates(self, q_tokens, q_mask=None,
                    params: SearchParams | None = None):
@@ -668,7 +724,9 @@ class LemurRetriever:
 
     def trace_count(self, params: SearchParams | None = None) -> int:
         """jit traces so far: for one resolved SearchParams, or in total.
-        The API contract is one trace per (backend, params, batch-shape)."""
+        The API contract is one trace per (backend, params, batch-shape).
+        What XLA compiled, or loaded from the persistent cache, for them is
+        :func:`xla_compile_count`."""
         if params is None:
             return sum(self._trace_counts.values())
         return self._trace_counts.get((self.backend, self.resolve(params)), 0)

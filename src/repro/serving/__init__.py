@@ -6,7 +6,8 @@ add over the Retriever API (single-device and sharded facades).
 * :mod:`repro.serving.server` — :class:`RetrieverServer`: thread-safe
   request queue, micro-batcher (``max_batch`` / ``max_wait_us``), streaming
   ``add()`` with atomic snapshot swap between micro-batches, and
-  :class:`ServerStats` (latency percentiles, QPS, occupancy histograms).
+  :class:`ServerStats` (latency percentiles, queue wait, QPS, occupancy
+  histogram).
 * :mod:`repro.serving.replay` — seeded Poisson arrival traces + the
   open-loop replay/warmup loop shared by the launcher, the online
   benchmark, and the example demo.

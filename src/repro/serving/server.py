@@ -39,10 +39,21 @@ facade (or its sharded twin) into an online service:
 * **Observability.**  :class:`ServerStats` tracks per-request latency
   percentiles (p50/p95/p99) measured from each request's *scheduled arrival*
   (``t_arrival``, free of coordinated omission under open-loop replay) with
-  the submit-call-relative twins alongside (``submit_p*_ms``), QPS over the
-  serving window, micro-batch occupancy and bucket histograms, and
-  rejected/expired counters; ``trace_count()``/``trace_shapes()`` pass
-  through to the underlying retriever.
+  the submit-call-relative twins alongside (``submit_p*_ms``), the queue
+  wait (arrival to admission into a micro-batch), QPS from the first
+  admission, the micro-batch occupancy histogram, and rejected/expired
+  counters; ``trace_count()``/``trace_shapes()`` pass through to the
+  underlying retriever.  Every future of a served request carries
+  ``batch_id`` (the micro-batch's sequence number) and ``queue_wait_s``
+  beside ``request_id``.  The worker writes host spans into an active
+  profiler trace (``jax.profiler.TraceAnnotation``, on the device trace's
+  clock; nothing is recorded without one): ``lemur.serve.collect`` from
+  the loop top to a formed batch, and per micro-batch ``lemur.serve.batch``
+  (metadata ``batch`` = ``batch_id``, ``n`` = real rows) holding
+  ``lemur.serve.pad``, ``lemur.serve.search`` (the retriever call, which
+  returns at dispatch), ``lemur.serve.fetch`` (the wait on the device and
+  the copy to the host) and ``lemur.serve.resolve`` (stats and every
+  future with its callbacks).  Mutation barriers get no span.
 
 The server works over any object with the facade serving surface
 (``search``/``add``/``resolve``/``trace_count``) — both ``LemurRetriever``
@@ -60,6 +71,7 @@ from concurrent.futures import Future
 from typing import Any
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.buckets import BucketLadder
 
@@ -95,9 +107,10 @@ class Overloaded(RuntimeError):
 class ServerStats:
     """Per-request latency + micro-batch shape accounting (thread-safe).
 
-    Latencies are kept in a bounded sliding window (``window`` most recent
-    requests) so a long-lived server never grows without bound; counters
-    (requests, batches, occupancy/bucket histograms) are exact totals."""
+    Latencies and queue waits are kept in bounded sliding windows
+    (``window`` most recent requests) so a long-lived server never grows
+    without bound; counters (requests, batches, the occupancy histogram)
+    are exact totals."""
 
     def __init__(self, window: int = 100_000):
         self._lock = threading.Lock()
@@ -110,8 +123,10 @@ class ServerStats:
         # kept so replays can assert the two diverge under submit-side stall
         self._submit_lat: collections.deque[float] = collections.deque(
             maxlen=window)
+        # arrival to admission into a micro-batch
+        self._queue_wait: collections.deque[float] = collections.deque(
+            maxlen=window)
         self._occupancy = collections.Counter()   # n_real per micro-batch
-        self._buckets = collections.Counter()     # (batch_bucket, tq_bucket)
         self._n_requests = 0
         self._n_batches = 0
         self._n_rejected = 0
@@ -119,17 +134,21 @@ class ServerStats:
         self._t_first: float | None = None
         self._t_last: float | None = None
 
-    def record_batch(self, latencies_s, submit_latencies_s, n_real: int,
-                     batch_bucket: int, tq_bucket: int, t_done: float) -> None:
+    def record_batch(self, latencies_s, submit_latencies_s, queue_waits_s,
+                     n_real: int, t_admit: float, t_done: float) -> None:
+        """One served micro-batch: per-request latencies and queue waits,
+        its real rows, when it was admitted and when its answers were
+        ready.  QPS is timed from the first batch's admission, so every
+        request counted was served inside the span."""
         with self._lock:
             self._latencies.extend(latencies_s)
             self._submit_lat.extend(submit_latencies_s)
+            self._queue_wait.extend(queue_waits_s)
             self._n_requests += len(latencies_s)
             self._occupancy[n_real] += 1
-            self._buckets[(batch_bucket, tq_bucket)] += 1
             self._n_batches += 1
             if self._t_first is None:
-                self._t_first = t_done
+                self._t_first = t_admit
             self._t_last = t_done
 
     def record_rejected(self, n: int = 1) -> None:
@@ -169,20 +188,18 @@ class ServerStats:
         return {f"p{q}": float(np.percentile(lat, q) * 1e3) for q in qs}
 
     def summary(self) -> dict:
-        """One JSON-able dict: percentiles, QPS over the serving window,
-        occupancy/bucket histograms, reject/expiry counters.  ``p*_ms`` are
-        measured from scheduled arrival; ``submit_p*_ms`` from the (possibly
-        delayed) submit call — under open-loop backlog only the former is
-        honest (coordinated omission)."""
+        """One JSON-able dict: percentiles, QPS from the first admission to
+        the last answer, the occupancy histogram, reject/expiry counters.
+        ``p*_ms`` are measured from scheduled arrival; ``submit_p*_ms`` from
+        the (possibly delayed) submit call — under open-loop backlog only
+        the former is honest (coordinated omission).  ``queue_wait_mean_ms``
+        runs from arrival to admission into a micro-batch."""
         pct = self.percentiles()
         with self._lock:
             n = self._n_requests
             span = ((self._t_last - self._t_first)
-                    if (self._t_first is not None and self._n_batches > 1)
-                    else 0.0)
+                    if self._t_first is not None else 0.0)
             occ = dict(sorted(self._occupancy.items()))
-            buckets = {f"{b}x{t}": c
-                       for (b, t), c in sorted(self._buckets.items())}
             n_batches = self._n_batches
             mean_ms = (float(np.mean(np.fromiter(self._latencies,
                                                  np.float64)) * 1e3)
@@ -191,6 +208,7 @@ class ServerStats:
             sub_pct = ({f"submit_p{q}_ms": float(np.percentile(sub, q) * 1e3)
                         for q in (50, 95, 99)} if sub.size else
                        {f"submit_p{q}_ms": float("nan") for q in (50, 95, 99)})
+            wait = np.fromiter(self._queue_wait, np.float64)
             n_rejected, n_expired = self._n_rejected, self._n_expired
         return {
             "n_requests": n,
@@ -200,10 +218,11 @@ class ServerStats:
             "mean_ms": mean_ms,
             **{f"{k}_ms": v for k, v in pct.items()},
             **sub_pct,
+            "queue_wait_mean_ms": (float(np.mean(wait) * 1e3) if wait.size
+                                   else float("nan")),
             "qps": n / span if span > 0 else float("nan"),
             "mean_occupancy": n / max(n_batches, 1),
             "occupancy_hist": occ,
-            "bucket_hist": buckets,
         }
 
 
@@ -266,6 +285,7 @@ class RetrieverServer:
         self._cond = threading.Condition()
         self._stats = ServerStats()
         self._rid = 0
+        self._batch_seq = 0    # micro-batches served; only the worker counts
         self._stopping = False
         self._drain = True
         self._paused = False
@@ -374,7 +394,9 @@ class RetrieverServer:
         """Enqueue one ragged query — ``q_tokens: (Tq, d)`` (a leading
         singleton batch axis is accepted and squeezed).  Returns a future
         resolving to ``(scores (k,), ids (k,))`` with ``future.request_id``
-        set; FIFO submission order is preserved relative to ``add()``.
+        set, and ``future.batch_id`` / ``future.queue_wait_s`` once it is
+        admitted to a micro-batch; FIFO submission order is preserved
+        relative to ``add()``.
 
         ``t_arrival`` is the request's scheduled arrival (perf_counter
         offset) — open-loop replays pass it so latency is measured from the
@@ -496,65 +518,78 @@ class RetrieverServer:
 
     def _serve_loop_inner(self) -> None:
         while True:
-            batch: list[_Search] = []
-            mut_op: _Mutation | None = None
-            expired: list[_Search] = []
-            with self._cond:
-                # wedge while paused (unless a non-drain stop must cancel),
-                # or while idle; an idle queue is a sign of life
-                while ((self._paused
-                        and not (self._stopping and not self._drain))
-                       or (not self._queue and not self._stopping)):
-                    if not self._queue and not self._paused:
-                        self._progress_t = time.perf_counter()
-                    self._cond.wait(timeout=0.05 if self._paused else None)
-                if not self._queue and self._stopping:
-                    return
-                if self._stopping and not self._drain:
-                    # cancel-don't-leak: every queued future (searches AND
-                    # mutation barriers) resolves with CancelledError to its
-                    # waiters — Future.cancel() on a pending future always
-                    # succeeds here because the worker (sole executor) is
-                    # the one abandoning it
-                    for op in self._queue:
-                        op.future.cancel()
-                    self._queue.clear()
-                    return
-                # deadline sweep: pull expired searches out of the queue now,
-                # resolve them typed once the lock is dropped
-                now = time.perf_counter()
-                expired = [op for op in self._queue
-                           if isinstance(op, _Search)
-                           and op.deadline is not None and now > op.deadline]
-                if expired:
-                    gone = set(map(id, expired))
-                    kept = [op for op in self._queue if id(op) not in gone]
-                    self._queue.clear()
-                    self._queue.extend(kept)
-                if self._queue:
-                    if self._stopping and self._drain:
-                        # drain ordering guarantee: pending mutation barriers
-                        # are flushed BEFORE the remaining searches are
-                        # served, so drained results reflect the final
-                        # snapshot version
-                        muts = [op for op in self._queue
-                                if isinstance(op, _Mutation)]
-                        if muts and not isinstance(self._queue[0], _Mutation):
-                            rest = [op for op in self._queue
-                                    if not isinstance(op, _Mutation)]
-                            self._queue.clear()
-                            self._queue.extend(muts + rest)
-                    head = self._queue[0]
-                    if isinstance(head, _Mutation):
-                        mut_op = self._queue.popleft()
-                    else:
-                        batch = self._collect_batch(head)
+            # the worker's time between micro-batches: waiting for requests,
+            # the max_wait fill and the lock hand-offs
+            with TraceAnnotation("lemur.serve.collect"):
+                work = self._next_work()
+            if work is None:
+                return
+            expired, mut_op, batch, t_admit = work
             if expired:
                 self._resolve_expired(expired)
             if mut_op is not None:
                 self._apply_mutation(mut_op)
             elif batch:
-                self._run_batch(batch)
+                self._run_batch(batch, t_admit)
+
+    def _next_work(self):
+        """Wait for work and take it off the queue: ``(expired searches,
+        a mutation or None, a micro-batch, its admission time)``, or None
+        when the worker should exit."""
+        batch: list[_Search] = []
+        mut_op: _Mutation | None = None
+        expired: list[_Search] = []
+        with self._cond:
+            # wedge while paused (unless a non-drain stop must cancel),
+            # or while idle; an idle queue is a sign of life
+            while ((self._paused
+                    and not (self._stopping and not self._drain))
+                   or (not self._queue and not self._stopping)):
+                if not self._queue and not self._paused:
+                    self._progress_t = time.perf_counter()
+                self._cond.wait(timeout=0.05 if self._paused else None)
+            if not self._queue and self._stopping:
+                return None
+            if self._stopping and not self._drain:
+                # cancel-don't-leak: every queued future (searches AND
+                # mutation barriers) resolves with CancelledError to its
+                # waiters — Future.cancel() on a pending future always
+                # succeeds here because the worker (sole executor) is
+                # the one abandoning it
+                for op in self._queue:
+                    op.future.cancel()
+                self._queue.clear()
+                return None
+            # deadline sweep: pull expired searches out of the queue now,
+            # resolve them typed once the lock is dropped
+            now = time.perf_counter()
+            expired = [op for op in self._queue
+                       if isinstance(op, _Search)
+                       and op.deadline is not None and now > op.deadline]
+            if expired:
+                gone = set(map(id, expired))
+                kept = [op for op in self._queue if id(op) not in gone]
+                self._queue.clear()
+                self._queue.extend(kept)
+            if self._queue:
+                if self._stopping and self._drain:
+                    # drain ordering guarantee: pending mutation barriers
+                    # are flushed BEFORE the remaining searches are
+                    # served, so drained results reflect the final
+                    # snapshot version
+                    muts = [op for op in self._queue
+                            if isinstance(op, _Mutation)]
+                    if muts and not isinstance(self._queue[0], _Mutation):
+                        rest = [op for op in self._queue
+                                if not isinstance(op, _Mutation)]
+                        self._queue.clear()
+                        self._queue.extend(muts + rest)
+                head = self._queue[0]
+                if isinstance(head, _Mutation):
+                    mut_op = self._queue.popleft()
+                else:
+                    batch = self._collect_batch(head)
+        return expired, mut_op, batch, time.perf_counter()
 
     def _resolve_expired(self, expired: list[_Search]) -> None:
         """Resolve swept requests with a typed :class:`DeadlineExceeded` —
@@ -601,7 +636,7 @@ class RetrieverServer:
         self._queue.extend(kept)
         return batch
 
-    def _run_batch(self, batch: list[_Search]) -> None:
+    def _run_batch(self, batch: list[_Search], t_admit: float) -> None:
         # last-chance expiry filter: a request whose deadline passed during
         # collection resolves typed and never occupies a micro-batch slot
         now = time.perf_counter()
@@ -616,29 +651,43 @@ class RetrieverServer:
         # a batch entering execution is progress too: without this stamp a
         # long (e.g. freshly-invalidated-compile) batch looks like a stall
         self._progress_t = time.perf_counter()
-        try:
-            q, qm, n_real = self._ladder.pad_batch(
-                [op.q for op in batch], [op.qm for op in batch])
-            scores, ids = self._retriever.search(q, qm, batch[0].params)
-            scores = np.asarray(scores)   # blocks until ready
-            ids = np.asarray(ids)
-        except Exception as e:  # noqa: BLE001 — the request owns the error
-            for op in batch:
-                op.future.set_exception(e)
-            return
-        t_done = time.perf_counter()
-        self._progress_t = t_done
-        # record stats BEFORE resolving any future: a client unblocked by the
-        # last result may immediately read/reset the stats window, and this
-        # batch must already be in it
-        self._stats.record_batch([t_done - op.t_arrival for op in batch],
-                                 [t_done - op.t_submit for op in batch],
-                                 n_real, q.shape[0], q.shape[1], t_done)
-        version = getattr(self._retriever, "version", None)
-        for i, op in enumerate(batch):
-            # which corpus snapshot answered (facade.version, bumped per add)
-            op.future.snapshot_version = version
-            op.future.set_result((scores[i], ids[i]))
+        seq = self._batch_seq
+        self._batch_seq += 1
+        for op in batch:
+            op.future.batch_id = seq
+            op.future.queue_wait_s = t_admit - op.t_arrival
+        with TraceAnnotation("lemur.serve.batch", batch=seq, n=len(batch)):
+            try:
+                with TraceAnnotation("lemur.serve.pad"):
+                    q, qm, n_real = self._ladder.pad_batch(
+                        [op.q for op in batch], [op.qm for op in batch])
+                with TraceAnnotation("lemur.serve.search"):
+                    scores, ids = self._retriever.search(q, qm,
+                                                         batch[0].params)
+                with TraceAnnotation("lemur.serve.fetch"):
+                    scores = np.asarray(scores)   # blocks until ready
+                    ids = np.asarray(ids)
+            except Exception as e:  # noqa: BLE001 — the request owns the error
+                for op in batch:
+                    op.future.set_exception(e)
+                return
+            t_done = time.perf_counter()
+            self._progress_t = t_done
+            with TraceAnnotation("lemur.serve.resolve"):
+                # record stats BEFORE resolving any future: a client
+                # unblocked by the last result may immediately read/reset
+                # the stats window, and this batch must already be in it
+                self._stats.record_batch(
+                    [t_done - op.t_arrival for op in batch],
+                    [t_done - op.t_submit for op in batch],
+                    [op.future.queue_wait_s for op in batch],
+                    n_real, t_admit, t_done)
+                version = getattr(self._retriever, "version", None)
+                for i, op in enumerate(batch):
+                    # which corpus snapshot answered (facade.version,
+                    # bumped per add)
+                    op.future.snapshot_version = version
+                    op.future.set_result((scores[i], ids[i]))
 
     def _apply_mutation(self, op: _Mutation) -> None:
         self._progress_t = time.perf_counter()

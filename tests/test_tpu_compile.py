@@ -7,12 +7,16 @@ kernel ahead of time for a described (not attached) v5e chip — nothing
 runs — at d=128, d'=2048, k'=1024, Tq=32, 80 tokens/doc (5 pages of 16),
 IVF lists of cap 2048, a server batch of 8 and an offline batch of 64;
 the reranks also at a k' as long as the corpus, and the sharded serve step
-over a 2x2 mesh.
+over a 2x2 mesh.  The whole search program is compiled at every batch size
+of the server's ladder too, and each of its device ops is held to the
+stage scope (``first_stage/``, ``rerank/``) that a trace attributes it by.
 
 The topology is described inside a module fixture, never at import, and
 the persistent compilation cache is off while these compiles run (a
 compile for a described chip cannot be read back without one).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -174,3 +178,111 @@ def test_one_launch_raises_on_tpu(monkeypatch, path):
             ops.fused_query(jnp.zeros((1, 2, 4)), jnp.ones((1, 2), bool),
                             psi, jnp.zeros((2, 4)), jnp.zeros((2, 8), I32),
                             jnp.zeros((2, 8, 4)), nprobe=1, kp=2)
+
+
+# --------------------------------------------------------------------------
+# the search program's stage scopes
+# --------------------------------------------------------------------------
+
+COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) ")
+INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = .*?\s([a-z][\w-]*)\(")
+STAGE = re.compile(r'op_name="[^"]*?(?:^|/)(first_stage|rerank)/')
+
+
+@pytest.fixture(scope="module")
+def search_hlo(v5e):
+    """{batch size: the search program's HLO text}, compiled for one v5e
+    chip at every batch size of the server's ladder: the paper's widths
+    over a corpus small enough to build here."""
+    from repro.configs.lemur_paper import CONFIG
+    from repro.core.index import LemurIndex
+    from repro.data import synthetic
+    from repro.retriever import LemurRetriever
+    from repro.retriever.facade import search_pipeline
+    from repro.serving import BucketLadder
+
+    corpus = synthetic.make_corpus(m=1024, d=D, avg_tokens=67,
+                                   max_tokens=PMAX * PAGE, seed=0)
+    r = LemurRetriever.build(
+        corpus, CONFIG.replace(m_pretrain=64, n_train=512, n_ols=256,
+                               epochs=1), key=jax.random.PRNGKey(0))
+    resolved, idx = r.resolve(None), r.index
+    one = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+    state = jax.tree.map(sds, (idx.psi, idx.stats, idx.store, idx.ann))
+
+    def pipeline(psi, stats, store, ann, q, qm):
+        return search_pipeline(
+            LemurIndex(r.cfg, psi, stats, store, r.backend, ann), q, qm,
+            resolved)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(ops, "_on_tpu", lambda: True)    # the kernels, not the CPU path
+    try:
+        return {b: jax.jit(pipeline).lower(
+                    *state, sds(jax.ShapeDtypeStruct((b, TQ, D), F32)),
+                    sds(jax.ShapeDtypeStruct((b, TQ), BOOL))
+                ).compile().as_text()
+                for b in BucketLadder().batch_sizes()}
+    finally:
+        mp.undo()
+
+
+def _executed(text):
+    """[(computation, name, opcode, line)] of every instruction that runs
+    as a device op: those of fused computations run inside their fusion."""
+    fused = set(re.findall(r"\bcalls=%([\w.-]+)", text))
+    out, comp = [], None
+    for line in text.splitlines():
+        m = COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = INSTRUCTION.match(line)
+        if m and comp not in fused:
+            out.append((comp, m.group(1), m.group(2), line))
+    return out
+
+
+def _stage(line):
+    m = STAGE.search(line)
+    return m.group(1) if m else None
+
+
+def test_search_program_ops_sit_under_a_stage_scope(search_hlo):
+    """Every kernel, while and sort of the search program belongs to
+    ``first_stage/`` or ``rerank/``; a fusion or copy either does too or
+    carries no op_name at all (the compiler made it: layout copies, index
+    arithmetic it split off), so no op of the program's own falls outside
+    both stages."""
+    for b, text in search_hlo.items():
+        for _, name, opc, line in _executed(text):
+            if opc not in ("custom-call", "while", "sort", "fusion"):
+                continue
+            kernel = 'custom_call_target="tpu_custom_call"' in line
+            if kernel or opc in ("while", "sort"):
+                assert _stage(line), (b, name, line[:300])
+            else:
+                assert _stage(line) or "op_name=" not in line, (b, line[:300])
+
+
+def test_kernels_keep_their_names_and_scopes(search_hlo):
+    """The IVF scan sits under ``first_stage/``; the rerank kernel, and the
+    ``while`` that maps it over row groups where the batch's page strips
+    exceed SMEM, under ``rerank/``; each kernel's instruction is named
+    after it at every batch size (not after the ``lax.map`` body)."""
+    for b, text in search_hlo.items():
+        ops_ = _executed(text)
+        kernels = {name.rsplit(".", 1)[0]: (comp, line)
+                   for comp, name, _, line in ops_
+                   if 'custom_call_target="tpu_custom_call"' in line}
+        assert set(kernels) == {"ivf_probe_scan", "rerank_paged_scores"}, \
+            (b, sorted(kernels))
+        assert _stage(kernels["ivf_probe_scan"][1]) == "first_stage", b
+        comp, line = kernels["rerank_paged_scores"]
+        assert _stage(line) == "rerank", b
+        loops = [l for _, _, opc, l in ops_
+                 if opc == "while" and f"body=%{comp}," in l]
+        assert all(_stage(l) == "rerank" for l in loops), b
+        if b == max(search_hlo):
+            assert loops, "the full batch's rerank runs in a lax.map"
