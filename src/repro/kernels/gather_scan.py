@@ -49,18 +49,26 @@ tensor of the legacy path never exists.
 ``rerank_paged_scores`` — the paged-corpus twin of the rerank: the corpus
 lives as fixed-size token PAGES behind a per-doc page table
 (``core.pages.PagedStore``), so a candidate's tokens are not one contiguous
-``(Td, d)`` slab.  Grid ``(B, k', pmax)``: the per-candidate page ids are
-scalar-prefetched to SMEM (exactly the paged-KV page-table-in-SMEM idiom),
-step ``(b, c, j)`` DMAs page ``table[cand[b, c], j]``'s ``(page, d)`` tile,
-scores it against the query slab, masks token positions ``>= n_tokens`` to
-``NEG``, and folds a per-query-token running max carried in VMEM scratch
-across the ``pmax`` minor steps (the TPU grid iterates the last dimension
-innermost, so the scratch persists per candidate); the final step applies
-the query mask and writes the MaxSim score into the output strip.  The
-page-id strips are flat per query and split, with their queries, into
-groups that fit SMEM.  Because per-token dots
-are unchanged and max is order-independent, scores are bit-identical to the
-dense-slab kernel's on the same docs.
+``(Td, d)`` slab.  The per-candidate page ids and token counts are
+scalar-prefetched to SMEM (the paged-KV page-table-in-SMEM idiom).  Grid
+``(B, ⌈k'/G⌉)``: step ``(b, i)`` scores a block of ``G`` candidates
+(:func:`rerank_paged_plan`: 16 at the served widths), since a grid step
+has a fixed cost (≈ 0.3 µs on a v5e) that one 8 KiB page does not cover.
+The page pool stays in HBM (``memory_space=pl.ANY``); each step starts the
+page DMAs of the NEXT block into the other half of a VMEM double buffer,
+waits for its own block's pages, and scores them in one
+``(G·pmax·page, d) × (d, Tq)`` matmul.  Per candidate, a row slice of
+that product masks token positions ``>= n_tokens`` to ``NEG`` (every
+candidate fetches all ``pmax`` pages, the page table's pads as page 0),
+takes the per-query-token max and the query-masked sum; the ``G`` scores
+land in the ``(1, k')`` output strip with one store.  The page-id strips
+are flat per query and split, with their queries, into groups that fit
+SMEM.
+
+VMEM per step (G 16, pmax 5, page 16, d 128, Tq 32): the page double
+buffer 2 × 640 KiB, the query slab 16 KiB ×2, the (1,280 × 32) score tile
+and the output strip; per-token dots are exact fp32 (``HIGHEST``) as in
+the other reranks.
 
 Every ``pallas_call`` is named after the function that issues it
 (``name="ivf_probe_scan"``, ``"rerank_paged_scores"``, …), so a compiled
@@ -70,6 +78,7 @@ runs inside a ``lax.map`` over row groups.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -315,31 +324,98 @@ def rerank_gather_scores(q, q_mask, cand_ids, doc_tokens, doc_mask,
 # paged-corpus MaxSim rerank (page table fed through SMEM)
 # --------------------------------------------------------------------------
 
-def _rerank_paged_fp_kernel(pt_ref, nt_ref, q_ref, qm_ref, page_ref, out_ref,
-                            acc_ref, *, pmax):
-    # q: (Tq, d); page: (page, d) — ONE token page, DMA'd by the index_map
-    # from the prefetched flat page-id strip; acc: (Tq, 1) VMEM running
-    # per-query-token max, carried across the pmax minor grid steps
-    b, c, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+RERANK_VMEM_BYTES = 2 << 20   # the fp32 paged rerank's page double buffer
+MAX_CANDS_PER_STEP = 16
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.full(acc_ref.shape, NEG, jnp.float32)
 
-    Tq = q_ref.shape[0]
-    page = page_ref.shape[0]
+class RerankPagedPlan(NamedTuple):
+    """The blocking :func:`rerank_paged_scores` runs with."""
+    cands_per_step: int   # candidates whose pages one grid step scores
+    grid_steps: int       # summed over every launch of one call
+    page_dmas: int        # pmax per candidate slot of every step
+    vmem_bytes: int       # the double-buffered page scratch
+
+
+def rerank_paged_plan(B: int, kp: int, pmax: int, page: int,
+                      d: int) -> RerankPagedPlan:
+    """Blocking of the fp32 paged rerank for a (B, k') candidate batch of
+    docs of at most ``pmax`` pages of ``(page, d)`` fp32 tokens: the most
+    candidates per grid step, up to :data:`MAX_CANDS_PER_STEP`, whose
+    pages fit :data:`RERANK_VMEM_BYTES` twice (the DMA of the next block
+    lands while this one is scored).  k' is first cut into SMEM-sized
+    chunks (:func:`_cand_chunk`), each a row of its own; a chunk that is
+    not a multiple of the block leaves a short last block."""
+    kc = _cand_chunk(kp, pmax + 1)
+    cand_bytes = 2 * pmax * page * d * 4
+    g = max(1, min(MAX_CANDS_PER_STEP, RERANK_VMEM_BYTES // cand_bytes))
+    steps = B * (kp // kc) * pl.cdiv(kc, g)
+    return RerankPagedPlan(cands_per_step=g, grid_steps=steps,
+                           page_dmas=steps * g * pmax,
+                           vmem_bytes=g * cand_bytes)
+
+
+def _rerank_paged_fp_kernel(pt_ref, nt_ref, q_ref, qm_ref, pages_hbm, out_ref,
+                            buf, sem, *, kc, g, pmax):
+    # step (b, i) scores candidates i·g .. i·g+g-1 of row b.  Their pages
+    # arrive by manual DMA from the HBM pool (pages_hbm: (P, page, d)) into
+    # slot (step % 2) of buf: (2, g·pmax·page, d), candidate-major, while
+    # the step before is scored: a step starts the DMAs of the next block
+    # (the grid runs in order), then waits for its own.
+    # q: (Tq, d); qm: (1, Tq); out: the (1, kc) strip of row b
+    b, i = pl.program_id(0), pl.program_id(1)
+    nblk = pl.num_programs(1)
+    step = b * nblk + i
+    slot = step % 2
+    page = pages_hbm.shape[1]
+    span = pmax * page
+
+    def fetch(row, blk, slot):
+        """Start the DMAs of every page of block ``blk`` of ``row``: all
+        pmax of each candidate, the page table's pads as page 0 (masked
+        below), a short last block's extra slots as its last candidate.
+        No DMA depends on a token count: on a v5e a branch per page cost
+        more than the bytes it saved."""
+        for k in range(g):
+            base = (row * kc + jnp.minimum(blk * g + k, kc - 1)) * pmax
+            for j in range(pmax):
+                pltpu.make_async_copy(
+                    pages_hbm.at[pt_ref[base + j]],
+                    buf.at[slot, pl.ds((k * pmax + j) * page, page)],
+                    sem.at[slot]).start()
+
+    def fetch_step(t, carry):
+        fetch(t // nblk, t % nblk, t % 2)
+        return carry
+
+    # the first step fetches its own block too; the last fetches nothing
+    jax.lax.fori_loop(jnp.where(step == 0, 0, step + 1),
+                      jnp.minimum(step + 2, pl.num_programs(0) * nblk),
+                      fetch_step, 0)
+
+    # a DMA semaphore counts bytes: one wait for the size of the whole slot
+    # waits for every page DMA'd into it
+    pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+
+    # every token of the block against every query token: one matmul with
+    # tokens on the sublanes, so each candidate is a row slice of span
     s = jax.lax.dot_general(
-        q_ref[...], page_ref[...], (((1,), (1,)), ((), ())),
+        buf[slot], q_ref[...], (((1,), (1,)), ((), ())),
         precision=HIGHEST, preferred_element_type=jnp.float32,
-    )  # (Tq, page)
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (Tq, page), 1)
-    s = jnp.where(pos < nt_ref[b * pl.num_programs(1) + c], s, NEG)
-    acc_ref[...] = jnp.maximum(acc_ref[...],
-                               jnp.max(s, axis=-1, keepdims=True))
-
-    @pl.when(j == pmax - 1)
-    def _flush():
-        _put_lane(out_ref, c, _maxsim_flush(acc_ref[...], qm_ref))
+    )  # (g·span, Tq)
+    Tq = q_ref.shape[0]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (span, Tq), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    strip = out_ref[...]
+    for k in range(g):
+        c = i * g + k
+        # 0 tokens past the strip: a short last block's extra slots are dead
+        n = jnp.where(c < kc, nt_ref[b * kc + jnp.minimum(c, kc - 1)], 0)
+        sc = jnp.where(pos < n, s[k * span:(k + 1) * span], NEG)
+        best = jnp.max(sc, axis=0, keepdims=True)             # (1, Tq)
+        score = jnp.sum(jnp.where(qm_ref[...] > 0, best, 0.0), axis=1,
+                        keepdims=True)
+        strip = jnp.where(lane == c, score, strip)
+    out_ref[...] = strip
 
 
 def _paged_prefetch(cand_ids, page_table, n_tokens, pmax):
@@ -364,44 +440,50 @@ def rerank_paged_scores(q, q_mask, cand_ids, tok_pages, page_table, n_tokens,
     """Exact MaxSim of each query against ITS OWN candidates, streaming each
     candidate's token PAGES at the source.
 
-    q: (B, Tq, d); cand_ids: (B, k') int32 (-1 padded — pads/dead slots are
-    clamped for the DMA, score all-NEG here, and must be masked by the
+    q: (B, Tq, d); cand_ids: (B, k') int32 (-1 padded — pads/dead slots
+    are clamped for the DMA, score all-NEG here, and must be masked by the
     caller); tok_pages: (P, page, d) fp32; page_table: (C, pmax) int32 (-1
     padded); n_tokens: (C,) int32 — returns (B, k') fp32 raw pair scores.
-    The per-candidate page-id strip (B·k'·pmax int32, tiny next to the token
-    pages) is gathered in XLA and scalar-prefetched to SMEM.
+    The per-candidate page-id strip (B·k'·pmax int32, tiny next to the
+    token pages) is gathered in XLA and scalar-prefetched to SMEM; each
+    grid step scores a block of candidates (:func:`rerank_paged_plan`).
     """
     B, Tq, d = q.shape
     kp = cand_ids.shape[1]
     _, page, _ = tok_pages.shape
     pmax = page_table.shape[1]
+    g = rerank_paged_plan(B, kp, pmax, page, d).cands_per_step
     kc, nc, pt, nt = _paged_prefetch(cand_ids, page_table, n_tokens, pmax)
-    pg = lambda b, c, j, pt, nt: (pt[(b * kc + c) * pmax + j], 0, 0)
+    row = lambda b, i, pt, nt: (b, 0, 0)
 
     def call(pt, nt, q, qm):
         bb = q.shape[0]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(bb, kc, pmax),
+            grid=(bb, pl.cdiv(kc, g)),
             in_specs=[
-                pl.BlockSpec((None, Tq, d), lambda b, c, j, pt, nt: (b, 0, 0)),
-                pl.BlockSpec((None, Tq, 1), lambda b, c, j, pt, nt: (b, 0, 0)),
-                pl.BlockSpec((None, page, d), pg),
+                pl.BlockSpec((None, Tq, d), row),
+                pl.BlockSpec((None, 1, Tq), row),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, 1, kc),
-                                   lambda b, c, j, pt, nt: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((Tq, 1), jnp.float32)],
+            out_specs=pl.BlockSpec((None, 1, kc), row),
+            scratch_shapes=[pltpu.VMEM((2, g * pmax * page, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))],
         )
         return pl.pallas_call(
-            functools.partial(_rerank_paged_fp_kernel, pmax=pmax),
+            functools.partial(_rerank_paged_fp_kernel, kc=kc, g=g, pmax=pmax),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bb, 1, kc), jnp.float32),
+            # a step prefetches the next step's pages: the grid runs in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name="rerank_paged_scores",
         )(pt, nt, q, qm, tok_pages)
 
     q, q_mask = _fold_rows(nc, q, q_mask)
-    out = _over_row_chunks(call, (pt, nt), (q, _query_rows(q_mask)))
+    out = _over_row_chunks(call, (pt, nt),
+                           (q, q_mask.astype(jnp.int32)[:, None, :]))
     return out.reshape(B, kp)
 
 
@@ -566,8 +648,9 @@ def ivf_probe_res_scan(q, probe, ids, codes, centroids, values, *,
 def _rerank_paged_res_kernel(pt_ref, nt_ref, q_ref, qm_ref, cent_ref,
                              code_ref, cb_ref, val_ref, out_ref, acc_ref, *,
                              kc, pmax, bits):
-    # the paged fp rerank with the page DMA swapped for cent ids + packed
-    # codes (page, db) uint8 and an in-VMEM decode.  The id block holds the
+    # one token page per grid step (grid (B, k', pmax)), its cent ids +
+    # packed codes (page, db) uint8 decoded in VMEM and folded into a
+    # running per-query-token max carried in acc.  The id block holds the
     # aligned group of cent-page rows around the wanted page (a single
     # (1, page) row is not a legal TPU block); the row is picked here.  The
     # codec tables (cb: (ncent, d), val: (L, d)) ride along as full blocks
@@ -609,7 +692,7 @@ def rerank_paged_res_scores(q, q_mask, cand_ids, cent_pages, code_pages,
     page_table: (C, pmax) int32 (-1 padded); n_tokens: (C,) int32;
     centroids: (ncent, d) / values: (d, L) the codec tables -> (B, k') fp32
     raw pair scores, bit-identical to decoding the pages host-side and
-    running :func:`rerank_paged_scores`.
+    running the fp32 paged oracle (``ref.rerank_scores_paged_ref``).
     """
     B, Tq, d = q.shape
     kp = cand_ids.shape[1]

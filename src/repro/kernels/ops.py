@@ -188,10 +188,11 @@ def fused_rerank_paged(q, q_mask, cand_ids, tok_pages, page_table, n_tokens,
     token pages + per-doc page table + token counts) instead of dense
     ``(m, Td, d)`` slabs; candidates' page ids are fed to the kernel through
     SMEM scalar prefetch.  Same ``-1``-pad contract as :func:`fused_rerank`,
-    and — because per-token dots are unchanged and the token max is
-    order-independent — bit-identical scores to the dense paths on the same
-    docs.  TPU: the scalar-prefetch Pallas kernel
-    (:func:`repro.kernels.gather_scan.rerank_paged_scores`); otherwise the
+    and the same fp32 per-token dots as the dense paths on the same docs
+    (the token max is order-independent).  TPU: the scalar-prefetch Pallas kernel
+    (:func:`repro.kernels.gather_scan.rerank_paged_scores`), each grid step
+    scoring a block of candidates whose pages it DMAs into a VMEM double
+    buffer while the block before is scored; otherwise the
     gather-from-pages oracle.  fp32 only (the SQ8 token tier stays on the
     dense sharded path).
     """

@@ -233,6 +233,81 @@ def test_rerank_paged_res_kernel_interpret_vs_ref(B, C, Tq, d, kp, bits):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
+def _paged_case(rng, B, C, Tq, d, kp, page, pmax):
+    """A paged corpus behind a permuted (non-contiguous) page table, with
+    dead docs (0 tokens), docs of exactly pmax pages and ``-1`` candidates
+    in the first row."""
+    P = C * pmax
+    pages = jnp.asarray(rng.standard_normal((P, page, d)), jnp.float32)
+    table = jnp.asarray(rng.permutation(P).reshape(C, pmax), jnp.int32)
+    n_tokens = rng.integers(1, pmax * page + 1, (C,))
+    n_tokens[:2], n_tokens[2:4] = 0, pmax * page
+    q = jnp.asarray(rng.standard_normal((B, Tq, d)), jnp.float32)
+    qm = jnp.asarray(rng.random((B, Tq)) > 0.3).at[:, 0].set(True)
+    cand = rng.integers(-1, C, (B, kp))
+    cand[0, :4] = [-1, 0, 2, 3]        # a pad, a dead doc, two full docs
+    return (q, qm, jnp.asarray(cand, jnp.int32), pages, table,
+            jnp.asarray(n_tokens, jnp.int32))
+
+
+@pytest.mark.parametrize("B,kp", [
+    (2, 20),      # k' not a multiple of the block: a short last block
+    (3, 5),       # k' below the block
+    (1, 16),      # batch 1, k' one whole block
+    (16, 24),     # batch 16 of the server's ladder
+], ids=["kc-not-multiple-of-block", "kprime-below-block", "batch-1",
+        "batch-16"])
+def test_rerank_paged_kernel_interpret_vs_ref(B, kp):
+    """The blocked fp32 paged rerank (a block of candidates per grid step,
+    pages DMA'd by hand into a double buffer) matches the gather-from-pages
+    oracle on every slot, pads and dead docs included."""
+    rng = np.random.default_rng(B * 100 + kp)
+    C, Tq, d, page, pmax = 20, 5, 16, 8, 3
+    assert gather_scan.rerank_paged_plan(
+        B, kp, pmax, page, d).cands_per_step == gather_scan.MAX_CANDS_PER_STEP
+    args = _paged_case(rng, B, C, Tq, d, kp, page, pmax)
+    out = gather_scan.rerank_paged_scores(*args, interpret=True)
+    want = ref.rerank_scores_paged_ref(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_rerank_paged_block_size_leaves_scores_unchanged(monkeypatch):
+    """A VMEM budget that fits 3 candidates' pages twice in place of 16
+    changes the blocking (:func:`rerank_paged_plan`, which the kernel
+    reads) and not one score."""
+    rng = np.random.default_rng(21)
+    B, C, Tq, d, kp, page, pmax = 2, 20, 5, 16, 20, 8, 3
+    args = _paged_case(rng, B, C, Tq, d, kp, page, pmax)
+    jax.clear_caches()
+    wide = np.asarray(gather_scan.rerank_paged_scores(*args, interpret=True))
+    monkeypatch.setattr(gather_scan, "RERANK_VMEM_BYTES",
+                        3 * 2 * pmax * page * d * 4)
+    plan = gather_scan.rerank_paged_plan(B, kp, pmax, page, d)
+    assert plan.cands_per_step == 3 and plan.grid_steps == B * 7
+    jax.clear_caches()
+    narrow = np.asarray(gather_scan.rerank_paged_scores(*args, interpret=True))
+    jax.clear_caches()
+    np.testing.assert_array_equal(narrow, wide)
+
+
+@pytest.mark.parametrize("B", [1, 2, 4, 8, 16])
+def test_rerank_paged_plan_at_the_cell_widths(B):
+    """At the served widths (k' 1024, docs of 5 pages of 16 tokens, d 128)
+    every batch of the server's ladder takes 16 candidates per grid step,
+    64 steps per query, with a page double buffer inside the VMEM
+    budget."""
+    plan = gather_scan.rerank_paged_plan(B, 1024, 5, 16, 128)
+    assert plan.cands_per_step == 16
+    assert plan.grid_steps == B * 64 <= 2048
+    assert plan.page_dmas == B * 1024 * 5
+    assert plan.vmem_bytes == 2 * 16 * 5 * 16 * 128 * 4 \
+        <= gather_scan.RERANK_VMEM_BYTES
+    # a k' as long as the corpus is cut into SMEM-sized chunks first
+    long = gather_scan.rerank_paged_plan(B, 1 << 17, 5, 16, 128)
+    assert long.cands_per_step == 16 and long.grid_steps == B * (1 << 17) // 16
+
+
 @pytest.mark.parametrize("kernel", ["gather", "paged", "paged_res"])
 def test_rerank_kernels_split_long_kprime_for_smem(monkeypatch, kernel):
     """A k' whose scalar-prefetched strips overflow SMEM is folded into
