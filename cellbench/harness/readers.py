@@ -1,8 +1,9 @@
 """What the metric readers share: the window's answered requests, their
-work from the program's own probe lists and candidates (``--trace 1``),
-and kernel time from the trace.  Each reader in ``metrics/`` is a
-``read(ctx)`` that returns a number, or None when its cell has nothing
-for it to read (the harness then leaves the metric out)."""
+work from the program's own candidates and, where it has IVF lists, probe
+lists (``--trace 1``), and kernel time from the trace.  Each reader in
+``metrics/`` is a ``read(ctx)`` that returns a number, or None when its
+cell has nothing for it to read (the harness then leaves the metric
+out)."""
 from __future__ import annotations
 
 import numpy as np
@@ -27,7 +28,7 @@ def lemur(ctx) -> dict:
 
 
 def ivf_work(ctx) -> work.Work | None:
-    if ctx.observed is None:
+    if ctx.observed is None or ctx.observed["probes"] is None:
         return None
     reqs = in_window(ctx)
     probes = ctx.observed["probes"][rows(ctx, reqs)]

@@ -9,7 +9,7 @@ as the program does, float64 by nothing that shows).  It imports nothing
 of the program and takes nothing the program made: only the corpus and
 queries the benchmark generated.  It runs after the window, once the
 program's device state is freed, a slab of docs at a time so it fits
-beside nothing else.
+beside nothing else, the slabs spread over the cell's chips.
 """
 from __future__ import annotations
 
@@ -45,23 +45,34 @@ def _slab_scores(q, docs, mask, *, precision, chunk):
 
 
 def exact_scores(queries: np.ndarray, doc_tokens: np.ndarray,
-                 doc_mask: np.ndarray, *, precision=HIGHEST) -> np.ndarray:
+                 doc_mask: np.ndarray, *, precision=HIGHEST,
+                 devices=(None,)) -> np.ndarray:
     """(N, Tq, d) queries against every doc -> (N, m) fp32 scores.  Docs
-    with no real token score 0 (never the case in this corpus)."""
+    with no real token score 0 (never the case in this corpus).  Slab i of
+    ``SLAB_DOCS`` docs is scored on ``devices[i % len(devices)]`` (the
+    cell's chips; None is the default device): one slab per chip is in
+    flight at a time, and a slab's scores do not depend on its chip."""
     m = doc_tokens.shape[0]
     slab = min(SLAB_DOCS, m)
     chunk = min(CHUNK_DOCS, slab)
-    q = jnp.asarray(queries, jnp.float32)
+    q = [jax.device_put(jnp.asarray(queries, jnp.float32), dev)
+         for dev in devices]
     out = np.empty((queries.shape[0], m), np.float32)
-    for lo in range(0, m, slab):
-        hi = min(lo + slab, m)
-        docs = np.zeros((slab,) + doc_tokens.shape[1:], np.float32)
-        mask = np.zeros((slab, doc_mask.shape[1]), bool)
-        docs[:hi - lo], mask[:hi - lo] = doc_tokens[lo:hi], doc_mask[lo:hi]
-        mask[hi - lo:, 0] = True      # pad docs: any finite score, dropped
-        s = _slab_scores(q, jnp.asarray(docs), jnp.asarray(mask),
-                         precision=precision, chunk=chunk)
-        out[:, lo:hi] = np.asarray(s)[:, :hi - lo]
+    for first in range(0, m, slab * len(devices)):
+        running = []
+        for i, lo in enumerate(range(first, min(first + slab * len(devices),
+                                                m), slab)):
+            hi = min(lo + slab, m)
+            docs = np.zeros((slab,) + doc_tokens.shape[1:], np.float32)
+            mask = np.zeros((slab, doc_mask.shape[1]), bool)
+            docs[:hi - lo], mask[:hi - lo] = doc_tokens[lo:hi], doc_mask[lo:hi]
+            mask[hi - lo:, 0] = True      # pad docs: any finite score, dropped
+            s = _slab_scores(q[i], jax.device_put(docs, devices[i]),
+                             jax.device_put(mask, devices[i]),
+                             precision=precision, chunk=chunk)
+            running.append((lo, hi, s))
+        for lo, hi, s in running:
+            out[:, lo:hi] = np.asarray(s)[:, :hi - lo]
     return out
 
 

@@ -23,6 +23,7 @@ small trace recorded on the chip (``tests/fixtures``).
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import pathlib
 import re
 
@@ -104,6 +105,11 @@ def device_ops(events) -> dict:
     return out
 
 
+def chip_number(plane: str) -> int:
+    """The chip's number in a device plane's name, ``/device:TPU:<n>``."""
+    return int(DEVICE_PLANE.match(plane).group(1))
+
+
 def spans(events, name: str | None = None) -> list:
     return [e for e in events if not DEVICE_PLANE.match(e.plane)
             and (name is None or e.name == name)]
@@ -145,20 +151,29 @@ def busy_ns(ops, lo: float, hi: float) -> float:
 def idle_gaps(ops, host_spans, lo: float, hi: float) -> list:
     """[(label, ns), ...] of every stretch in [lo, hi] with no op running,
     longest first.  The label names the host span open at the gap's middle
-    (innermost: the latest to start), or ``host:none``."""
+    (innermost: the latest to start; of two that start together, the
+    first in ``host_spans``), or ``host:none``."""
     busy = _merged(((o.start, o.end) for o in ops), lo, hi)
     edges = [lo] + [x for b in busy for x in b] + [hi]
-    gaps = []
-    for s, e in zip(edges[::2], edges[1::2]):
-        if e <= s:
-            continue
-        mid = 0.5 * (s + e)
-        open_ = [h for h in host_spans if h.name != WINDOW_SPAN
-                 and h.start <= mid <= h.end]
-        label = (max(open_, key=lambda h: h.start).name if open_
-                 else "host:none")
-        gaps.append((label, e - s))
-    return sorted(gaps, key=lambda g: -g[1])
+    gaps = [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
+    # one sweep over the gaps' middles in order: every span begun by a
+    # middle goes on a heap by latest start, and one that has ended by it
+    # leaves the heap's top for good (later middles lie later still)
+    begun = sorted((h.start, i) for i, h in enumerate(host_spans)
+                   if h.name != WINDOW_SPAN)
+    heap: list = []
+    labels = [""] * len(gaps)
+    nxt = 0
+    for j in sorted(range(len(gaps)), key=lambda j: sum(gaps[j])):
+        mid = 0.5 * (gaps[j][0] + gaps[j][1])
+        while nxt < len(begun) and begun[nxt][0] <= mid:
+            heapq.heappush(heap, (-begun[nxt][0], begun[nxt][1]))
+            nxt += 1
+        while heap and host_spans[heap[0][1]].end < mid:
+            heapq.heappop(heap)
+        labels[j] = host_spans[heap[0][1]].name if heap else "host:none"
+    return sorted(((labels[j], e - s) for j, (s, e) in enumerate(gaps)),
+                  key=lambda g: -g[1])
 
 
 def label(o: Event) -> str:

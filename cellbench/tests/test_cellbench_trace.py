@@ -140,3 +140,40 @@ def test_reduction_of_a_recorded_v5e_trace():
         sum(o.dur for o in ivf)
     assert any("%ivf_probe_scan.1" in o.text and o not in ivf for o in ops)
     assert trace.label(rerank[0]).startswith("%closed_call.4 = ")
+
+
+def _innermost_by_scan(ops, host_spans, lo, hi):
+    """``idle_gaps`` as a scan of every host span at every gap: the plain
+    statement of the label, to hold the sweep to."""
+    busy = trace._merged(((o.start, o.end) for o in ops), lo, hi)
+    edges = [lo] + [x for b in busy for x in b] + [hi]
+    gaps = []
+    for s, e in zip(edges[::2], edges[1::2]):
+        if e <= s:
+            continue
+        mid = 0.5 * (s + e)
+        open_ = [h for h in host_spans if h.name != trace.WINDOW_SPAN
+                 and h.start <= mid <= h.end]
+        gaps.append((max(open_, key=lambda h: h.start).name if open_
+                     else "host:none", e - s))
+    return sorted(gaps, key=lambda g: -g[1])
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_idle_gaps_sweep_labels_as_a_scan_of_every_span(seed):
+    import random
+
+    rng = random.Random(seed)
+    ops = [Event(DEV, OPS, f"op{i}", rng.randrange(0, 10_000),
+                 rng.randrange(1, 60)) for i in range(400)]
+    # nested, overlapping and equal-start spans; some end before a gap
+    host = [Event(HOST, "python", trace.WINDOW_SPAN, 0, 10_000)]
+    for i in range(300):
+        start = rng.choice([rng.randrange(0, 10_000),
+                            host[rng.randrange(len(host))].start])
+        host.append(Event(HOST, "python", f"cellbench.s{i % 7}.{i}", start,
+                          rng.randrange(0, 400)))
+    rng.shuffle(host)
+    lo, hi = 100.0, 9_900.0
+    assert trace.idle_gaps(ops, host, lo, hi) == \
+        _innermost_by_scan(ops, host, lo, hi)
