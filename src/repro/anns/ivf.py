@@ -243,6 +243,27 @@ def extend_ivf(index: IVFIndex, new_vectors: jax.Array) -> IVFIndex:
                     rq_values=index.rq_values)
 
 
+def pad_lists(index: IVFIndex, cap: int) -> IVFIndex:
+    """``index`` with its lists padded to ``cap`` slots (``-1`` ids, zero
+    vectors and scales), so indexes of several corpora can share one
+    shape.  Pads score ``-inf`` in every scan, so search answers as
+    before."""
+    extra = cap - index.capacity
+    if extra < 0:
+        raise ValueError(f"cap {cap} < the lists' {index.capacity}")
+    if not extra:
+        return index
+
+    def pad(a, value=0):
+        widths = [(0, 0)] * a.ndim
+        widths[1] = (0, extra)
+        return jnp.pad(a, widths, constant_values=value)
+
+    return index._replace(
+        ids=pad(index.ids, -1), vecs=pad(index.vecs),
+        scales=None if index.scales is None else pad(index.scales))
+
+
 def probe_lists(index: IVFIndex, q: jax.Array, nprobe: int) -> jax.Array:
     """(B, d) queries -> (B, nprobe) ids of the best-scoring centroids.
     Scored at full fp32 precision: on the TPU, XLA's default rounds a

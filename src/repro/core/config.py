@@ -86,6 +86,12 @@ class LemurConfig(ConfigBase):
                                    # and the sharded serve step.  The IVF twin
                                    # lives in cfg.ivf.use_one_launch.
     score_dtype: str = "float32"
+    build_per_shard: bool = False  # a corpus larger than one chip: build()
+                                   # without a mesh stops after ψ and the
+                                   # OLS solver (sharded.TrainedLemur), and
+                                   # its shard(mesh) builds each shard's
+                                   # index on that shard's chip, as
+                                   # build(mesh=) does
 
     def __post_init__(self):
         from repro.anns import registry  # late: keeps config import-light

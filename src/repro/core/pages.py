@@ -171,30 +171,39 @@ def _encode_flat(codec: ResidualCodec, flat: np.ndarray):
     return [np.asarray(cid, np.int32), np.asarray(packed, np.uint8)]
 
 
+def pages_needed(doc_mask, page: int = TOKENS_PER_PAGE) -> tuple[int, int]:
+    """``(pages, pages_per_doc)`` that :func:`from_dense` lays ``doc_mask``'s
+    docs out in: the pages their tokens fill, and the widest doc's page
+    count (at least 1)."""
+    ppd = -(-np.asarray(doc_mask, bool).sum(axis=1) // page)
+    return int(ppd.sum()), max(1, int(ppd.max(initial=1)))
+
+
 def from_dense(W, doc_tokens, doc_mask, *, page: int = TOKENS_PER_PAGE,
-               min_capacity: int = MIN_CAPACITY,
-               codec: ResidualCodec | None = None):
+               min_capacity: int = MIN_CAPACITY, min_pages: int = 1,
+               min_pmax: int = 1, codec: ResidualCodec | None = None):
     """Build a :class:`PagedStore` from the dense padded layout.
 
     With ``codec`` the tokens are residual-encoded into the compressed tier
     (``cent_pages``/``code_pages``; ``tok_pages`` keeps a zero-width fp32
-    pool so every shape property still derives from it).  Returns
-    ``(store, bytes_moved)`` — the one-time O(corpus) build cost.  The free
-    list is derivable (:func:`free_list`), so it is not threaded through
-    immutable index snapshots."""
+    pool so every shape property still derives from it).  ``min_capacity``,
+    ``min_pages`` and ``min_pmax`` floor the slot capacity, the page pool
+    and the page-table width, so stores of several corpora can share one
+    shape.  Returns ``(store, bytes_moved)`` — the one-time O(corpus) build
+    cost.  The free list is derivable (:func:`free_list`), so it is not
+    threaded through immutable index snapshots."""
     W = np.asarray(W)
     m = W.shape[0]
     dt = np.asarray(doc_tokens, np.float32)
     dm = np.asarray(doc_mask, bool)
     d = dt.shape[2]
-    counts = dm.sum(axis=1)
-    pmax = max(1, int((-(-counts // page)).max(initial=1)))
+    pmax = max(min_pmax, pages_needed(dm, page)[1])
     flat = dt[dm]
     flats = [flat] if codec is None else _encode_flat(codec, flat)
     chunks, local, counts = _paginate(dm, page, pmax, flats)
     need = chunks[0].shape[0]
     C = max(min_capacity, next_pow2(m))
-    P = next_pow2(max(1, need))
+    P = max(min_pages, next_pow2(max(1, need)))
     table = np.full((C, pmax), -1, np.int32)
     table[:m] = local                               # local idx == page id here
     ntok = np.zeros((C,), np.int32)

@@ -1,22 +1,30 @@
 """Distributed LEMUR: sharded indexing + sharded serving on the mesh.
 
-Serving (Fig. 1 at pod scale): the latent corpus W, the IVF lists, and the
-doc-token store are sharded over the *flattened* mesh (every chip owns
-m/n_devices docs).  A query batch is replicated across the corpus axis;
-each shard runs (latent scan -> local top-k' -> local exact rerank) entirely
-locally, and only the (k, score) pairs cross the wire in a final all-gather
-merge — per-query traffic is k·n_devices·8 bytes, independent of m.
+Serving (Fig. 1 at pod scale): the corpus is sharded by document over the
+*flattened* mesh (every chip owns m/n_devices docs).  A query batch is
+replicated across the corpus axis; each shard retrieves its local top-k
+entirely locally, and only the (k, score) pairs cross the wire in a final
+all-gather merge (:func:`merge_topk`) — per-query traffic is
+k·n_devices·8 bytes, independent of m.  Two shard layouts:
+
+* :class:`ShardedIndexState` (``LemurRetriever.build(mesh=)``): each shard
+  is a one-chip index — paged store and IVF lists — and runs the one-chip
+  pipeline (``facade.search_pipeline``) on it
+  (:func:`make_shard_search_step`, :func:`make_shard_candidates_step`);
+* :class:`ShardedRetrievalState` (``LemurRetriever.shard``): a dense slot
+  pool, scanned exactly and reranked by the gather kernel
+  (:func:`make_serve_step`).
 
 Indexing (§4.3): the Gram factor is tiny ((d')² fp32) and replicated; each
 shard fits OLS rows for its own documents with zero communication.
 
-The facade entry point is :meth:`repro.retriever.LemurRetriever.shard`;
 ``repro.core.distributed`` re-exports this module for v0 call sites.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -182,14 +190,22 @@ def _local_retrieve(psi_q, W, W_scales, doc_tokens, doc_scales, doc_mask,
         gids = jnp.where(local_ids >= 0, jnp.take(row_ids, safe), -1)
     else:
         gids = local_ids + idx * m_loc
-    # hierarchical merge: reduce back to top-k after every axis gather
-    all_s, all_i = scores, gids
-    for ax in axes:
-        all_s = jax.lax.all_gather(all_s, ax, axis=1, tiled=True)
-        all_i = jax.lax.all_gather(all_i, ax, axis=1, tiled=True)
-        all_s, pos = jax.lax.top_k(all_s, min(k, all_s.shape[1]))
-        all_i = jnp.take_along_axis(all_i, pos, axis=1)
-    return all_s, all_i
+    return merge_topk(scores, gids, k=k, axes=axes)
+
+
+def merge_topk(scores, ids, *, k: int, axes: tuple[str, ...]):
+    """The shards' (B, k_loc) top-k -> the global (B, min(k, ·)) top-k,
+    replicated (inside ``shard_map``).  Hierarchical: an all-gather along
+    one mesh axis at a time, reduced back to top-k after each, so a stage
+    gathers k·|axis| columns instead of k·n_devices at once.  Every op sits
+    under the ``merge/`` scope."""
+    with jax.named_scope("merge"):
+        for ax in axes:
+            scores = jax.lax.all_gather(scores, ax, axis=1, tiled=True)
+            ids = jax.lax.all_gather(ids, ax, axis=1, tiled=True)
+            scores, pos = jax.lax.top_k(scores, min(k, scores.shape[1]))
+            ids = jnp.take_along_axis(ids, pos, axis=1)
+    return scores, ids
 
 
 def default_k_prime_local(cfg_k: int, cfg_k_prime: int, n_shards: int) -> int:
@@ -281,6 +297,112 @@ def make_serve_step(mesh: Mesh, cfg: LemurConfig, *,
           q_tokens, q_mask)
 
     return serve_step
+
+
+class ShardedIndexState(NamedTuple):
+    """Device arrays of a doc-sharded deployment whose shards are one-chip
+    indexes (``LemurRetriever.build(mesh=)``), as one pytree.
+
+    ψ and the target stats are replicated.  Every leaf of ``store`` (a
+    ``PagedStore``) and ``ann`` (an ``IVFIndex``) is the shards' own arrays,
+    of one common shape, joined along axis 0 and sharded over the flattened
+    mesh: inside ``shard_map`` shard s sees exactly the store and IVF lists
+    a one-chip build of its docs holds, numbered from 0.  ``base`` holds
+    each shard's first global doc id."""
+    psi: dict
+    stats: Any
+    store: Any
+    ann: Any
+    base: jax.Array                 # (n_shards,) int32
+
+
+def shard_devices(mesh: Mesh) -> list:
+    """The devices of ``mesh`` in shard order: block s (along axis 0) of a
+    leaf sharded over the flattened mesh lives on the s-th."""
+    return list(mesh.devices.flat)
+
+
+def join_shards(mesh: Mesh, blocks: list):
+    """One corpus-sharded pytree from per-shard pytrees of one structure and
+    shape, shard s's leaves committed to ``shard_devices(mesh)[s]``: each
+    global leaf is their blocks joined along axis 0, without a copy."""
+    sharding = NamedSharding(mesh, P(corpus_axes(mesh)))
+
+    def join(*xs):
+        shape = (len(xs) * xs[0].shape[0],) + tuple(xs[0].shape[1:])
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        list(xs))
+
+    return jax.tree.map(join, *blocks)
+
+
+def _over_shards(mesh: Mesh, body, out_specs):
+    """``step(state, q_tokens, q_mask)``: ``body(local state, q, qm)`` on
+    every shard of a :class:`ShardedIndexState`, the queries replicated."""
+    rows = P(corpus_axes(mesh))
+    specs = ShardedIndexState(psi=P(), stats=P(), store=rows, ann=rows,
+                              base=rows)
+
+    def step(state, q_tokens, q_mask):
+        return jax.shard_map(body, mesh=mesh, in_specs=(specs, P(), P()),
+                             out_specs=out_specs, check_vma=False)(
+            state, q_tokens, q_mask)
+
+    return step
+
+
+def _shard_index(cfg: LemurConfig, backend: str, state: ShardedIndexState):
+    from repro.core.index import LemurIndex
+
+    return LemurIndex(cfg, state.psi, state.stats, state.store, backend,
+                      state.ann)
+
+
+def _global_ids(ids, base):
+    return jnp.where(ids >= 0, ids + base[0], -1)
+
+
+def make_shard_search_step(mesh: Mesh, cfg: LemurConfig, backend: str,
+                           params):
+    """``serve_step(state, q_tokens, q_mask) -> (scores, ids)``, (B,
+    params.k) and replicated, over a :class:`ShardedIndexState`.
+
+    Each shard runs the one-chip ``facade.search_pipeline`` on its own
+    index with ``params`` (resolved; its ``k_prime`` is the per-shard
+    budget), for at most k' answers, as many as it has candidates; its
+    local ids become global ids, then :func:`merge_topk` merges the
+    shards' top-k.  Pads stay ``(NEG, -1)``, also where k exceeds every
+    shard's k' together."""
+    from repro.retriever.facade import search_pipeline
+
+    axes = corpus_axes(mesh)
+    local = dataclasses.replace(params, k=min(params.k, params.k_prime))
+
+    def body(state, q, qm):
+        s, ids = search_pipeline(_shard_index(cfg, backend, state), q, qm,
+                                 local)
+        s, ids = merge_topk(s, _global_ids(ids, state.base), k=params.k,
+                            axes=axes)
+        short = params.k - s.shape[1]
+        return (jnp.pad(s, ((0, 0), (0, short)), constant_values=maxsim.NEG),
+                jnp.pad(ids, ((0, 0), (0, short)), constant_values=-1))
+
+    return _over_shards(mesh, body, (P(), P()))
+
+
+def make_shard_candidates_step(mesh: Mesh, cfg: LemurConfig, backend: str,
+                               params):
+    """``step(state, q_tokens, q_mask) -> ids``, (B, n_shards ×
+    params.k_prime): every shard's first-stage candidates
+    (``facade.first_stage``) as global ids, shard s's in the s-th block of
+    columns, pads ``-1``."""
+    from repro.retriever.facade import first_stage
+
+    def body(state, q, qm):
+        cand = first_stage(_shard_index(cfg, backend, state), q, qm, params)
+        return _global_ids(cand, state.base)
+
+    return _over_shards(mesh, body, P(None, corpus_axes(mesh)))
 
 
 def make_index_step(mesh: Mesh, cfg: LemurConfig, *, doc_block: int = 128):

@@ -23,12 +23,18 @@ from repro.retriever.facade import (
     xla_compile_count,
 )
 from repro.retriever.params import SearchParams
-from repro.retriever.sharded import ShardedLemurRetriever
+from repro.retriever.sharded import (
+    MeshLemurRetriever,
+    ShardedLemurRetriever,
+    TrainedLemur,
+)
 
 __all__ = [
     "CorruptIndexError",
     "LemurRetriever",
+    "MeshLemurRetriever",
     "ShardedLemurRetriever",
+    "TrainedLemur",
     "SearchParams",
     "IVFSearchParams",
     "NoSearchParams",

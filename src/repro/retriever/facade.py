@@ -9,6 +9,7 @@ One object owns the full lifecycle of a LEMUR index (Fig. 1):
     r.update([3, 7], new_tokens, new_mask)       # delete+add, ONE version
     r2 = r.with_backend("muvera")                # same reduction, new stage
     sr = r.shard(mesh)                           # multi-device serving
+    mr = LemurRetriever.build(corpus, cfg, mesh=mesh)  # corpus > one chip
     r.save("my_index/"); r = LemurRetriever.load("my_index/")
 
 Design points:
@@ -327,14 +328,30 @@ class LemurRetriever:
 
     @classmethod
     def build(cls, corpus, cfg: LemurConfig | None = None, *, key=None,
-              x_train: np.ndarray | None = None,
-              verbose: bool = False) -> "LemurRetriever":
+              mesh=None, x_train: np.ndarray | None = None,
+              verbose: bool = False):
         """Full offline build: training-token selection (§4.2) -> ψ
         pre-training against m' sampled docs (§4.3) -> OLS output layer over
         the full corpus (eq. 7) -> first-stage index via the backend
         registry.  ``corpus`` is any object with doc_tokens/doc_mask arrays
-        (e.g. ``data.synthetic.MultiVectorCorpus``)."""
+        (e.g. ``data.synthetic.MultiVectorCorpus``).
+
+        ``mesh`` builds a corpus larger than one chip: the docs are split
+        into one contiguous shard per device of the mesh, ψ and the OLS
+        solver are trained once as above, and each shard's W rows, IVF
+        lists and paged store are built on its own chip.  Returns a
+        :class:`~repro.retriever.sharded.MeshLemurRetriever`, which serves
+        each query from every shard with the one-chip pipeline and merges
+        their top-k.  Without a mesh, ``cfg.build_per_shard`` stops after
+        the OLS solver and returns a :class:`~repro.retriever.sharded.
+        TrainedLemur`, whose ``shard(mesh)`` builds the shards the same
+        way."""
         cfg = cfg or LemurConfig()
+        per_shard = mesh is not None or cfg.build_per_shard
+        if per_shard:
+            from repro.retriever import sharded
+
+            sharded.check_mesh_config(cfg)
         if key is None:
             key = jax.random.PRNGKey(0)
         t0 = time.time()
@@ -370,6 +387,11 @@ class LemurRetriever:
         x_ols = x_train[jax.random.choice(keys[2], x_train.shape[0], (n_ols,),
                                           replace=False)]
         solver = indexer.ols_solver_state(phi["psi"], x_ols, cfg)
+        if per_shard:
+            trained = sharded.TrainedLemur(cfg, keys, phi["psi"], stats,
+                                           solver, doc_tokens, doc_mask,
+                                           verbose=verbose)
+            return trained if mesh is None else trained.shard(mesh)
         W = indexer.fit_output_layer_ols(phi["psi"], x_ols, doc_tokens,
                                          doc_mask, cfg, stats,
                                          solver_state=solver)

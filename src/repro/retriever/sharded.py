@@ -1,6 +1,30 @@
-"""ShardedLemurRetriever: the facade's multi-device serving surface.
+"""Multi-device serving: a corpus sharded by document over a mesh.
 
-Obtained via :meth:`repro.retriever.LemurRetriever.shard`::
+Two deployments, both answering as the one-chip facade does (``search``,
+``resolve``, ``trace_count``, ``trace_shapes``, ``version``), so
+``RetrieverServer`` serves either unchanged.
+
+**A corpus larger than one chip** is built with ``LemurRetriever.build(
+corpus, cfg, mesh=mesh)``, which returns a :class:`MeshLemurRetriever`::
+
+    sr = LemurRetriever.build(corpus, cfg, key=key, mesh=mesh)
+    scores, ids = sr.search(q, qm, SearchParams(k=10))
+    cand = sr.candidates(q, qm)           # (B, shards x k'_local) global ids
+
+ψ and the OLS solver are trained once over the whole corpus, as a
+one-chip build does; then each shard -- a contiguous block of docs -- gets
+its W rows, IVF lists and paged token store built on its own chip, so no
+chip ever holds another shard's lists or pages.  Every query goes to every
+shard; each runs the one-chip pipeline (``facade.search_pipeline``: IVF
+first stage at the per-shard budget k'_local, fp32 paged rerank) on its own
+index, and the shards' top-k meet in ``dist.merge_topk``.  It is
+read-only: ``add``/``delete``/``update`` raise.  With
+``cfg.build_per_shard``, ``LemurRetriever.build(corpus, cfg)`` stops after
+the OLS solver and returns a :class:`TrainedLemur`, whose ``shard(mesh)``
+builds the same shards.
+
+**The slot pool** (``LemurRetriever.shard(mesh)``) re-lays a built
+one-chip retriever out over the mesh: :class:`ShardedLemurRetriever`::
 
     r = LemurRetriever.build(corpus, cfg)
     sr = r.shard(mesh)                       # corpus block-sharded over mesh
@@ -11,13 +35,14 @@ Obtained via :meth:`repro.retriever.LemurRetriever.shard`::
     sr.save("idx/"); sr = ShardedLemurRetriever.load("idx/", mesh)
 
 It mirrors the single-device facade's surface (``search`` / ``add`` /
-``save`` / ``load`` / ``trace_count``) on top of the Fig.-1-at-pod-scale
-serve step in :mod:`repro.dist.serve`: the latent corpus W and the doc
-token store are block-sharded over the *flattened* mesh, each shard runs
-latent scan → local top-k' → local exact rerank, and only (k, score) pairs
-cross the wire in the hierarchical merge.
+``save`` / ``load`` / ``trace_count``) on top of the slot-pool serve step
+in :mod:`repro.dist.serve`: the latent corpus W and the doc token store are
+block-sharded over the *flattened* mesh, each shard runs latent scan →
+local top-k' → local exact rerank, and only (k, score) pairs cross the
+wire in the hierarchical merge.  The whole index passes through one chip
+on its way, so it serves only corpora that one chip can build.
 
-Design points:
+Design points of the slot pool:
 
 * **State build.**  ``ShardedRetrievalState`` is materialized from any
   built retriever as a SLOT POOL: every shard owns a power-of-two bucket of
@@ -52,6 +77,9 @@ Design points:
 """
 from __future__ import annotations
 
+import concurrent.futures
+import dataclasses
+import time
 from typing import Any
 
 import jax
@@ -59,8 +87,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import dist
+from repro.anns import ivf, registry
+from repro.anns.base import CorpusView
 from repro.anns.quantization import sq8_quant
-from repro.core import maxsim, pages
+from repro.core import indexer, maxsim, pages
 from repro.core.config import LemurConfig
 from repro.retriever.facade import LemurRetriever
 from repro.retriever.params import SearchParams
@@ -393,3 +423,281 @@ class ShardedLemurRetriever:
         """``LemurRetriever.load(...)`` then shard onto ``mesh``."""
         base = LemurRetriever.load(directory, step=step)
         return cls(base, mesh, sq8=sq8, k_prime_local=k_prime_local)
+
+
+# --------------------------------------------------------------------------
+# a corpus larger than one chip: one one-chip index per shard
+# --------------------------------------------------------------------------
+
+def check_mesh_config(cfg: LemurConfig) -> None:
+    """Raise unless ``build(mesh=)`` can build ``cfg``: IVF shards over
+    fp32 token pages (the one-chip pipeline each shard runs)."""
+    if registry.canonical(cfg.anns) != "ivf":
+        raise ValueError(f"build(mesh=) builds IVF shards; cfg.anns is "
+                         f"{cfg.anns!r}")
+    if cfg.residual.enabled or int(cfg.residual.token_budget) > 0:
+        raise ValueError("build(mesh=) stores fp32 token pages; the "
+                         "residual tier and token pooling are one-chip only")
+
+
+class TrainedLemur:
+    """What ``LemurRetriever.build`` trains over a whole corpus -- ψ, the
+    target stats, the OLS solver -- with the corpus on the host and no
+    index yet.  ``build`` returns it, without a mesh, for a configuration
+    with ``build_per_shard``: the index of a corpus larger than one chip
+    is built only where its shards go, by :meth:`shard`."""
+
+    def __init__(self, cfg: LemurConfig, keys, psi, stats, solver,
+                 doc_tokens: np.ndarray, doc_mask: np.ndarray, *,
+                 verbose: bool = False):
+        self._cfg = cfg
+        self._parts = (keys, psi, stats, solver, doc_tokens, doc_mask)
+        self._verbose = verbose
+
+    @property
+    def cfg(self) -> LemurConfig:
+        return self._cfg
+
+    @property
+    def m(self) -> int:
+        return self._parts[4].shape[0]
+
+    def __repr__(self) -> str:
+        return f"TrainedLemur(m={self.m}, d_prime={self.cfg.d_prime})"
+
+    def shard(self, mesh, *, k_prime_local: int | None = None
+              ) -> "MeshLemurRetriever":
+        """Each shard's index on its own device of ``mesh``
+        (:func:`build_shards`).  ``k_prime_local`` pins the per-shard
+        candidate budget (default: ``dist.default_k_prime_local`` of each
+        search's k and k')."""
+        return build_shards(mesh, self.cfg, *self._parts,
+                            k_prime_local=k_prime_local,
+                            verbose=self._verbose)
+
+
+def build_shards(mesh, cfg: LemurConfig, keys, psi, stats, solver,
+                 doc_tokens: np.ndarray, doc_mask: np.ndarray, *,
+                 k_prime_local: int | None = None,
+                 verbose: bool = False) -> "MeshLemurRetriever":
+    """The per-shard half of ``LemurRetriever.build(mesh=)``, given ψ, the
+    target stats and the OLS solver trained over the whole corpus.
+
+    Shard s holds the docs ``[s·m/n, (s+1)·m/n)``.  On its own chip it gets
+    its W rows (the build's solver), its IVF (key ``fold_in(keys[3], s)``,
+    nlist by the paper's rule on the largest shard's m, shared by all) and
+    its fp32 paged store, floored to the shapes every shard needs; the
+    shards build concurrently, one host thread each.  The dense corpus
+    stays on the host."""
+    t0 = time.time()
+    mesh = dist.auto_axes(mesh)
+    devices = dist.shard_devices(mesh)
+    n, m = len(devices), doc_tokens.shape[0]
+    bounds = [s * m // n for s in range(n + 1)]
+    sizes = [bounds[s + 1] - bounds[s] for s in range(n)]
+    bcfg = cfg.ivf.replace(nlist=int(cfg.ivf.nlist)
+                           or ivf.default_nlist(max(sizes)))
+    be = registry.get_backend("ivf")
+    need = [pages.pages_needed(doc_mask[bounds[s]:bounds[s + 1]])
+            for s in range(n)]
+    floors = dict(min_capacity=max(pages.MIN_CAPACITY,
+                                   pages.next_pow2(max(sizes))),
+                  min_pages=pages.next_pow2(max(max(p for p, _ in need), 1)),
+                  min_pmax=max(w for _, w in need))
+
+    def on_device(tree, dev):
+        return jax.tree.map(
+            lambda a: jax.device_put(a, dev) if isinstance(a, jax.Array)
+            else a, tree)
+
+    def build_one(s: int):
+        dev, lo, hi = devices[s], bounds[s], bounds[s + 1]
+        toks, mask = doc_tokens[lo:hi], doc_mask[lo:hi]
+        with jax.default_device(dev):
+            sv = on_device(solver, dev)
+            W = indexer.fit_output_layer_ols(
+                on_device(psi, dev), sv["x_ols"], toks, mask, cfg,
+                on_device(stats, dev), solver_state=sv)
+            ann = be.build(jax.random.fold_in(keys[3], s),
+                           CorpusView(W, toks, mask), bcfg)
+            # the store lays W out on the host; drop the device copy first
+            W = np.asarray(W)
+            store, _ = pages.from_dense(W, toks, mask, **floors)
+        return on_device((store, ann), dev)
+
+    with concurrent.futures.ThreadPoolExecutor(n) as pool:
+        blocks = list(pool.map(build_one, range(n)))
+    cap = max(ann.capacity for _, ann in blocks)
+    blocks = [(store, on_device(ivf.pad_lists(ann, cap), dev))
+              for (store, ann), dev in zip(blocks, devices)]
+    store, ann = dist.join_shards(mesh, blocks)
+    base = dist.join_shards(mesh, [
+        jax.device_put(np.asarray([lo], np.int32), dev)
+        for lo, dev in zip(bounds, devices)])
+    replicated = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    state = dist.ShardedIndexState(
+        psi=jax.device_put(psi, replicated),
+        stats=jax.device_put(stats, replicated),
+        store=store, ann=ann, base=base)
+    if verbose:
+        print(f"[build] {n} shards of {min(sizes)}-{max(sizes)} docs "
+              f"(nlist {bcfg.nlist}, cap {cap}) done ({time.time()-t0:.1f}s)")
+    return MeshLemurRetriever(cfg, mesh, state, m,
+                              k_prime_local=k_prime_local)
+
+
+class MeshLemurRetriever:
+    """A doc-sharded deployment whose shards are one-chip indexes (see the
+    module docstring).  Construct via ``LemurRetriever.build(mesh=)``.
+    ``k_prime_local`` pins each shard's candidate budget for every search
+    (default: :meth:`k_prime_local`'s rule)."""
+
+    backend = "ivf"
+
+    def __init__(self, cfg: LemurConfig, mesh, state: dist.ShardedIndexState,
+                 m: int, *, k_prime_local: int | None = None):
+        self._cfg = cfg
+        self._mesh = mesh
+        self._state = state
+        self._m = m
+        self._k_prime_local = k_prime_local
+        self._compiled: dict[tuple, Any] = {}
+        self._candidates: dict[SearchParams, Any] = {}
+        self._trace_counts: dict[tuple, int] = {}
+        self._trace_shapes: dict[tuple, int] = {}
+        self._resolve_memo: dict[SearchParams | None, SearchParams] = {}
+
+    # -- introspection ------------------------------------------------------
+
+    @property
+    def cfg(self) -> LemurConfig:
+        return self._cfg
+
+    @property
+    def mesh(self):
+        return self._mesh
+
+    @property
+    def m(self) -> int:
+        return self._m
+
+    @property
+    def n_shards(self) -> int:
+        return dist.n_corpus_shards(self._mesh)
+
+    @property
+    def rows_per_shard(self) -> int:
+        """Docs of the largest shard."""
+        return -(-self._m // self.n_shards)
+
+    @property
+    def version(self) -> int:
+        """Snapshot version: 0, as nothing mutates this deployment."""
+        return 0
+
+    @property
+    def state(self) -> dist.ShardedIndexState:
+        return self._state
+
+    def __repr__(self) -> str:
+        shape = "x".join(str(self._mesh.shape[a]) for a in self._mesh.axis_names)
+        return (f"MeshLemurRetriever(m={self.m}, mesh={shape}, "
+                f"k_prime_local={self.k_prime_local()})")
+
+    # -- query --------------------------------------------------------------
+
+    def resolve(self, params: SearchParams | None = None) -> SearchParams:
+        """Fill a (possibly partial) SearchParams from the build config
+        (memoized, as the facade's)."""
+        resolved = self._resolve_memo.get(params)
+        if resolved is None:
+            resolved = (params or SearchParams()).resolve(self.cfg,
+                                                          self.backend)
+            self._resolve_memo[params] = resolved
+        return resolved
+
+    def k_prime_local(self, params: SearchParams | None = None) -> int:
+        """Each shard's candidate budget for ``params``: the pinned one, or
+        the global k' spread over the shards with a 4x oversample
+        (``dist.default_k_prime_local``)."""
+        if self._k_prime_local is not None:
+            return self._k_prime_local
+        r = self.resolve(params)
+        return dist.default_k_prime_local(r.k, r.k_prime, self.n_shards)
+
+    def _local(self, resolved: SearchParams) -> SearchParams:
+        return dataclasses.replace(resolved,
+                                   k_prime=self.k_prime_local(resolved))
+
+    def search(self, q_tokens, q_mask=None, params: SearchParams | None = None):
+        """q_tokens: (B, Tq, d) -> (scores (B, k), global doc ids (B, k)).
+
+        One compiled step per (resolved params, batch shape): every shard
+        runs the one-chip pipeline at k'_local, then the merge.  Returns
+        once dispatched; ``lemur.search`` is its host span, as the
+        facade's."""
+        with jax.profiler.TraceAnnotation("lemur.search"):
+            q_tokens = jnp.asarray(q_tokens)
+            if q_mask is None:
+                q_mask = jnp.ones(q_tokens.shape[:2], bool)
+            return self._compiled_fn(self.resolve(params))(
+                self._state, q_tokens, q_mask)
+
+    def candidates(self, q_tokens, q_mask=None,
+                   params: SearchParams | None = None):
+        """Every shard's first-stage candidates, the serve step's own (same
+        params, same k'_local): (B, n_shards × k'_local) global ids, shard
+        s's in the s-th block of columns, pads ``-1``."""
+        q_tokens = jnp.asarray(q_tokens)
+        if q_mask is None:
+            q_mask = jnp.ones(q_tokens.shape[:2], bool)
+        resolved = self.resolve(params)
+        fn = self._candidates.get(resolved)
+        if fn is None:
+            fn = self._candidates[resolved] = jax.jit(
+                dist.make_shard_candidates_step(
+                    self._mesh, self.cfg, self.backend,
+                    self._local(resolved)))
+        return fn(self._state, q_tokens, q_mask)
+
+    def _compiled_fn(self, resolved: SearchParams):
+        key = (self.backend, resolved)
+        fn = self._compiled.get(key)
+        if fn is None:
+            step = dist.make_shard_search_step(
+                self._mesh, self.cfg, self.backend, self._local(resolved))
+            counts, shapes = self._trace_counts, self._trace_shapes
+
+            def run(state, q, qm):
+                counts[key] = counts.get(key, 0) + 1     # trace-time only
+                skey = key + (tuple(q.shape),)
+                shapes[skey] = shapes.get(skey, 0) + 1
+                return step(state, q, qm)
+
+            fn = self._compiled[key] = jax.jit(run)
+        return fn
+
+    def trace_count(self, params: SearchParams | None = None) -> int:
+        """jit traces of ``search`` so far: for one resolved SearchParams,
+        or in total; one per (params, batch shape)."""
+        if params is None:
+            return sum(self._trace_counts.values())
+        return self._trace_counts.get((self.backend, self.resolve(params)),
+                                      0)
+
+    def trace_shapes(self) -> dict[tuple, int]:
+        """``{q.shape: n_traces}`` aggregated over params, as the facade's."""
+        out: dict[tuple, int] = {}
+        for (*_, shape), n in self._trace_shapes.items():
+            out[shape] = out.get(shape, 0) + n
+        return out
+
+    # -- mutation -----------------------------------------------------------
+
+    def _read_only(self, *_, **__):
+        raise NotImplementedError(
+            "a retriever built with build(mesh=) is read-only: its shards "
+            "take no add/delete/update yet (LemurRetriever.shard(mesh) "
+            "serves a mutable corpus that one chip can build)")
+
+    add = delete = update = _read_only

@@ -75,6 +75,28 @@ def test_ivf_all_ids_valid(rng):
         assert len(set(row.tolist())) == len(row)
 
 
+@pytest.mark.parametrize("sq8", [False, True])
+def test_ivf_padded_lists_answer_as_before(rng, sq8):
+    """``pad_lists`` widens every list with -1 slots: the same answers
+    from a wider shape, and a cap below the lists' is refused."""
+    from repro.anns.ivf import pad_lists
+
+    corpus = jnp.asarray(rng.standard_normal((300, 16)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((5, 16)), jnp.float32)
+    idx = build_ivf(jax.random.PRNGKey(0), corpus, nlist=16, sq8=sq8)
+    wide = pad_lists(idx, 2 * idx.capacity)
+    assert wide.capacity == 2 * idx.capacity
+    assert (np.asarray(wide.ids)[:, idx.capacity:] == -1).all()
+    for fused in (False, True):
+        s0, i0 = search_ivf(idx, q, nprobe=4, k=12, use_fused_gather=fused)
+        s1, i1 = search_ivf(wide, q, nprobe=4, k=12, use_fused_gather=fused)
+        np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+        np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+    assert pad_lists(idx, idx.capacity) is idx
+    with pytest.raises(ValueError):
+        pad_lists(idx, idx.capacity // 2)
+
+
 def test_muvera_fde_better_than_random(tiny_corpus):
     """FDE inner products correlate with MaxSim (Jayaram et al. Thm 2.1)."""
     from repro.core import maxsim

@@ -9,8 +9,17 @@ single device under any pytest ordering) and compares a 1-device and an
 
 The corpora deliberately do NOT divide the device count (m=90, 8 devices)
 so the pad-row masking path is always exercised.
+
+``LemurRetriever.build(mesh=)`` (the tests after the slot pool's) is held
+to a plain reference on 4 forced host devices: a loop over the shards that
+builds a one-chip index from the same ψ, W rows and key over each shard's
+docs, runs the one-chip pipeline on it and merges in numpy, and exact
+MaxSim in float64.
 """
+import json
 import textwrap
+
+import pytest
 
 
 # shared preamble: tiny retriever whose k' covers the whole corpus, so the
@@ -391,3 +400,364 @@ def test_sharded_warm_swap_parity_and_barrier(run_forced8):
     print("OK")
     """))
     assert "OK" in out
+
+
+# --------------------------------------------------------------------------
+# LemurRetriever.build(mesh=): one one-chip index per shard
+# --------------------------------------------------------------------------
+
+# one subprocess on 4 forced host devices builds the deployment once and
+# records what every test below checks; m=302 does not divide 4, so the
+# shards hold 75, 76, 75 and 76 docs
+_MESH_SCRIPT = """
+import dataclasses, json, tempfile
+import jax, jax.numpy as jnp, numpy as np
+from repro import dist
+from repro.anns import ivf, registry
+from repro.anns.base import CorpusView
+from repro.core import LemurConfig, maxsim, pages
+from repro.core.index import LemurIndex
+from repro.core.model import TargetStats
+from repro.data import synthetic
+from repro.kernels import ops
+from repro.retriever import (IVFBackendConfig, IVFSearchParams,
+                             LemurRetriever, MeshLemurRetriever, SearchParams)
+from repro.retriever.facade import search_pipeline
+
+out = {}
+M = 302
+corpus = synthetic.make_corpus(m=M, d=16, avg_tokens=8, max_tokens=12,
+                               n_centers=24, seed=0)
+cfg = LemurConfig(d=16, d_prime=32, m_pretrain=64, n_train=512, n_ols=256,
+                  epochs=2, k=5, k_prime=64, anns="ivf",
+                  ivf=IVFBackendConfig(nprobe=8, sq8=True))
+key = jax.random.PRNGKey(0)
+mesh = jax.make_mesh((2, 2), ("data", "model"))
+devs = dist.shard_devices(mesh)
+mr = LemurRetriever.build(corpus, cfg, key=key, mesh=mesh)
+one = LemurRetriever.build(corpus, cfg, key=key)
+q = synthetic.queries_from_corpus_query(corpus, 8, 4, seed=5)
+qm = np.ones(q.shape[:2], bool)
+bounds = [s * M // 4 for s in range(5)]
+nlist = ivf.default_nlist(max(np.diff(bounds)))
+exact64 = np.stack([
+    [(qi.astype(np.float64) @ corpus.doc_tokens[j][corpus.doc_mask[j]]
+      .astype(np.float64).T).max(1).sum() for j in range(M)] for qi in q])
+out["nlist"] = [int(mr.state.ann.centroids.shape[0] // 4), int(nlist)]
+
+# (a) psi bit for bit; W rows within fp32 tolerance
+host = jax.device_get(mr.state)
+out["psi_bit_equal"] = all(
+    np.array_equal(a, b) for a, b in zip(jax.tree.leaves(host.psi),
+                                         jax.tree.leaves(jax.device_get(
+                                             one.index.psi))))
+C = host.store.W.shape[0] // 4
+W_mesh = np.concatenate([host.store.W[s * C:s * C + bounds[s + 1] - bounds[s]]
+                         for s in range(4)])
+W_one = np.asarray(one.index.W)
+out["W_rel_err"] = float(np.abs(W_mesh - W_one).max() / np.abs(W_one).max())
+
+# (b) the plain reference: a one-chip index per shard from the same psi, W
+# rows and key, the one-chip pipeline on each, the merge in numpy
+keys = jax.random.split(key, 4)
+stats = TargetStats(*host.stats)
+
+def plain_reference(params):
+    local = dataclasses.replace(mr.resolve(params),
+                                k_prime=mr.k_prime_local(params))
+    run = jax.jit(lambda psi, stats, store, ann, q, qm: search_pipeline(
+        LemurIndex(cfg, psi, stats, store, "ivf", ann), q, qm, local))
+    ss, ii = [], []
+    for s in range(4):
+        lo, hi = bounds[s], bounds[s + 1]
+        W = W_mesh[lo:hi]
+        toks, mask = corpus.doc_tokens[lo:hi], corpus.doc_mask[lo:hi]
+        ann = registry.get_backend("ivf").build(
+            jax.random.fold_in(keys[3], s), CorpusView(jnp.asarray(W), toks,
+                                                       mask),
+            cfg.ivf.replace(nlist=nlist))
+        store, _ = pages.from_dense(W, toks, mask)
+        sc, ids = (np.asarray(a) for a in run(host.psi, stats, store, ann,
+                                              q, qm))
+        ss.append(sc)
+        ii.append(np.where(ids >= 0, ids + lo, -1))
+    ss, ii = np.concatenate(ss, 1), np.concatenate(ii, 1)
+    order = np.argsort(-ss, axis=1, kind="stable")[:, :local.k]
+    return (np.take_along_axis(ss, order, 1),
+            np.take_along_axis(ii, order, 1))
+
+want_s, want_i = plain_reference(None)
+got_s, got_i = (np.asarray(a) for a in mr.search(q, qm))
+out["served_bit_equal"] = bool(np.array_equal(got_i, want_i)
+                               and np.array_equal(got_s, want_s))
+out["served_ids"] = got_i.tolist()
+out["served_shape"] = list(got_i.shape)
+
+# (c) nprobe = nlist and k'_local covering every shard: the exact top-k
+full = SearchParams(k_prime=M, backend=IVFSearchParams(nprobe=nlist))
+out["k_prime_local_full"] = mr.k_prime_local(full)
+
+def exact_gap(r):
+    s, i = (np.asarray(a) for a in r.search(q, qm, full))
+    truth = np.argsort(-exact64, axis=1, kind="stable")[:, :cfg.k]
+    same = all(set(a) == set(b) for a, b in zip(i.tolist(), truth.tolist()))
+    ex = np.take_along_axis(exact64, i, 1)
+    return same, float(np.max(np.abs(s - ex) / np.maximum(np.abs(ex), 1)))
+
+out["exact_top_k"], out["exact_gap"] = exact_gap(mr)
+rerank = ops.fused_rerank_paged
+
+def bf16_rerank(q, qm, cand, tok_pages, *a, **kw):
+    r = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+    return rerank(r(q), qm, cand, r(tok_pages), *a, **kw)
+
+ops.fused_rerank_paged = bf16_rerank
+out["bf16_top_k"], out["bf16_gap"] = exact_gap(
+    MeshLemurRetriever(mr.cfg, mr.mesh, mr.state, mr.m))
+ops.fused_rerank_paged = rerank
+
+# (d) candidates: every shard's k'_local, global ids, pads -1
+cand = np.asarray(mr.candidates(q, qm))
+kl = mr.k_prime_local()
+out["cands_width"] = [int(cand.shape[1]), 4 * kl]
+out["cands_in_own_shard"] = all(
+    ((blk == -1) | ((blk >= bounds[s]) & (blk < bounds[s + 1]))).all()
+    for s, blk in enumerate(np.split(cand, 4, axis=1)))
+out["cands_pad"] = bool((cand == -1).any())
+out["cands_fewer_than_docs"] = bool(((cand >= 0).sum(1) < M).all())
+out["served_in_cands"] = all(
+    set(s[s >= 0].tolist()) <= set(c[c >= 0].tolist())
+    for s, c in zip(got_i, cand))
+cand_full = np.asarray(mr.candidates(q, qm, full))
+truth = np.argsort(-exact64, axis=1, kind="stable")[:, :cfg.k]
+out["exact_top_k_in_cands"] = all(
+    set(t.tolist()) <= set(c.tolist()) for t, c in zip(truth, cand_full))
+
+# (e) each device's block of every per-shard leaf is its own shard's
+placed, own_docs, own_lists = True, True, True
+for leaf in jax.tree.leaves((mr.state.store, mr.state.ann, mr.state.base)):
+    blk = leaf.shape[0] // 4
+    for sh in leaf.addressable_shards:
+        s = (sh.index[0].start or 0) // blk
+        placed &= sh.device == devs[s] and sh.data.shape[0] == blk
+for s, dev in enumerate(devs):
+    mine = lambda t: jax.tree.map(lambda a: next(
+        sh.data for sh in a.addressable_shards if sh.device == dev), t)
+    st, ann = mine(mr.state.store), mine(mr.state.ann)
+    lo, hi = bounds[s], bounds[s + 1]
+    toks, mask = pages.gather_docs(st, jnp.arange(hi - lo))
+    toks, mask = np.asarray(toks), np.asarray(mask)
+    w = corpus.doc_tokens.shape[1]
+    own_docs &= (int(st.n_docs[0]) == hi - lo
+                 and np.array_equal(mask[:, :w], corpus.doc_mask[lo:hi])
+                 and np.array_equal(toks[:, :w] * mask[:, :w, None],
+                                    corpus.doc_tokens[lo:hi]
+                                    * corpus.doc_mask[lo:hi, :, None]))
+    ids = np.asarray(ann.ids)
+    own_lists &= sorted(ids[ids >= 0].tolist()) == list(range(hi - lo))
+out["placed"], out["own_docs"], out["own_lists"] = (
+    bool(placed), bool(own_docs), bool(own_lists))
+
+# (f) one trace per batch shape
+fresh = MeshLemurRetriever(mr.cfg, mr.mesh, mr.state, mr.m)
+for b in (8, 8, 3, 8, 3):
+    fresh.search(q[:b], qm[:b])
+out["traces"] = [fresh.trace_count(), fresh.trace_count(SearchParams())]
+out["trace_shapes"] = sorted([list(k), v]
+                             for k, v in fresh.trace_shapes().items())
+
+# (g) add raises
+try:
+    mr.add(corpus.doc_tokens[:2], corpus.doc_mask[:2])
+    out["add"] = "returned"
+except NotImplementedError as e:
+    out["add"] = str(e)
+
+# build_per_shard: build() without a mesh trains and stops; shard(mesh)
+# builds what build(mesh=) builds, here with a pinned per-shard budget
+trained = LemurRetriever.build(corpus, cfg.replace(build_per_shard=True),
+                               key=key)
+out["trained"] = type(trained).__name__
+pinned = trained.shard(mesh, k_prime_local=kl)
+out["deferred_same_state"] = all(
+    np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(jax.device_get(pinned.state)), jax.tree.leaves(host)))
+s3, i3 = (np.asarray(a) for a in pinned.search(q, qm))
+out["deferred_same_answers"] = bool(np.array_equal(s3, got_s)
+                                    and np.array_equal(i3, got_i))
+out["rows_per_shard"] = pinned.rows_per_shard
+# k past each shard's k': every shard answers all its candidates, the
+# merge drops none, and the columns past every candidate are pads
+wide = SearchParams(k=4 * kl, use_fused_gather=False)
+out["k_prime_local_wide"] = [pinned.k_prime_local(wide),
+                             mr.k_prime_local(wide), kl]
+ws, wi = (np.asarray(a) for a in pinned.search(q, qm, wide))
+out["wide_shape"] = list(wi.shape)
+out["wide_is_candidates"] = all(
+    sorted(a[a >= 0].tolist()) == sorted(c[c >= 0].tolist())
+    for a, c in zip(wi, cand))
+out["wide_pads"] = bool(((wi == -1) == (ws == maxsim.NEG)).all()
+                        and (wi == -1).any())
+wider = SearchParams(k=4 * kl + 3, use_fused_gather=False)
+ws, wi = (np.asarray(a) for a in pinned.search(q, qm, wider))
+out["wider_tail"] = [list(wi.shape), bool((wi[:, -3:] == -1).all()
+                                          and (ws[:, -3:] == maxsim.NEG).all())]
+
+# build(mesh=) with k' covering every shard answers as build().shard(mesh)
+s1, i1 = (np.asarray(a) for a in mr.search(q, qm, full))
+s2, i2 = (np.asarray(a) for a in one.shard(mesh, sq8=False).search(
+    q, qm, SearchParams(k_prime=M, use_ann=False)))
+out["answers_as_shard"] = [bool(np.array_equal(i1, i2)),
+                           float(np.abs(s1 - s2).max())]
+
+# spans: the step's ops carry the stage scopes; search opens lemur.search
+hlo = fresh._compiled_fn(fresh.resolve(None)).lower(
+    mr.state, jnp.asarray(q), jnp.asarray(qm)).compile().as_text()
+out["scopes"] = sorted({sc for sc in ("first_stage/", "rerank/", "merge/")
+                        if sc in hlo})
+with tempfile.TemporaryDirectory() as d:
+    jax.profiler.start_trace(d)
+    jax.block_until_ready(mr.search(q, qm))
+    jax.profiler.stop_trace()
+    import pathlib
+    from jax.profiler import ProfileData
+    path = sorted(pathlib.Path(d).glob("plugins/profile/*/*.xplane.pb"))[-1]
+    out["host_spans"] = sorted({e.name for p in ProfileData.from_file(
+        str(path)).planes for line in p.lines for e in line.events
+        if e.name.startswith("lemur.")})
+print("JSON" + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def on_mesh(run_forced8):
+    """What ``_MESH_SCRIPT`` found on 4 forced host devices."""
+    out = run_forced8(_MESH_SCRIPT, n_devices=4)
+    return json.loads(out.split("JSON", 1)[1])
+
+
+def test_mesh_build_trains_psi_and_w_as_one_chip(on_mesh):
+    """(a) ψ is the one-chip build's bit for bit (trained once, same keys);
+    each shard's W rows are the one-chip rows within 1e-3 of the largest
+    entry.  Why not bit for bit: the OLS right-hand side Ψᵀg sums the same
+    n' products, but in another order when a block of docs has another
+    width (1 block of 302 against 4 of 75-76), and the solve against the
+    ridge Gram (condition number ~1e5 here) multiplies that fp32 rounding;
+    κ · 2^-24 ≈ 7e-3 bounds it, and it reads ~6e-5."""
+    assert on_mesh["psi_bit_equal"]
+    assert on_mesh["W_rel_err"] < 1e-3, on_mesh["W_rel_err"]
+    # nlist by the paper's rule on the shard's m, the same on every shard
+    assert on_mesh["nlist"][0] == on_mesh["nlist"][1]
+
+
+def test_mesh_search_equals_the_plain_per_shard_reference(on_mesh):
+    """(b) served ids and scores equal, bit for bit on fp32, a loop over
+    the shards that builds a one-chip index from the same ψ, W rows and
+    key, runs ``search_pipeline`` on it and merges the results in numpy."""
+    assert on_mesh["served_shape"] == [8, 5]
+    assert on_mesh["served_bit_equal"]
+
+
+def test_mesh_full_probe_is_the_exact_top_k_and_bf16_is_not(on_mesh):
+    """(c) with nprobe = nlist and k'_local covering every shard the served
+    top-k is the whole corpus's exact top-k, scores within 1e-5 of float64
+    MaxSim (relative to max(|exact|, 1)).  The limit: each fp32 token dot
+    sums d=16 products and a score sums Tq=4 token maxima of at most 1, so
+    rounding moves a score by at most (16 + 4) · 2^-24 · 4 ≈ 4.8e-6.  The
+    control, the rerank's inputs rounded to bf16 (2^-8), fails it."""
+    assert on_mesh["k_prime_local_full"] >= 76          # the largest shard
+    assert on_mesh["exact_top_k"]
+    assert on_mesh["exact_gap"] < 1e-5, on_mesh["exact_gap"]
+    assert on_mesh["bf16_gap"] > 1e-5, on_mesh["bf16_gap"]
+
+
+def test_mesh_candidates_are_every_shards_first_stage(on_mesh):
+    """(d) ``candidates()`` is (B, shards × k'_local): shard s's global ids
+    in the s-th block of columns, pads -1, every served id among them."""
+    width, want = on_mesh["cands_width"]
+    assert width == want
+    assert on_mesh["cands_in_own_shard"]
+    assert on_mesh["cands_pad"]
+    assert on_mesh["served_in_cands"]
+
+
+def test_mesh_leaves_hold_only_their_own_shard(on_mesh):
+    """(e) every per-shard leaf (store, IVF lists, base) is block-sharded:
+    device s holds one block, and that block is shard s's docs -- its pages
+    gather back to exactly the corpus rows [s·m/n, (s+1)·m/n), its IVF
+    lists hold each of those docs once."""
+    assert on_mesh["placed"]
+    assert on_mesh["own_docs"]
+    assert on_mesh["own_lists"]
+
+
+def test_mesh_search_traces_once_per_batch_shape(on_mesh):
+    """(f) five searches over two batch shapes trace twice."""
+    assert on_mesh["traces"] == [2, 2]
+    assert on_mesh["trace_shapes"] == [[[3, 4, 16], 1], [[8, 4, 16], 1]]
+
+
+def test_mesh_retriever_refuses_add(on_mesh):
+    """(g) the mesh-built deployment is read-only, and says so."""
+    assert "read-only" in on_mesh["add"]
+
+
+def test_build_per_shard_defers_the_index_to_shard(on_mesh):
+    """With ``cfg.build_per_shard`` and no mesh, ``build`` trains ψ and the
+    OLS solver and returns a ``TrainedLemur``; its ``shard(mesh)`` builds,
+    leaf for leaf, the state ``build(mesh=)`` builds and answers as it
+    does."""
+    assert on_mesh["trained"] == "TrainedLemur"
+    assert on_mesh["deferred_same_state"]
+    assert on_mesh["deferred_same_answers"]
+    assert on_mesh["rows_per_shard"] == 76
+
+
+def test_pinned_k_prime_local_holds_for_every_search(on_mesh):
+    """A budget pinned at ``shard(mesh, k_prime_local=)`` is each shard's
+    for every search; unpinned, a k past k' widens it."""
+    pinned, default, kl = on_mesh["k_prime_local_wide"]
+    assert pinned == kl
+    assert default == 4 * kl
+
+
+def test_mesh_search_past_every_shards_k_prime_is_its_candidates(on_mesh):
+    """At k = shards x k'_local, on the rerank that gathers pages, the
+    answer's ids are every shard's candidates; a pad id scores NEG; past
+    that, the columns are pads."""
+    kl = on_mesh["k_prime_local_wide"][2]
+    assert on_mesh["wide_shape"] == [8, 4 * kl]
+    assert on_mesh["wide_is_candidates"]
+    assert on_mesh["wide_pads"]
+    shape, tail_pads = on_mesh["wider_tail"]
+    assert shape == [8, 4 * kl + 3]
+    assert tail_pads
+
+
+def test_mesh_build_answers_as_build_then_shard(on_mesh):
+    """Where the first stage keeps every doc (nprobe = nlist, k'_local
+    covering each shard), ``build(mesh=)`` answers what the slot pool of
+    ``build().shard(mesh)`` answers with its exact scan: the same ids, the
+    same scores."""
+    same_ids, gap = on_mesh["answers_as_shard"]
+    assert same_ids
+    assert gap == 0.0
+
+
+def test_mesh_candidates_hold_every_served_id(on_mesh):
+    width, want = on_mesh["cands_width"]
+    assert width == want
+    assert on_mesh["cands_fewer_than_docs"]
+    assert on_mesh["served_in_cands"]
+
+
+def test_mesh_candidates_covering_a_shard_hold_the_exact_top_k(on_mesh):
+    assert on_mesh["exact_top_k_in_cands"]
+
+
+def test_mesh_step_carries_stage_scopes_and_search_span(on_mesh):
+    """The compiled sharded step's op names carry ``first_stage/`` and
+    ``rerank/`` (the one-chip pipeline on each shard) and ``merge/``; the
+    sharded ``search`` opens a ``lemur.search`` host span."""
+    assert on_mesh["scopes"] == ["first_stage/", "merge/", "rerank/"]
+    assert "lemur.search" in on_mesh["host_spans"]

@@ -425,3 +425,31 @@ def test_no_stray_deprecation_warnings_on_new_api(tiny_corpus):
         r = LemurRetriever.build(tiny_corpus, cfg, key=jax.random.PRNGKey(0))
         q = jnp.asarray(tiny_corpus.doc_tokens[:4, :4])
         r.search(q, params=SearchParams(k=5))
+
+
+def test_paged_store_shape_floors_keep_every_doc(tiny_corpus):
+    """``from_dense``'s floors widen the slot capacity, the page pool and
+    the page table (so several shards can share one shape) and change no
+    doc: each gathers back exactly as from the unfloored store.
+    ``pages_needed`` is the layout the unfloored store takes."""
+    from repro.core import pages
+
+    toks, mask = tiny_corpus.doc_tokens[:50], tiny_corpus.doc_mask[:50]
+    W = np.random.default_rng(0).standard_normal((50, 8)).astype(np.float32)
+    base, _ = pages.from_dense(W, toks, mask)
+    need, pmax = pages.pages_needed(mask)
+    assert base.pages_per_doc == pmax
+    assert base.n_pages == pages.next_pow2(need)
+    wide, _ = pages.from_dense(W, toks, mask, min_capacity=128,
+                               min_pages=4 * base.n_pages, min_pmax=pmax + 2)
+    assert (wide.capacity, wide.n_pages, wide.pages_per_doc) == (
+        128, 4 * base.n_pages, pmax + 2)
+    ids = jnp.arange(50)
+    t0, m0 = (np.asarray(a) for a in pages.gather_docs(base, ids))
+    t1, m1 = (np.asarray(a) for a in pages.gather_docs(wide, ids))
+    width = m0.shape[1]
+    np.testing.assert_array_equal(m1[:, :width], m0)
+    assert not m1[:, width:].any()
+    np.testing.assert_array_equal(t1[:, :width], t0)
+    np.testing.assert_array_equal(np.asarray(wide.W)[:50], W)
+    assert int(np.asarray(wide.alive).sum()) == 50

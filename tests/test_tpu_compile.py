@@ -141,6 +141,86 @@ def test_sharded_serve_step_compiles_for_v5e_2x2(v5e, monkeypatch):
     assert "tpu_custom_call" in text and "all-gather" in text
 
 
+def _doc_sharded_step(v5e, params):
+    """The compiled serve step of ``build(mesh=)`` over a 2x2 mesh for
+    ``params``, each shard the one-chip index of the MS MARCO cell
+    (131,072 docs: 2^20 token pages, 1,024 SQ8 lists of cap 2,048)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro import dist
+    from repro.anns.ivf import IVFIndex
+    from repro.configs.lemur_paper import CONFIG
+    from repro.core.model import TargetStats
+    from repro.core.pages import PagedStore
+
+    mesh = Mesh(np.array(v5e.devices[:4]).reshape(2, 2), ("data", "model"))
+    S = lambda s, dt, spec: jax.ShapeDtypeStruct(
+        s, dt, sharding=NamedSharding(mesh, spec))
+    rows, repl = P(("data", "model")), P()
+    R = lambda s, dt: S((4 * s[0],) + s[1:], dt, rows)   # 4 shards' blocks
+    vec = S((DP,), F32, repl)
+    nlist = 1024
+    state = dist.ShardedIndexState(
+        psi={"dense": {"kernel": S((D, DP), F32, repl), "bias": vec},
+             "ln": {"scale": vec, "bias": vec}},
+        stats=TargetStats(S((), F32, repl), S((), F32, repl)),
+        store=PagedStore(R((P_PAGES, PAGE, D), F32), R((SLOTS, PMAX), I32),
+                         R((SLOTS,), I32), R((SLOTS, DP), F32),
+                         R((SLOTS,), BOOL), R((1,), I32)),
+        ann=IVFIndex(R((nlist, DP), F32), R((nlist, CAP), I32),
+                     R((nlist, CAP, DP), I8), R((nlist, CAP), F32),
+                     R((nlist,), I32), R((DP,), F32)),
+        base=R((1,), I32))
+    step = dist.make_shard_search_step(mesh, CONFIG, "ivf",
+                                       params.resolve(CONFIG, "ivf"))
+    return jax.jit(step).lower(state, S((16, TQ, D), F32, repl),
+                               S((16, TQ), BOOL, repl)).compile()
+
+
+def _chip_bytes(compiled) -> int:
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+
+
+def test_doc_sharded_serve_step_compiles_for_v5e_2x2(v5e, monkeypatch):
+    """The serve step of ``build(mesh=)`` at the MS MARCO cell's sizes: the
+    IVF scan and paged rerank kernels on every shard, the all-gather
+    merge, and a shard's state plus the program's scratch within one
+    chip's 16 GB."""
+    from repro.retriever import SearchParams
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # the TPU dispatch
+    compiled = _doc_sharded_step(v5e, SearchParams(k_prime=KP))
+    text = compiled.as_text()
+    assert "all-gather" in text
+    assert re.search(r"ivf_probe_scan[^\n]*tpu_custom_call", text)
+    assert re.search(r"rerank_paged_scores[^\n]*tpu_custom_call", text)
+    assert _chip_bytes(compiled) < 16e9, _chip_bytes(compiled)
+
+
+def test_doc_sharded_search_of_every_candidate_compiles_for_v5e_2x2(
+        v5e, monkeypatch):
+    """The same deployment searched at k = 4 shards x k'_local on the rerank
+    that gathers the candidates' pages (``use_fused_gather=False``), as a
+    caller takes every shard's candidates: one shard's gathered (16, k',
+    80, 128) fp32 tokens and its state within one chip's 16 GB, and the
+    answer 4 x k' wide."""
+    from repro.retriever import SearchParams
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    compiled = _doc_sharded_step(v5e, SearchParams(
+        k=4 * KP, k_prime=KP, use_fused_gather=False))
+    text = compiled.as_text()
+    assert "all-gather" in text
+    assert re.search(r"ivf_probe_scan[^\n]*tpu_custom_call", text)
+    assert not re.search(r"rerank_paged_scores[^\n]*tpu_custom_call", text)
+    assert f"s32[16,{4 * KP}]" in text
+    assert _chip_bytes(compiled) < 16e9, _chip_bytes(compiled)
+
+
 @pytest.mark.parametrize("name", ["mips_topk", "query_fused"])
 def test_one_launch_top_k_is_refused_for_v5e(one_chip, name):
     """The one-launch kernels merge a carried top-k' with ``lax.top_k``
