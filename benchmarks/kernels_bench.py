@@ -35,17 +35,18 @@ def _gather_rows(rng):
     vecs = jnp.asarray(rng.standard_normal((nlist, cap, d)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, d)), jnp.float32)
     probe = jnp.asarray(rng.integers(0, nlist, (B, nprobe)), jnp.int32)
+    counts = jnp.full((nlist,), cap, jnp.int32)     # every list full
     codes, scales = sq8_quant(vecs)
     for name, v, s, item in (("ivf_scan_fp32", vecs, None, 4),
                              ("ivf_scan_sq8", codes, scales, 1)):
         legacy = jax.jit(lambda qq, pp, v=v, s=s: ref.ivf_scan_ref(
             qq, pp, ids, v, s))
         fused = jax.jit(lambda qq, pp, v=v, s=s: ops.fused_ivf_scan(
-            qq, pp, ids, v, s))
+            qq, pp, ids, counts, v, s))
         lo, fo = legacy(q, probe), fused(q, probe)
         parity = bool(np.allclose(np.asarray(lo), np.asarray(fo)))
         if interpret:
-            ko = ops.fused_ivf_scan(q[:4], probe[:4], ids, v, s,
+            ko = ops.fused_ivf_scan(q[:4], probe[:4], ids, counts, v, s,
                                     use_kernel=True)
             tol = 1e-6 if s is None else 2 ** -13
             parity &= bool(np.allclose(np.asarray(ko), np.asarray(lo[:4]),

@@ -276,8 +276,8 @@ def kernel_vs_xla(r, queries):
         psi_q = pool_queries(idx.psi, q, qm)
         probe = probe_lists(ann, psi_q, nprobe)
         got, want = (np.asarray(ops.fused_ivf_scan(
-            psi_q, probe, ann.ids, ann.vecs, ann.scales, use_kernel=k))
-            for k in (True, False))
+            psi_q, probe, ann.ids, ann.counts, ann.vecs, ann.scales,
+            use_kernel=k)) for k in (True, False))
         fin = np.isfinite(want)
         if not np.array_equal(np.isfinite(got), fin):
             raise AssertionError("SQ8 probe scans pad different slots")

@@ -40,7 +40,8 @@ class IVFIndex(NamedTuple):
                            # codes when rq (coded against the OWN cluster
                            # centroid — the id is implicit in the list row)
     scales: jax.Array | None  # (nlist, cap) fp32 when sq8 else None
-    counts: jax.Array      # (nlist,) int32
+    counts: jax.Array      # (nlist,) int32: list c's entries are its
+                           # slots [0, counts[c]), the rest -1 pads
     mean: jax.Array | None = None  # (d,) corpus mean (centered MIPS: ranking
                                    # by q.(w-mean) == ranking by q.w)
     # residual-codec storage tier (None unless built with residual_bits)
@@ -297,7 +298,8 @@ def search_ivf(index: IVFIndex, q: jax.Array, nprobe: int, k: int,
                                    else False)
     elif use_fused_gather:
         # masked -inf inside the scan (same pad convention as below)
-        s = ops.fused_ivf_scan(q, probe, index.ids, index.vecs, index.scales)
+        s = ops.fused_ivf_scan(q, probe, index.ids, index.counts, index.vecs,
+                               index.scales)
     else:
         vecs = ref.take_lists(index.vecs, probe)   # (B, nprobe, cap, d)
         cap = vecs.shape[2]

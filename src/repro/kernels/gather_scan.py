@@ -10,26 +10,32 @@ duplicated per query row.
 
 These kernels move the gather INTO the grid instead: the probe / candidate
 ids are scalar-prefetched to SMEM (``pltpu.PrefetchScalarGridSpec``), and
-each grid step's BlockSpec ``index_map`` reads the prefetched id to DMA
-exactly one cluster (or candidate) tile HBM→VMEM, where the MXU contraction
-runs immediately.  Per query the HBM read volume is O(nprobe·cap·d) /
-O(k'·Td·d) source bytes streamed exactly once; nothing is materialized.
-Consecutive grid steps double-buffer their DMAs automatically (the Pallas
-grid pipeline), so the scan runs at HBM bandwidth.
+each grid step DMAs the prefetched id's cluster (or candidate) tiles
+HBM→VMEM — by its BlockSpec ``index_map``, or by hand where the step
+streams a variable number of them — where the MXU contraction runs
+immediately.  Per query the HBM read volume is the probed lists' real
+entries / O(k'·Td·d) source bytes streamed exactly once; nothing is
+materialized.  The DMAs are double-buffered (by the Pallas grid pipeline,
+or by hand), so the scan runs at HBM bandwidth.
 
-``ivf_probe_scan`` — grid ``(B, nprobe, cap/bc)``; step ``(b, p, t)`` DMAs
-cap-tile ``t`` (``bc`` rows, :func:`cap_tile`) of cluster ``probe[b, p]``'s
-list (fp32, or int8 codes dequantized
-in-kernel via the same exact bf16 split as ``mips_sq8``), scores it against
-query row ``b`` in one MXU matmul, masks ``-1`` pad slots to ``-inf`` and
-writes a compact ``(B, nprobe, cap)`` score strip (the top-k' runs on the
-strip outside, like the legacy path — bit-identical ids on fp32).
+``ivf_probe_scan`` — grid ``(B, nprobe)``; step ``(b, p)`` streams the
+real entries of cluster ``probe[b, p]``'s list, which are its first
+``count`` slots: ⌈count/bc⌉ tiles of ``bc`` rows (:func:`ivf_scan_plan`)
+DMA'd by hand from the lists left in HBM, the probe ids and their lists'
+counts in SMEM.  Each tile starts the next tile's DMA before it waits for
+its own, across steps too, so one tile's DMA hides behind the one before;
+an empty list streams nothing.  Each tile (fp32, or int8 codes dequantized
+in-kernel via the same exact bf16 split as ``mips_sq8``) is scored against
+query row ``b`` in one MXU matmul, ``-1`` pad slots masked to ``-inf``,
+into the list's ``(1, cap)`` row of a ``(B, nprobe, cap)`` score strip
+whose slots past ``count`` stay ``-inf`` (the top-k' runs on the strip
+outside, like the legacy path — bit-identical ids on fp32).
 
-VMEM per step: a list tile of at most 1 MiB, the query row and a ``(1,
-bc)`` score strip — ×2 for the pipeline's double buffer, inside v5e VMEM at
-any cap and d.  Row vectors travel with a unit axis (``(B, 1, d)``,
-``(nlist, 1, cap)``) so every block's last two dims are the array's own or
-(8, 128)-aligned, as the TPU lowering requires.
+VMEM per step: the tile double buffer (2 × 256 KiB at d' 2048 int8, 2 ×
+1 MiB fp32), the query row and the list's id, scale and score rows of
+``cap`` slots.  Row vectors travel with a unit axis (``(B, 1, d)``) and a
+list's rows as ``(cap/bc, bc)``, so every block's last two dims are the
+array's own or (8, 128)-aligned, as the TPU lowering requires.
 
 ``rerank_gather_scores`` — grid ``(B, k')``; step ``(b, c)`` DMAs candidate
 ``cand[b, c]``'s ``(Td, d)`` token slab (fp or int8 + per-token scales),
@@ -78,10 +84,12 @@ runs inside a ``lax.map`` over row groups.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -97,25 +105,6 @@ HIGHEST = jax.lax.Precision.HIGHEST
 # scalar-prefetch IVF probe scan
 # --------------------------------------------------------------------------
 
-def _ivf_scan_fp_kernel(probe_ref, q_ref, ids_ref, vecs_ref, out_ref):
-    # q: (1, d); ids: (1, bc); vecs: (bc, d) — one cap-tile of ONE cluster,
-    # DMA'd by the index_map from the prefetched probe id; out: (1, bc)
-    s = jax.lax.dot_general(
-        q_ref[...], vecs_ref[...], (((1,), (1,)), ((), ())),
-        precision=HIGHEST, preferred_element_type=jnp.float32,
-    )  # (1, bc)
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
-
-
-def _ivf_scan_sq8_kernel(probe_ref, q_ref, ids_ref, codes_ref, scales_ref,
-                         out_ref):
-    # int8 cluster codes dequantized IN-KERNEL: the exact bf16 split of the
-    # fp32 query x bf16-widened codes (kernels.mips_sq8.split_dot), per-slot
-    # scales folded into the score strip
-    s = split_dot(q_ref[...], codes_ref[...]) * scales_ref[...]   # (1, bc)
-    out_ref[...] = jnp.where(ids_ref[...] >= 0, s, -jnp.inf)
-
-
 def cap_tile(cap: int, row_bytes: int, budget: int = 1 << 20) -> int:
     """Rows of one cluster list streamed per grid step: the whole list when
     it fits ``budget`` bytes of VMEM, else the largest halving of ``cap``
@@ -127,48 +116,155 @@ def cap_tile(cap: int, row_bytes: int, budget: int = 1 << 20) -> int:
     return bc
 
 
+SCAN_TILE_ROWS = 128          # list rows one DMA of the probe scan brings
+SCAN_VMEM_BYTES = 2 << 20     # the probe scan's tile double buffer
+
+
+class IVFScanPlan(NamedTuple):
+    """The tiling :func:`ivf_probe_scan` runs with, and what it streams."""
+    rows_per_tile: int    # list rows one DMA brings
+    vmem_bytes: int       # the double-buffered tile scratch
+    tiles: int            # tiles streamed for the given probed counts
+    padded_tiles: int     # tiles the same probes take over the full cap
+
+
+def ivf_scan_plan(cap: int, row_bytes: int, probed_counts=()) -> IVFScanPlan:
+    """Tiling of the probe scan over lists of ``cap`` slots of ``row_bytes``
+    (d' int8 codes, or 4·d' fp32): :data:`SCAN_TILE_ROWS` rows that divide
+    ``cap`` (the whole list when it is shorter), halved while two tiles
+    pass :data:`SCAN_VMEM_BYTES` and the half stays a multiple of 32 rows
+    (the int8 sublane tile).  Given the real lengths ``probed_counts`` of
+    some probed lists, it also counts the tiles streamed, ⌈count/rows⌉
+    each, against the ``cap/rows`` each would take padded."""
+    bc = cap if cap <= SCAN_TILE_ROWS else math.gcd(cap, SCAN_TILE_ROWS)
+    while 2 * bc * row_bytes > SCAN_VMEM_BYTES and bc % 64 == 0:
+        bc //= 2
+    n = np.asarray(probed_counts, np.int64)
+    return IVFScanPlan(rows_per_tile=bc, vmem_bytes=2 * bc * row_bytes,
+                       tiles=int(np.sum(-(-n // bc))),
+                       padded_tiles=int(n.size) * (cap // bc))
+
+
+def _ivf_scan_kernel(probe_ref, cnt_ref, tile0_ref, q_ref, ids_ref, *refs,
+                     bc):
+    # step (b, p) scans the real entries [0, cnt) of cluster probe[b, p]:
+    # ⌈cnt/bc⌉ tiles of bc rows DMA'd from the HBM lists (vecs_hbm: (nlist,
+    # cap, d)) into buf: (2, bc, d), alternating slots over the launch's
+    # whole tile sequence (tile0: this step's first tile's place in it).
+    # Each tile starts the DMA of the next before it waits for its own —
+    # the last tile of a step starts the next step's first (the grid runs
+    # in order).  ids / scales / out: this list's (cap/bc, bc) rows, one
+    # row a tile; slots past cnt stay -inf
+    *scales_ref, vecs_hbm, out_ref, buf, sem = refs
+    b, p = pl.program_id(0), pl.program_id(1)
+    step = b * pl.num_programs(1) + p
+    last = pl.num_programs(0) * pl.num_programs(1) - 1
+    n = cnt_ref[step]
+    nt = (n + bc - 1) // bc
+    nxt = jnp.minimum(step + 1, last)
+
+    def copy(s, t, slot):
+        return pltpu.make_async_copy(
+            vecs_hbm.at[probe_ref[s], pl.ds(t * bc, bc)], buf.at[slot],
+            sem.at[slot])
+
+    # a step's first tile was started by the step before, unless that one
+    # streamed nothing
+    @pl.when((nt > 0) & ((step == 0) | (cnt_ref[jnp.maximum(step - 1, 0)]
+                                        == 0)))
+    def _first():
+        copy(step, 0, tile0_ref[step] % 2).start()
+
+    out_ref[...] = jnp.full(out_ref.shape, -jnp.inf, jnp.float32)
+
+    def tile(t, carry):
+        slot = (tile0_ref[step] + t) % 2
+
+        @pl.when(t + 1 < nt)
+        def _next_tile():
+            copy(step, t + 1, 1 - slot).start()
+
+        @pl.when((t + 1 == nt) & (step < last) & (cnt_ref[nxt] > 0))
+        def _next_step():
+            copy(nxt, 0, 1 - slot).start()
+
+        copy(step, t, slot).wait()
+        row = pl.ds(t, 1)
+        if scales_ref:
+            # int8 codes dequantized IN-KERNEL: the exact bf16 split of the
+            # fp32 query x bf16-widened codes (kernels.mips_sq8.split_dot),
+            # per-slot scales folded into the score row
+            s = split_dot(q_ref[...], buf[slot]) * scales_ref[0][row, :]
+        else:
+            s = jax.lax.dot_general(
+                q_ref[...], buf[slot], (((1,), (1,)), ((), ())),
+                precision=HIGHEST, preferred_element_type=jnp.float32,
+            )  # (1, bc)
+        pos = t * bc + jax.lax.broadcasted_iota(jnp.int32, (1, bc), 1)
+        out_ref[row, :] = jnp.where((ids_ref[row, :] >= 0) & (pos < n), s,
+                                    -jnp.inf)
+        return carry
+
+    jax.lax.fori_loop(0, nt, tile, 0)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ivf_probe_scan(q, probe, ids, vecs, scales=None, *, interpret: bool = False):
-    """Scan the probed IVF cluster lists without gathering them to HBM.
+def ivf_probe_scan(q, probe, ids, counts, vecs, scales=None, *,
+                   interpret: bool = False):
+    """Scan the probed IVF cluster lists without gathering them to HBM,
+    streaming only each list's real entries.
 
     q: (B, d) fp32; probe: (B, nprobe) int32 cluster ids; ids: (nlist, cap)
-    int32 (-1 padded); vecs: (nlist, cap, d) fp32 — or int8 codes with
-    scales: (nlist, cap) — returns (B, nprobe, cap) fp32 scores with pad
-    slots at ``-inf``.  Each grid step DMAs one cap-tile of cluster
-    ``probe[b, p]``.  Row vectors travel with a unit axis (``(B, 1, d)``,
-    ``(nlist, 1, cap)``) so every block's last two dims are either the
-    array's own or (8, 128)-aligned, as the TPU lowering requires.
+    int32 (-1 padded); counts: (nlist,) int32, each list's real entries
+    being its slots [0, count); vecs: (nlist, cap, d) fp32 — or int8 codes
+    with scales: (nlist, cap) — returns (B, nprobe, cap) fp32 scores with
+    pad slots and every slot past a list's count at ``-inf``.  The probe
+    ids and their lists' counts are scalar-prefetched; grid step (b, p)
+    DMAs the ⌈count/bc⌉ tiles of cluster ``probe[b, p]``
+    (:func:`ivf_scan_plan`) from the lists left in HBM, so a list streams
+    what it holds, not its cap.
     """
     B, d = q.shape
     nprobe = probe.shape[1]
     nlist, cap = ids.shape
-    bc = cap_tile(cap, d * vecs.dtype.itemsize)
-    row = lambda b, p, t, pr: (pr[b, p], 0, t)
-    in_specs = [
-        pl.BlockSpec((None, 1, d), lambda b, p, t, pr: (b, 0, 0)),
-        pl.BlockSpec((None, 1, bc), row),
-        pl.BlockSpec((None, bc, d), lambda b, p, t, pr: (pr[b, p], t, 0)),
-    ]
-    args = [q.reshape(B, 1, d), ids.reshape(nlist, 1, cap), vecs]
-    kernel = _ivf_scan_fp_kernel
-    if scales is not None:
-        in_specs.append(pl.BlockSpec((None, 1, bc), row))
-        args.append(scales.reshape(nlist, 1, cap))
-        kernel = _ivf_scan_sq8_kernel
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, nprobe, cap // bc),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, 1, bc),
-                               lambda b, p, t, pr: (b, p, 0, t)),
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nprobe, 1, cap), jnp.float32),
-        interpret=interpret,
-        name="ivf_probe_scan",
-    )(probe.astype(jnp.int32), *args)
+    bc = ivf_scan_plan(cap, d * vecs.dtype.itemsize).rows_per_tile
+    probe = probe.astype(jnp.int32)
+    cnt = jnp.take(counts.astype(jnp.int32), probe, axis=0)    # (B, nprobe)
+    nt = ((cnt + bc - 1) // bc).reshape(-1)
+    tile0 = (jnp.cumsum(nt) - nt).reshape(B, nprobe)
+    rows = lambda a: a.reshape(nlist, cap // bc, bc)
+    lists = [rows(ids)] + ([] if scales is None else [rows(scales)])
+
+    def call(pr, cn, t0, q):
+        bb = q.shape[0]
+        row = lambda b, p, pr, cn, t0: (pr[b * nprobe + p], 0, 0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bb, nprobe),
+            in_specs=[
+                pl.BlockSpec((None, 1, d), lambda b, p, pr, cn, t0: (b, 0, 0)),
+                *[pl.BlockSpec((None, cap // bc, bc), row)] * len(lists),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, None, cap // bc, bc),
+                                   lambda b, p, pr, cn, t0: (b, p, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, bc, d), vecs.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        )
+        return pl.pallas_call(
+            functools.partial(_ivf_scan_kernel, bc=bc),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((bb, nprobe, cap // bc, bc),
+                                           jnp.float32),
+            # a step starts the next step's first tile: the grid runs in
+            # order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name="ivf_probe_scan",
+        )(pr, cn, t0, q, *lists, vecs)
+
+    out = _over_row_chunks(call, (probe, cnt, tile0), (q.reshape(B, 1, d),))
     return out.reshape(B, nprobe, cap)
 
 

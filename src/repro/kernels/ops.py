@@ -112,22 +112,25 @@ def mips_sq8_batched(q, codes, scales, *, use_kernel: bool | None = None,
     return jnp.take_along_axis(full, strip, axis=1)         # (B, n)
 
 
-def fused_ivf_scan(q, probe, ids, vecs, scales=None, *,
+def fused_ivf_scan(q, probe, ids, counts, vecs, scales=None, *,
                    use_kernel: bool | None = None):
     """Gather-at-source IVF probe scan: score the probed cluster lists
     without materializing the ``(B, nprobe, cap, d)`` gather in HBM.
 
-    q: (B, d); probe: (B, nprobe) int32; ids/vecs/scales are the IVF
-    index's padded cluster lists -> (B, nprobe, cap) fp32 scores, pad slots
-    ``-inf``.  TPU: the scalar-prefetch Pallas kernel
+    q: (B, d); probe: (B, nprobe) int32; ids/counts/vecs/scales are the
+    IVF index's padded cluster lists, each list's real entries the prefix
+    ``[0, count)`` -> (B, nprobe, cap) fp32 scores, pad slots ``-inf``.
+    TPU: the scalar-prefetch Pallas kernel, which streams only each
+    probed list's real entries
     (:func:`repro.kernels.gather_scan.ivf_probe_scan`); otherwise the
-    gather-then-score oracle (identical math to the legacy path).
+    gather-then-score oracle (identical math to the legacy path), which
+    masks by id — the same slots, as every slot past a count is a pad.
     """
     if use_kernel is None:
         use_kernel = _on_tpu()
     if not use_kernel:
         return ref.ivf_scan_ref(q, probe, ids, vecs, scales)
-    return _gs.ivf_probe_scan(q, probe, ids, vecs, scales,
+    return _gs.ivf_probe_scan(q, probe, ids, counts, vecs, scales,
                               interpret=not _on_tpu())
 
 

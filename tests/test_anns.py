@@ -97,6 +97,44 @@ def test_ivf_padded_lists_answer_as_before(rng, sq8):
         pad_lists(idx, idx.capacity // 2)
 
 
+def _ivf_producer(name, corpus):
+    """One of the ways the program makes an ``IVFIndex`` on one chip."""
+    from repro.anns import registry
+    from repro.anns.ivf import extend_ivf, pad_lists
+
+    build = lambda **kw: build_ivf(jax.random.PRNGKey(0), corpus[:200],
+                                   nlist=16, **kw)
+    be = registry.get_backend("ivf")
+    return {
+        "build_ivf-fp32": lambda: build(),
+        "build_ivf-sq8": lambda: build(sq8=True),
+        "build_ivf-res4": lambda: build(residual_bits=4),
+        "extend_ivf": lambda: extend_ivf(build(sq8=True), corpus[200:]),
+        "pad_lists": lambda: (lambda i: pad_lists(i, 4 * i.capacity))(
+            build(sq8=True)),
+        "pack_state-unpack_state": lambda: be.unpack_state(
+            *be.pack_state(build(sq8=True))),
+    }[name]()
+
+
+@pytest.mark.parametrize("producer", ["build_ivf-fp32", "build_ivf-sq8",
+                                      "build_ivf-res4", "extend_ivf",
+                                      "pad_lists", "pack_state-unpack_state"])
+def test_ivf_lists_hold_their_entries_as_a_prefix(rng, producer):
+    """The probe scan streams only the slots [0, count) of each list, so
+    every producer of an index keeps list c's entries there and -1 pads
+    after: ``ids[c, :counts[c]] >= 0`` and ``ids[c, counts[c]:] == -1``."""
+    corpus = jnp.asarray(rng.standard_normal((300, 16)), jnp.float32)
+    idx = _ivf_producer(producer, corpus)
+    ids, counts = np.asarray(idx.ids), np.asarray(idx.counts)
+    assert counts.shape == (idx.nlist,)
+    slot = np.arange(idx.capacity)[None, :]
+    real = slot < counts[:, None]
+    assert (ids[real] >= 0).all()
+    assert (ids[~real] == -1).all()
+    assert counts.sum() == len(np.unique(ids[real]))
+
+
 def test_muvera_fde_better_than_random(tiny_corpus):
     """FDE inner products correlate with MaxSim (Jayaram et al. Thm 2.1)."""
     from repro.core import maxsim

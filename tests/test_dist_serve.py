@@ -534,7 +534,7 @@ out["exact_top_k_in_cands"] = all(
     set(t.tolist()) <= set(c.tolist()) for t, c in zip(truth, cand_full))
 
 # (e) each device's block of every per-shard leaf is its own shard's
-placed, own_docs, own_lists = True, True, True
+placed, own_docs, own_lists, prefix = True, True, True, True
 for leaf in jax.tree.leaves((mr.state.store, mr.state.ann, mr.state.base)):
     blk = leaf.shape[0] // 4
     for sh in leaf.addressable_shards:
@@ -555,8 +555,11 @@ for s, dev in enumerate(devs):
                                     * corpus.doc_mask[lo:hi, :, None]))
     ids = np.asarray(ann.ids)
     own_lists &= sorted(ids[ids >= 0].tolist()) == list(range(hi - lo))
+    real = np.arange(ids.shape[1])[None, :] < np.asarray(ann.counts)[:, None]
+    prefix &= bool((ids[real] >= 0).all() and (ids[~real] == -1).all())
 out["placed"], out["own_docs"], out["own_lists"] = (
     bool(placed), bool(own_docs), bool(own_lists))
+out["prefix_lists"] = bool(prefix)
 
 # (f) one trace per batch shape
 fresh = MeshLemurRetriever(mr.cfg, mr.mesh, mr.state, mr.m)
@@ -689,6 +692,13 @@ def test_mesh_leaves_hold_only_their_own_shard(on_mesh):
     assert on_mesh["placed"]
     assert on_mesh["own_docs"]
     assert on_mesh["own_lists"]
+
+
+def test_mesh_shards_hold_their_list_entries_as_a_prefix(on_mesh):
+    """Each shard's IVF lists, padded to the common cap, keep list c's
+    entries in slots [0, counts[c]) and -1 after: the probe scan streams
+    only that prefix of each list."""
+    assert on_mesh["prefix_lists"]
 
 
 def test_mesh_search_traces_once_per_batch_shape(on_mesh):

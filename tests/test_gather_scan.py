@@ -16,6 +16,8 @@ Plus the compilation contract: the fused path still compiles exactly once
 per (backend, resolved params, batch shape), and the fused/legacy toggle is
 part of the compiled-fn key (flipping it may not silently reuse a trace).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,7 +72,8 @@ def test_fused_ivf_scan_strip_masks_pads():
     index = _mk_ivf(rng, 50, 12, 16, sq8=False)   # ragged lists => many pads
     q = jnp.asarray(rng.standard_normal((3, 12)), jnp.float32)
     probe = jnp.asarray(rng.integers(0, index.nlist, (3, 5)), jnp.int32)
-    s = ops.fused_ivf_scan(q, probe, index.ids, index.vecs, index.scales)
+    s = ops.fused_ivf_scan(q, probe, index.ids, index.counts, index.vecs,
+                           index.scales)
     pads = np.asarray(jnp.take(index.ids, probe, axis=0)) < 0
     assert np.all(np.isneginf(np.asarray(s)[pads]))
     assert np.all(np.isfinite(np.asarray(s)[~pads]))
@@ -137,30 +140,103 @@ def test_fused_rerank_sq8_matches_sharded_math():
 # the Pallas kernels themselves (interpret mode) vs the jnp refs
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,nlist,cap,d,nprobe", [
-    (4, 8, 5, 12, 3),     # tiny cap, non-128 d
-    (1, 16, 9, 32, 8),    # B=1
+# count-bounded lists: list c holds its real entries in slots [0, count),
+# tiles of 128 rows (ivf_scan_plan at cap 512), probed so that a row
+# mixes every fill, repeats a list, and starts or follows an empty one
+PREFIX_COUNTS = (0, 37, 128, 256, 300, 512)
+#                empty, a partial tile, one tile, an exact multiple, several
+#                tiles and a partial one, count = cap
+
+
+@pytest.mark.parametrize("B,nlist,cap,d,nprobe,counts,probe", [
+    (4, 8, 5, 12, 3, None, None),     # tiny cap, non-128 d
+    (1, 16, 9, 32, 8, None, None),    # B=1
+    (2, 6, 512, 8, 2, PREFIX_COUNTS, [[0, 0], [1, 0]]),     # empty lists
+    (1, 6, 512, 8, 3, PREFIX_COUNTS, [[1, 2, 1]]),          # partial tile
+    (1, 6, 512, 8, 2, PREFIX_COUNTS, [[3, 3]]),             # exact multiple
+    (2, 6, 512, 8, 1, PREFIX_COUNTS, [[5], [5]]),           # count = cap
+    (1, 6, 512, 8, 2, PREFIX_COUNTS, [[4, 0]]),             # several tiles
+    (1, 6, 512, 8, 4, PREFIX_COUNTS, [[4, 1, 4, 4]]),       # a list twice
+    (3, 6, 512, 8, 4, PREFIX_COUNTS,                        # B > 1
+     [[0, 1, 2, 3], [4, 5, 4, 1], [1, 0, 0, 5]]),
 ])
-def test_ivf_scan_kernel_interpret_vs_ref(B, nlist, cap, d, nprobe):
-    rng = np.random.default_rng(B * nlist)
-    ids = jnp.asarray(rng.integers(-1, 99, (nlist, cap)), jnp.int32)
+def test_ivf_scan_kernel_interpret_vs_ref(B, nlist, cap, d, nprobe, counts,
+                                          probe):
+    rng = np.random.default_rng(B * nlist + cap)
+    if counts is None:
+        # holes anywhere, every list's count its cap: the whole list streams
+        ids = jnp.asarray(rng.integers(-1, 99, (nlist, cap)), jnp.int32)
+        counts = jnp.full((nlist,), cap, jnp.int32)
+        probe = rng.integers(0, nlist, (B, nprobe))
+    else:
+        real = np.arange(cap)[None, :] < np.asarray(counts)[:, None]
+        ids = jnp.asarray(np.where(real, rng.integers(0, 1 << 20,
+                                                      (nlist, cap)), -1),
+                          jnp.int32)
+        counts = jnp.asarray(counts, jnp.int32)
+    probe = jnp.asarray(probe, jnp.int32)
     vecs = jnp.asarray(rng.standard_normal((nlist, cap, d)),
                        jnp.float32) * (ids >= 0)[..., None]
     q = jnp.asarray(rng.standard_normal((B, d)), jnp.float32)
-    probe = jnp.asarray(rng.integers(0, nlist, (B, nprobe)), jnp.int32)
-    out = gather_scan.ivf_probe_scan(q, probe, ids, vecs, interpret=True)
+    past = (np.arange(cap)[None, None, :]
+            >= np.asarray(counts)[np.asarray(probe)][..., None])
+    out = gather_scan.ivf_probe_scan(q, probe, ids, counts, vecs,
+                                     interpret=True)
     want = ref.ivf_scan_ref(q, probe, ids, vecs)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    assert np.all(np.isneginf(np.asarray(out)[past]))
     # SQ8 variant: in-kernel dequant within the hi/lo-bf16 tolerance
     codes, scales = sq8_quant(vecs)
-    out = gather_scan.ivf_probe_scan(q, probe, ids, codes, scales,
+    out = gather_scan.ivf_probe_scan(q, probe, ids, counts, codes, scales,
                                      interpret=True)
     want = ref.ivf_scan_ref(q, probe, ids, codes, scales)
     fin = np.isfinite(np.asarray(want))
     np.testing.assert_array_equal(np.isfinite(np.asarray(out)), fin)
-    denom = max(float(np.max(np.abs(np.asarray(want)[fin]))), 1.0)
-    assert np.max(np.abs(np.asarray(out)[fin] - np.asarray(want)[fin])) / denom \
-        < SQ8_RTOL
+    assert np.all(np.isneginf(np.asarray(out)[past]))
+    denom = max(float(np.max(np.abs(np.asarray(want)[fin]), initial=0.0)),
+                1.0)
+    assert np.max(np.abs(np.asarray(out)[fin] - np.asarray(want)[fin]),
+                  initial=0.0) / denom < SQ8_RTOL
+
+
+def test_count_bounded_scan_keeps_search_ivf_top_k(monkeypatch):
+    """``search_ivf`` through the count-bounded kernel (interpret mode)
+    returns the top-k' ids and scores of the padded gather-then-score
+    scan, on lists long enough for several tiles and a partial one."""
+    rng = np.random.default_rng(13)
+    index = _mk_ivf(rng, 1200, 8, 4, sq8=False)
+    counts = np.asarray(index.counts)
+    assert (counts > 128).any() and (counts % 128 != 0).any()
+    q = jnp.asarray(rng.standard_normal((3, 8)), jnp.float32)
+    want_s, want_i = ivf.search_ivf(index, q, 3, 200, use_fused_gather=False)
+    monkeypatch.setattr(ops, "fused_ivf_scan", functools.partial(
+        ops.fused_ivf_scan, use_kernel=True))
+    jax.clear_caches()
+    got_s, got_i = ivf.search_ivf(index, q, 3, 200, use_fused_gather=True)
+    jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+
+
+@pytest.mark.parametrize("B", [1, 2, 4, 8, 16])
+def test_ivf_scan_plan_at_the_cells_widths(B):
+    """At d' 2048 int8 and cap 2048, over the server's batch ladder: tiles
+    of a multiple of 32 rows, their double buffer inside the VMEM budget,
+    a full list streaming cap/bc tiles and an empty one none."""
+    cap, dp, nprobe = 2048, 2048, 32
+    plan = gather_scan.ivf_scan_plan(cap, dp)
+    bc = plan.rows_per_tile
+    assert bc % 32 == 0 and cap % bc == 0
+    assert plan.vmem_bytes == 2 * bc * dp <= gather_scan.SCAN_VMEM_BYTES
+    full = gather_scan.ivf_scan_plan(cap, dp, np.full((B, nprobe), cap))
+    assert full.tiles == full.padded_tiles == B * nprobe * cap // bc
+    mixed = gather_scan.ivf_scan_plan(
+        cap, dp, np.tile([0, 1, bc, bc + 1], (B, nprobe // 4)))
+    assert mixed.tiles == B * nprobe // 4 * (0 + 1 + 1 + 2)
+    assert mixed.padded_tiles == full.padded_tiles
+    # fp32 rows of 4 d' fit the budget too
+    assert gather_scan.ivf_scan_plan(cap, 4 * dp).vmem_bytes \
+        <= gather_scan.SCAN_VMEM_BYTES
 
 
 def test_rerank_kernel_interpret_vs_ref():
@@ -361,8 +437,10 @@ def test_ops_fused_dispatch_kernel_vs_ref():
     index = _mk_ivf(rng, 80, 16, 16, sq8=False)
     q = jnp.asarray(rng.standard_normal((2, 16)), jnp.float32)
     probe = jnp.asarray(rng.integers(0, index.nlist, (2, 4)), jnp.int32)
-    a = ops.fused_ivf_scan(q, probe, index.ids, index.vecs, use_kernel=True)
-    b = ops.fused_ivf_scan(q, probe, index.ids, index.vecs, use_kernel=False)
+    a = ops.fused_ivf_scan(q, probe, index.ids, index.counts, index.vecs,
+                           use_kernel=True)
+    b = ops.fused_ivf_scan(q, probe, index.ids, index.counts, index.vecs,
+                           use_kernel=False)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -62,10 +62,11 @@ def _cases(B, kp=KP):
     m_loc = SLOTS // 4                 # one shard of a 2x2 mesh
     return {
         "ivf_probe_scan-fp32": (gather_scan.ivf_probe_scan, [
-            q, probe, ((256, CAP), I32), ((256, CAP, DP), F32)], {}),
+            q, probe, ((256, CAP), I32), ((256,), I32),
+            ((256, CAP, DP), F32)], {}),
         "ivf_probe_scan-sq8": (gather_scan.ivf_probe_scan, [
-            q, probe, ((1024, CAP), I32), ((1024, CAP, DP), I8),
-            ((1024, CAP), F32)], {}),
+            q, probe, ((1024, CAP), I32), ((1024,), I32),
+            ((1024, CAP, DP), I8), ((1024, CAP), F32)], {}),
         "ivf_probe_scan-res4": (gather_scan.ivf_probe_res_scan, [
             q, probe, ((1024, CAP), I32), ((1024, CAP, DP // 2), U8),
             ((1024, DP), F32), ((DP, LEVELS), F32)], {}),
